@@ -84,3 +84,17 @@ class NativeLib:
             # AttributeError: symbol mismatch (old binary / changed ABI)
             log.warning("failed to load %s: %s", self._lib_path, e)
             return None
+
+
+def bridge_report() -> dict:
+    """Resolve all three bridges (building from the tracked
+    ``native_src/`` where the untracked ``.so`` is missing or stale)
+    and say how each loaded: ``"native"`` or ``"python"`` (fallback)."""
+    from eksml_tpu.data import native as data_native
+    from eksml_tpu.evalcoco import native as eval_native
+    from eksml_tpu.parallel import native as parallel_native
+
+    return {name: "native" if mod.get_lib() is not None else "python"
+            for name, mod in (("imageops", data_native),
+                              ("maskops", eval_native),
+                              ("topology", parallel_native))}

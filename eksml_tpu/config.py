@@ -594,9 +594,6 @@ def _define_defaults() -> None:
     _C.TPU.MESH_SHAPE = ()         # () → (num_devices, 1)
     _C.TPU.MESH_AXES = ("data", "model")
     _C.TPU.TOPOLOGY = ""           # e.g. "v5e-32"; validated like the CRD schema
-    # 0 = auto-size from model scale via the native shim
-    # (parallel/native.py recommend_combine_threshold)
-    _C.TPU.ALLREDUCE_COMBINE_THRESHOLD_BYTES = 64 * 1024 * 1024
     # ≙ §5.1: jax.profiler trace server port (0 = off); the NCCL_DEBUG
     # analogue for perf visibility
     _C.TPU.PROFILER_PORT = 0
@@ -822,9 +819,10 @@ def finalize_configs(is_training: bool) -> AttrDict:
 
 # CPU-feasible shrunk-model KEY=VALUE overrides (compiles in ~1-4 min
 # on one core; full model takes 2h+).  Single source for the test
-# suite's subprocess drives and bench_sweep --quick so the two can't
-# drift onto different shapes.  Run-shape knobs (steps/epochs/periods/
-# image size) intentionally stay with each consumer.
+# suite's subprocess drives, tools/perf_gate.py and chip_smoke.py
+# --rehearse so they can't drift onto different shapes.  Run-shape
+# knobs (steps/epochs/periods/image size) intentionally stay with each
+# consumer.
 SMOKE_OVERRIDES = (
     "DATA.NUM_CLASSES=5", "PREPROC.MAX_SIZE=128",
     "PREPROC.TRAIN_SHORT_EDGE_SIZE=(128,128)", "DATA.MAX_GT_BOXES=8",

@@ -22,12 +22,15 @@ offset), which is what modern Mask-RCNN implementations use.
 
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import logging
 import os
 from typing import Sequence
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 # The gather formulation materializes [N, out, s, out, s, C]
 # intermediates (and their transposes in the backward) — at the
@@ -63,9 +66,10 @@ def _chunk_size(n: int) -> int | None:
 
 
 def _bilinear_gather(feat: jnp.ndarray, y: jnp.ndarray, x: jnp.ndarray):
-    """Sample ``feat [H, W, C]`` at float coords ``y, x [...]`` with
+    """Sample ``feat [H, W, C]`` at float32 coords ``y, x [...]`` with
     bilinear interpolation; out-of-range samples contribute 0 (matching
-    ROIAlign's zero padding)."""
+    ROIAlign's zero padding).  Coordinates and tap weights are float32
+    whatever the feature dtype; only the finished weight is cast."""
     H, W = feat.shape[0], feat.shape[1]
     y0 = jnp.floor(y)
     x0 = jnp.floor(x)
@@ -79,7 +83,7 @@ def _bilinear_gather(feat: jnp.ndarray, y: jnp.ndarray, x: jnp.ndarray):
         yc = jnp.clip(yi, 0, H - 1).astype(jnp.int32)
         xc = jnp.clip(xi, 0, W - 1).astype(jnp.int32)
         vals = feat[yc, xc]  # gather → [..., C]
-        return vals * (w * inb.astype(feat.dtype))[..., None]
+        return vals * (w * inb).astype(feat.dtype)[..., None]
 
     return (tap(y0, x0, hy * hx) + tap(y0, x0 + 1, hy * lx)
             + tap(y0 + 1, x0, ly * hx) + tap(y0 + 1, x0 + 1, ly * lx))
@@ -89,7 +93,10 @@ def roi_align(feat: jnp.ndarray, rois: jnp.ndarray, spatial_scale: float,
               out_size: int, sampling_ratio: int = 2) -> jnp.ndarray:
     """ROIAlign on one level: feat ``[H, W, C]``, rois ``[N, 4]``
     (x1,y1,x2,y2 in image coords) → ``[N, out_size, out_size, C]``."""
-    rois = rois.astype(feat.dtype) * spatial_scale
+    # float32 coordinates even for bf16 features: bf16's 8 significant
+    # bits cannot place a sample on a 336-wide map (first chip run: the
+    # bf16 formulation was off by more than max|out| at 1344 px)
+    rois = rois.astype(jnp.float32) * spatial_scale
     x1, y1, x2, y2 = rois[:, 0], rois[:, 1], rois[:, 2], rois[:, 3]
     # aligned=True: -0.5 half-pixel offset
     roi_w = jnp.maximum(x2 - x1, 1e-4)
@@ -98,9 +105,9 @@ def roi_align(feat: jnp.ndarray, rois: jnp.ndarray, spatial_scale: float,
     bin_h = roi_h / out_size
     s = sampling_ratio
     # sample offsets within a bin: (i + 0.5)/s for i in [0, s)
-    frac = (jnp.arange(s, dtype=feat.dtype) + 0.5) / s
+    frac = (jnp.arange(s, dtype=jnp.float32) + 0.5) / s
     # bin index grid
-    bins = jnp.arange(out_size, dtype=feat.dtype)
+    bins = jnp.arange(out_size, dtype=jnp.float32)
     # y coords: [N, out, s] ; x coords: [N, out, s]
     ys = (y1[:, None, None] - 0.5
           + (bins[None, :, None] + frac[None, None, :]) * bin_h[:, None, None])
@@ -215,6 +222,41 @@ def batched_multilevel_roi_align(feats, rois, strides, out_size,
     return fn(tuple(feats), rois, levels)
 
 
+# (mesh, batch axes) of the program being traced, set by
+# ``ShardingPlan.jit`` — see ``batch_partition`` / ``_per_shard``
+_BATCH_PARTITION = contextvars.ContextVar("eksml_batch_partition",
+                                          default=None)
+
+
+@contextlib.contextmanager
+def batch_partition(mesh, batch_spec):
+    """Declare, for the duration of a trace, the mesh and the axes the
+    BATCH dimension is split over.  XLA's SPMD partitioner cannot split
+    a Mosaic kernel ("Mosaic kernels cannot be automatically
+    partitioned"), so on a multi-device mesh the kernel dispatch below
+    needs to know how to run once per batch shard."""
+    token = _BATCH_PARTITION.set((mesh, batch_spec[0]))
+    try:
+        yield
+    finally:
+        _BATCH_PARTITION.reset(token)
+
+
+def _per_shard(kernel, num_levels: int):
+    """``kernel(feats, rois)`` as one call per batch shard
+    (``jax.shard_map`` over the declared batch axes; ROIAlign is
+    independent per image, so this is exact).  Identity when no
+    multi-device partition is declared."""
+    declared = _BATCH_PARTITION.get()
+    if declared is None or declared[0].size == 1:
+        return kernel
+    mesh, axes = declared
+    return jax.shard_map(
+        kernel, mesh=mesh,
+        in_specs=((P(axes),) * num_levels, P(axes)),
+        out_specs=P(axes), check_vma=False)
+
+
 # "roi_align" scope → roi-fwd / roi-bwd (transpose context) in the
 # profiling attribution (eksml_tpu/profiling SCOPE_RULES)
 @jax.named_scope("roi_align")
@@ -238,9 +280,12 @@ def dispatch_roi_align(feats, rois, strides, out_size,
     dtype = feats[0].dtype
     img_extent = max(feats[0].shape[1], feats[0].shape[2]) * strides[0]
     coverage = (TILE - tile_margin(dtype)) * strides[-1]
-    if img_extent <= coverage and pallas_roi_align_supported(dtype):
-        return pallas_batched_multilevel_roi_align(
-            tuple(feats), rois, tuple(strides), out_size, sampling_ratio,
-            min_level)
+    if img_extent <= coverage and pallas_roi_align_supported():
+        def kernel(fs, r):
+            return pallas_batched_multilevel_roi_align(
+                fs, r, tuple(strides), out_size, sampling_ratio,
+                min_level)
+
+        return _per_shard(kernel, len(feats))(tuple(feats), rois)
     return batched_multilevel_roi_align(feats, rois, strides, out_size,
                                         sampling_ratio, min_level)

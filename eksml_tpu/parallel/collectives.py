@@ -4,16 +4,17 @@ The reference's collective layer is NCCL ring-allreduce orchestrated by
 Horovod with env-var tuning (HOROVOD_FUSION_THRESHOLD=64MB,
 NCCL_MIN_NRINGS=8 — charts/maskrcnn/values.yaml:24-28).  Under XLA the
 allreduce is *emitted by the compiler* from sharding annotations; what
-remains of that layer is (a) explicit collectives for host-side logic,
-(b) the fusion knob re-expressed as an XLA flag, and (c) the debug
-check the reference cannot do: asserting replicas actually agree
-(SURVEY.md §5.2).
+remains of that layer is (a) explicit collectives for host-side logic
+and (b) the debug check the reference cannot do: asserting replicas
+actually agree (SURVEY.md §5.2).  The fusion knob has no analogue here:
+libtpu 0.0.34 has no ``xla_tpu_all_reduce_combine_threshold_bytes``
+(it exits on the unknown flag), so XLA's own collective combining runs
+untuned and nothing in this package edits ``LIBTPU_INIT_ARGS``.
 """
 
 from __future__ import annotations
 
 import logging
-import os
 import time
 from typing import Dict
 
@@ -23,82 +24,6 @@ import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
 log = logging.getLogger(__name__)
-
-
-_FLAG_PROBE_SCRIPT = """
-import os, sys, time
-os.environ["LIBTPU_INIT_ARGS"] = sys.argv[1]
-import jax, jax.numpy as jnp, numpy as np
-# The verdict is only meaningful from a TPU compile: if this child
-# fell back to CPU (e.g. the parent holds the device lock on a real
-# TPU host), a passing trivial jit proves nothing about the flag —
-# exit nonzero so the parent REJECTS rather than poisons itself.
-if jax.default_backend() != "tpu":
-    sys.exit(2)
-nonce = np.float32(time.time_ns() % 100003 + 2)
-jax.block_until_ready(
-    jax.jit(lambda x: x * nonce)(jnp.ones((8,), jnp.float32)))
-"""
-
-
-def _flag_probe_subprocess(flag: str, timeout: float) -> bool:
-    """Compile a nonce constant in a CHILD process with ``flag`` in
-    LIBTPU_INIT_ARGS; True iff the compile succeeds.  The nonce forces
-    a persistent-cache miss so a real compile always runs."""
-    import subprocess
-    import sys
-
-    try:
-        return subprocess.run(
-            [sys.executable, "-c", _FLAG_PROBE_SCRIPT, flag],
-            timeout=timeout, capture_output=True).returncode == 0
-    except Exception:  # noqa: BLE001 — timeout/spawn failure = reject
-        return False
-
-
-def set_xla_collective_flags(combine_threshold_bytes: int,
-                             validate: bool = True) -> None:
-    """HOROVOD_FUSION_THRESHOLD analogue: how many bytes of gradient
-    all-reduce XLA combines into one collective.  Must run before the
-    backend compiles the train step.
-
-    The flag is VALIDATED in a SUBPROCESS when a TPU backend is live,
-    and only set in THIS process after the child proves the option
-    compiles.  Two hardware-observed failure modes force this design:
-    (1) a libtpu whose XLA revision doesn't know the option rejects
-    EVERY subsequent compile; (2) the round-5 session proved the
-    rejection is STICKY per process — after one failed compile with
-    the bad flag, stripping it from the env did not recover the
-    process (every later compile kept failing), so an in-process
-    validate-then-strip can itself take down training.  The verdict is
-    cached in ``EKSML_ALLREDUCE_FLAG_OK`` (inherited by children) so
-    one probe serves the process tree; an operator-set LIBTPU value
-    always wins."""
-    flags = os.environ.get("LIBTPU_INIT_ARGS", "")
-    if "all_reduce_combine_threshold" in flags:
-        return  # operator already decided
-    flag = (f"--xla_tpu_all_reduce_combine_threshold_bytes="
-            f"{combine_threshold_bytes}")
-    if validate:
-        try:
-            if jax.default_backend() != "tpu":
-                return
-        except Exception:  # noqa: BLE001 — backend init failure
-            return
-        verdict = os.environ.get("EKSML_ALLREDUCE_FLAG_OK")
-        if verdict is None:
-            timeout = float(os.environ.get(
-                "EKSML_FLAG_PROBE_TIMEOUT", "180"))
-            probe_flags = f"{flags} {flag}".strip()
-            verdict = ("1" if _flag_probe_subprocess(probe_flags,
-                                                     timeout) else "0")
-            os.environ["EKSML_ALLREDUCE_FLAG_OK"] = verdict
-        if verdict != "1":
-            log.warning(
-                "combine-threshold flag rejected by this libtpu — "
-                "running with XLA's default collective fusion")
-            return
-    os.environ["LIBTPU_INIT_ARGS"] = f"{flags} {flag}".strip()
 
 
 def warm_mesh_collectives(mesh: Mesh) -> None:

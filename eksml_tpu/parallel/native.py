@@ -2,8 +2,8 @@
 
 The reference's comm layer was native (NCCL ring construction, Horovod
 fusion buffering — SURVEY.md §5.8); here the compiled surface owns
-slice geometry, DCN ring ordering and combine-threshold sizing, with
-pure-python fallbacks so nothing requires the build.
+slice geometry and DCN ring ordering, with pure-python fallbacks so
+nothing requires the build.
 """
 
 from __future__ import annotations
@@ -29,9 +29,6 @@ def _declare(lib: ctypes.CDLL) -> None:
     lib.topo_chip_coords.restype = ctypes.c_int32
     lib.topo_host_ring.argtypes = [ctypes.c_char_p, i32p]
     lib.topo_host_ring.restype = ctypes.c_int32
-    lib.combine_threshold_bytes.argtypes = [ctypes.c_int64,
-                                            ctypes.c_int32]
-    lib.combine_threshold_bytes.restype = ctypes.c_int64
 
 
 _LIB = NativeLib(
@@ -88,10 +85,3 @@ def _host_ring_py(name: str) -> Optional[List[int]]:
     return order
 
 
-def recommend_combine_threshold(param_bytes: int, chips: int) -> int:
-    """HOROVOD_FUSION_THRESHOLD analogue, sized from model scale."""
-    lib = get_lib()
-    if lib is not None:
-        return int(lib.combine_threshold_bytes(param_bytes, chips))
-    t = max(4 << 20, min(param_bytes // 8, 64 << 20))
-    return t // 2 if chips > 256 else t

@@ -4,9 +4,7 @@
 // NCCL_SOCKET_IFNAME, reference charts/maskrcnn/values.yaml:26-28) and
 // Horovod's C++ fusion buffer sizing (HOROVOD_FUSION_THRESHOLD,
 // values.yaml:25) — re-expressed for ICI/DCN: slice geometry math,
-// per-host chip coordinates, DCN ring ordering across hosts, and
-// combine-threshold recommendation feeding
-// xla_tpu_all_reduce_combine_threshold_bytes.
+// per-host chip coordinates and DCN ring ordering across hosts.
 //
 // C ABI + ctypes (eksml_tpu/parallel/native.py); build:
 //   make -C eksml_tpu/parallel/native_src
@@ -105,21 +103,6 @@ int32_t topo_host_ring(const char* name, int32_t* out_order) {
     }
   }
   return n;
-}
-
-// Combine-threshold recommendation (bytes) — the HOROVOD_FUSION_
-// THRESHOLD analogue, sized so each fused allreduce amortizes ICI
-// latency without starving overlap: clamp param_bytes/8 into
-// [4 MiB, 64 MiB], halved for slices spanning DCN (>256 chips here,
-// single-slice v5e otherwise) where latency is higher but overlap
-// windows shorter.
-int64_t combine_threshold_bytes(int64_t param_bytes, int32_t chips) {
-  int64_t t = param_bytes / 8;
-  const int64_t lo = 4LL << 20, hi = 64LL << 20;
-  if (t < lo) t = lo;
-  if (t > hi) t = hi;
-  if (chips > 256) t /= 2;
-  return t;
 }
 
 }  // extern "C"

@@ -72,6 +72,7 @@ the gradients instead of bounding a flat all-replica ring
 
 from __future__ import annotations
 
+import functools
 import logging
 import re
 from typing import Any, Dict, List, Optional, Sequence, Tuple
@@ -696,11 +697,18 @@ class ShardingPlan:
 
     def jit(self, fn, **jit_kwargs):
         """``jax.jit`` behind the plan: the single place strategy
-        executability would be enforced (SNIPPETS.md [3]).  Every
-        strategy in :data:`STRATEGIES` is executable since the
-        tensor/2d plans landed — the wrapper stays so a future
-        skeleton strategy has somewhere to refuse."""
-        return jax.jit(fn, **jit_kwargs)
+        executability is enforced (SNIPPETS.md [3]).  The trace runs
+        under ``batch_partition`` so the Pallas ROIAlign dispatch can
+        run once per batch shard — the SPMD partitioner refuses a bare
+        Mosaic kernel on a multi-device mesh."""
+        from eksml_tpu.ops.roi_align import batch_partition
+
+        @functools.wraps(fn)
+        def traced(*args, **kwargs):
+            with batch_partition(self.mesh, self.batch_spec):
+                return fn(*args, **kwargs)
+
+        return jax.jit(traced, **jit_kwargs)
 
     # -- introspection ------------------------------------------------
 

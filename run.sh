@@ -51,8 +51,8 @@ fi
 echo "Training started:" `date '+%Y-%m-%d-%H-%M-%S'`
 
 # the argv shape below mirrors reference run.sh:33-45; TRAINER=horovod
-# becomes TRAINER=spmd, the NCCL/Horovod env tuning becomes
-# TPU.ALLREDUCE_COMBINE_THRESHOLD_BYTES (same 64MB default)
+# becomes TRAINER=spmd; the NCCL/Horovod env tuning has no analogue
+# (XLA combines the gradient all-reduces itself)
 python3 -m eksml_tpu.train \
   --logdir $LOG_DIR/$RUN_ID/train_log/maskrcnn \
   $SYNTH_FLAG \

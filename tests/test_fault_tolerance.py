@@ -111,7 +111,7 @@ def compile_cache(tmp_path_factory):
 def _launch(logdir, cache_dir, log_path, config=TINY, synthetic=True,
             extra_env=None):
     env = dict(os.environ)
-    env.update({"EKSML_PLATFORM": "cpu",
+    env.update({"JAX_PLATFORMS": "cpu",
                 "JAX_COMPILATION_CACHE_DIR": cache_dir})
     env.update(extra_env or {})
     cmd = [sys.executable, "-m", "eksml_tpu.train", "--logdir", logdir]
@@ -826,7 +826,7 @@ def test_operator_capacity_wave(tmp_path, compile_cache):
     train_cfg = [c for c in TINY if "MAX_EPOCHS" not in c] + [
         "TRAIN.MAX_EPOCHS=40", "TRAIN.SHARDING.STRATEGY=fsdp"]
     env = dict(os.environ)
-    env.update({"EKSML_PLATFORM": "cpu",
+    env.update({"JAX_PLATFORMS": "cpu",
                 "JAX_COMPILATION_CACHE_DIR": compile_cache})
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     cmd = [sys.executable, os.path.join(repo, "tools",

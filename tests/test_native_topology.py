@@ -8,9 +8,7 @@ import numpy as np
 import pytest
 
 from eksml_tpu.parallel.mesh import TOPOLOGIES, validate_topology
-from eksml_tpu.parallel.native import (get_lib, host_ring,
-                                       recommend_combine_threshold,
-                                       topo_lookup)
+from eksml_tpu.parallel.native import get_lib, host_ring, topo_lookup
 
 
 def test_native_lib_builds():
@@ -63,17 +61,3 @@ def test_native_validate_matches_python():
         if chips > 0:
             with pytest.raises(ValueError):
                 validate_topology(num_chips=chips)
-
-
-def test_combine_threshold_bounds():
-    mb = 1024 * 1024
-    # small model → floor
-    assert recommend_combine_threshold(1 * mb, 32) == 4 * mb
-    # R50-scale (180 MB) → ~22 MB, inside [4, 64] MB
-    t = recommend_combine_threshold(180 * mb, 32)
-    assert 4 * mb <= t <= 64 * mb
-    # huge model → ceiling
-    assert recommend_combine_threshold(10_000 * mb, 32) == 64 * mb
-    # DCN-spanning slices halve it
-    assert (recommend_combine_threshold(10_000 * mb, 512)
-            == 32 * mb)

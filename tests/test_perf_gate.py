@@ -36,8 +36,9 @@ def test_chip_spec_lookup():
         P.chip_spec("v99")
     assert "v5e" in str(e.value)  # the error names the valid targets
     assert P.target_for_device_kind("TPU v5 lite") == "v5e"
-    assert P.target_for_device_kind("cpu") is None
-    assert P.target_for_device_kind(None) is None
+    for unknown in ("cpu", None, "TPU v9"):
+        with pytest.raises(ValueError, match="no chip spec"):
+            P.target_for_device_kind(unknown)
 
 
 # ---- roofline on a hand-rolled module --------------------------------
@@ -128,10 +129,10 @@ def test_predict_for_compiled_single_entry_point():
     device kind, comm sizes from the mesh, and DCN once the ring spans
     more devices than one slice holds."""
     one_slice = P.predict_for_compiled(
-        HLO_FIXTURE, device_kind="cpu",
+        HLO_FIXTURE, device_kind="TPU v5 lite",
         mesh_shape={"data": 4, "fsdp": 1, "model": 1},
         precision="float32", num_slices=1)
-    assert one_slice["target"] == P.DEFAULT_TARGET  # unknown kind
+    assert one_slice["target"] == "v5e"
     assert one_slice["comm_sizes"]["all-reduce"] == 4
     # 2 slices x 2 devices: the 4-wide all-reduce crosses the slice
     # boundary and prices against the DCN NIC
@@ -142,6 +143,17 @@ def test_predict_for_compiled_single_entry_point():
     assert two_slice["target"] == "v5e"
     assert (two_slice["sections_ms"]["comms"]
             > one_slice["sections_ms"]["comms"])
+
+
+@pytest.mark.parametrize("kind", ["cpu", None, "TPU v9 (unreleased)"])
+def test_predict_for_compiled_unknown_device_kind_is_an_error(kind):
+    """A program is priced for the chip it ran on or not at all: an
+    unknown ``device_kind`` (a CPU included) raises instead of
+    borrowing the v5e row."""
+    with pytest.raises(ValueError, match="no chip spec"):
+        P.predict_for_compiled(HLO_FIXTURE, device_kind=kind,
+                               mesh_shape={"data": 1},
+                               precision="bfloat16")
 
 
 def test_comm_sizes_for_mesh():
@@ -306,7 +318,7 @@ def test_update_baseline_writes_under_record_key(tmp_path,
 def test_calibration_points_glob_route_filters(tmp_path):
     """Self-calibrating rung artifacts pair via the glob route —
     except forward-only micro rungs (dispatch-overhead-dominated,
-    the bank_round.py comparability rule) and error rounds."""
+    the comparability rule) and error rounds."""
     rec = {"operating_point": "512_b1", "step_time_ms": 100.0,
            "predicted_step_time_ms": 10.0, "status": "ok"}
     (tmp_path / "bench_rung_512_b1.json").write_text(json.dumps(rec))

@@ -141,3 +141,30 @@ def test_roi_chunking_prime_n_warns(monkeypatch, caplog):
         assert ra._chunk_size(512) == 128   # clean divisor: silent
         assert ra._chunk_size(64) is None   # within bound: silent
     assert not caplog.records
+
+
+def test_bf16_features_keep_float32_coordinates():
+    """bf16 has 8 significant bits: a sample coordinate near 300 on a
+    336-wide level-0 map would land a pixel or two off, and the XLA
+    formulation — the reference every kernel check compares against,
+    and what every non-TPU bf16 run computes — was off by more than
+    max|out| at the 1344 px canvas (first chip run, PR 21).  The ROI
+    coordinates and tap weights stay float32; only values are bf16."""
+    import numpy as np
+
+    from eksml_tpu.ops.roi_align import batched_multilevel_roi_align
+
+    rng = np.random.RandomState(0)
+    img, strides = 1344, (4, 8, 16, 32)
+    feats = tuple(jnp.asarray(rng.randn(1, img // s, img // s, 8),
+                              jnp.bfloat16) for s in strides)
+    side = np.exp(rng.uniform(np.log(16), np.log(img * 0.9), (1, 32, 2)))
+    xy = rng.uniform(0, 1, (1, 32, 2)) * (img - 1 - side)
+    rois = jnp.asarray(np.concatenate([xy, xy + side], -1), jnp.float32)
+    out = batched_multilevel_roi_align(feats, rois, strides, 7)
+    ref = batched_multilevel_roi_align(
+        tuple(f.astype(jnp.float32) for f in feats), rois, strides, 7)
+    assert out.dtype == jnp.bfloat16
+    err = float(jnp.abs(out.astype(jnp.float32) - ref).max()
+                / jnp.abs(ref).max())
+    assert err < 2e-2, err

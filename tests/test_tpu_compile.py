@@ -1,0 +1,151 @@
+"""AOT compiles of the Pallas ROIAlign kernels for a DESCRIBED v5e.
+
+The TPU compiler is installed in the sandbox and compiles for a chip
+that is described, not attached (``on-chip-measurement`` guide §2.3), so
+what Mosaic would refuse on the chip — a misaligned slice, too much
+scoped vmem — is refused here at no chip time.  This is the stronger
+form of the old on-hardware compile probe: the kernels of the training
+main path at the PRODUCTION shapes (batch 4, 1344² FPN levels, C=256,
+the box head's 512 ROIs × 7² and the mask head's 128 ROIs × 14², bf16;
+batch 1 in f32), forward and both backward variants.
+
+A compile that passes is not a chip run: numeric agreement on the chip
+is ``chip_smoke.py``'s kernel phase.
+
+The topology is described inside a module-scoped fixture (only the
+xdist worker that is given this file loads libtpu), never at import;
+the persistent compile cache is off around these compiles because an
+entry written for a described chip cannot be read back without one.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from eksml_tpu.ops.pallas import pallas_batched_multilevel_roi_align
+
+STRIDES = (4, 8, 16, 32)
+CANVAS = 1344
+CHANNELS = 256
+HEADS = {"box": (512, 7), "mask": (128, 14)}  # ROIs/image, out_size
+BATCH = {"bfloat16": 4, "float32": 1}
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _shapes(head, dtype, sharding):
+    n, out_size = HEADS[head]
+    b = BATCH[dtype]
+    feats = tuple(jax.ShapeDtypeStruct(
+        (b, CANVAS // s, CANVAS // s, CHANNELS), jnp.dtype(dtype),
+        sharding=sharding) for s in STRIDES)
+    rois = jax.ShapeDtypeStruct((b, n, 4), jnp.float32,
+                                sharding=sharding)
+    return feats, rois, out_size
+
+
+def _kernel_sites(compiled) -> int:
+    return compiled.as_text().count("tpu_custom_call")
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("head", ["box", "mask"])
+def test_forward_compiles_for_v5e(one_chip, head, dtype):
+    feats, rois, out_size = _shapes(head, dtype, one_chip)
+
+    def fwd(fs, r):
+        return pallas_batched_multilevel_roi_align(
+            fs, r, STRIDES, out_size, 2, 2)
+
+    compiled = jax.jit(fwd).lower(feats, rois).compile()
+    assert _kernel_sites(compiled) >= 1
+
+
+@pytest.mark.parametrize("overlap", ["1", "0"])
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("head", ["box", "mask"])
+def test_grad_compiles_for_v5e(one_chip, monkeypatch, head, dtype,
+                               overlap):
+    """Forward + the transpose kernel, async write-back pipeline on
+    (the default) and off.  The backward gate asks
+    ``jax.default_backend()``, which is the CPU here, so the test
+    steers it through the existing ``EKSML_ROI_BWD`` switch."""
+    monkeypatch.setenv("EKSML_ROI_BWD", "pallas")
+    monkeypatch.setenv("EKSML_BWD_OVERLAP", overlap)
+    feats, rois, out_size = _shapes(head, dtype, one_chip)
+
+    def loss(fs, r):
+        out = pallas_batched_multilevel_roi_align(
+            fs, r, STRIDES, out_size, 2, 2)
+        return out.astype(jnp.float32).sum()
+
+    compiled = jax.jit(jax.grad(loss)).lower(feats, rois).compile()
+    # the forward is dead code under grad-of-sum; what must be there is
+    # the backward's read-modify-write kernel (plus its HBM laundering)
+    assert _kernel_sites(compiled) >= 1
+
+
+@pytest.fixture(scope="module")
+def four_chip_mesh(topo):
+    import numpy as np
+    from jax.sharding import Mesh
+
+    return Mesh(np.array(topo.devices).reshape(4, 1), ("data", "model"))
+
+
+@pytest.mark.parametrize("head", ["box", "mask"])
+def test_dispatch_compiles_on_a_four_chip_mesh(four_chip_mesh,
+                                               monkeypatch, head):
+    """The data-parallel program: batch 4 split over the (4, 1) mesh.
+    XLA's SPMD partitioner refuses a bare Mosaic kernel there; under
+    ``batch_partition`` (what ``ShardingPlan.jit`` declares) the
+    dispatch runs the kernel once per batch shard and the program
+    compiles, forward and backward."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from eksml_tpu.ops.roi_align import (batch_partition,
+                                         dispatch_roi_align)
+
+    monkeypatch.setenv("EKSML_ROI_BACKEND", "pallas")
+    monkeypatch.setenv("EKSML_ROI_BWD", "pallas")
+    spec = P(("data", "model"))
+    feats, rois, out_size = _shapes(
+        head, "bfloat16", NamedSharding(four_chip_mesh, spec))
+
+    def loss(fs, r):
+        return dispatch_roi_align(fs, r, STRIDES, out_size).astype(
+            jnp.float32).sum()
+
+    def declared(fs, r):
+        with batch_partition(four_chip_mesh, spec):
+            return jax.value_and_grad(loss)(fs, r)
+
+    compiled = jax.jit(declared).lower(feats, rois).compile()
+    assert _kernel_sites(compiled) >= 2  # forward and backward
+    with pytest.raises(NotImplementedError, match="partitioned"):
+        jax.jit(loss).lower(feats, rois).compile()
