@@ -1,0 +1,69 @@
+"""Smoke-width cells for the CPU tests of the benchmark: the harness's
+own path (``harness.run_cell``) at ``config.SMOKE_OVERRIDES`` widths in
+float32.  Never a device number."""
+
+import json
+import os
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
+CPU_PEAK = {"bf16_flops_per_s": 1e12, "hbm_bytes_per_s": 1e11,
+            "hbm_bytes": 16e9}
+
+def smoke_overrides(batch_per_chip):
+    from eksml_tpu.config import SMOKE_OVERRIDES
+
+    return list(SMOKE_OVERRIDES) + [
+        "TRAIN.PRECISION=float32", "DATA.NUM_WORKERS=0",
+        f"TRAIN.BATCH_SIZE_PER_CHIP={batch_per_chip}"]
+
+
+SMOKE_TRAFFIC = {
+    "records": 16, "sizes": [[96, 128, 0.70], [128, 96, 0.25],
+                             [128, 128, 0.05]],
+    "instances": [1, 5], "polygon_vertices": 12, "box_side_px": [8, 60],
+    "num_classes": 5}
+
+# what decides `correct` at smoke widths in float32 on the CPU: both
+# sides compute in float32, so the gaps are reduction order and the
+# rare discrete flip of a threshold; see test_reference_parity.py
+SMOKE_LIMITS = {"rpn_loss_step1": 1e-5, "loss_step1": 1e-4,
+                "loss_step2": 1e-3, "loss_step3": 1e-3,
+                "first_grad_worst_leaf": 1e-3, "first_grad_median_leaf": 1e-4,
+                "delta3_worst_leaf": 1e-2, "delta3_median_leaf": 1e-3,
+                "frozen_moved": 0.0}
+
+
+def smoke_cell(mask: bool, chips: int = 1, limits=None,
+               batch_per_chip: int = 2):
+    from benchmark import harness
+
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        manifest = json.load(f)
+    name = "maskrcnn-r50-fpn" if mask else "fasterrcnn-r50-fpn"
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           f"{name}.json")) as f:
+        config = json.load(f)
+    model = dict(config["model"], canvas=[128, 128], resnet_blocks=[1, 1, 1, 1],
+                 fpn_channels=32, rpn_pre_nms_topk=64, rpn_post_nms_topk=32,
+                 frcnn_batch_per_im=16, fc_head_dim=64, num_classes=5,
+                 mask_head_dim=16, max_gt_boxes=8,
+                 # the CPU runs the program's XLA ROIAlign, which keeps
+                 # the plain FPN level rule: no tile to fit
+                 roi_tile_usable=1e9)
+    config = dict(config, model=model, precision="float32",
+                  batch_per_chip=batch_per_chip,
+                  overrides=[o for o in config["overrides"]
+                             if not o.startswith(("TRAIN.PRECISION",
+                                                  "TRAIN.BATCH_SIZE",
+                                                  "PREPROC.MAX_SIZE"))]
+                  + smoke_overrides(batch_per_chip))
+    workload = {"name": "smoke", "config": name, "chips": chips,
+                "traffic": SMOKE_TRAFFIC, "warmup_steps": 4,
+                "follow_steps": 3, "trace_steps": 3,
+                "limits": dict(SMOKE_LIMITS if limits is None else limits)}
+    return harness.Cell(name="smoke", chips=chips, config=config,
+                        workload=workload,
+                        end_to_end=manifest["end_to_end"],
+                        per_layer=manifest["per_layer"])
