@@ -287,11 +287,23 @@ SHARDING_DEFAULTS = dict(
 #
 # - ENABLED: install the per-host span tracer (context-manager spans
 #   through the hot path → bounded ring → Chrome-trace JSON at
-#   <logdir>/trace-host<i>.json).  Off = the span API is a true no-op
-#   (shared null context manager, no allocation).
+#   <logdir>/trace-host<i>.json).  A live span is also a
+#   jax.profiler.TraceAnnotation, so inside a profiler session
+#   (/debugz/profile, --profile) the .xplane.pb carries the spans on
+#   the profiler's clock: data_wait (step, seq), globalize_batch,
+#   train_step (a StepTraceAnnotation), loss_sync (the log step's wait
+#   for the device), host_metrics, host_aggregate,
+#   checkpoint_save/restore, eval, the producer threads' batch_build
+#   (seq, rows) and h2d_prefetch (seq), and device_step (step): one
+#   per step, ended by a stamper thread that blocks on the step's
+#   loss, so each step has a completion time with no sync in the
+#   loop.  Off = the span API is a true no-op (shared null context
+#   manager, no allocation, no annotation, no stamper thread).
 # - RING_EVENTS: span ring capacity (memory bound; oldest spans drop).
 # - PROFILE_STEPS: steps per on-demand/anomaly capture when the
-#   /debugz/profile request doesn't name its own count.
+#   /debugz/profile request doesn't name its own count.  Every capture
+#   starts the profiler with the Python tracer off (the spans'
+#   annotations are its host timeline): not a knob.
 # - PROFILE_COOLDOWN_SEC / MAX_CAPTURES_PER_RUN: the ProfileTrigger
 #   guard rails — a flapping alert or curious operator cannot chain
 #   captures back to back or fill the shared fs with trace dumps.

@@ -666,60 +666,61 @@ class DetectionLoader:
             try:
                 while not stop.is_set() and (num_steps is None
                                              or produced < num_steps):
-                    t_build = time.monotonic()
-                    t_span = time.perf_counter()
-                    self._heal_proc_pool()  # no-op unless a break is pending
-                    pad_hw, idx = self._next_bucket_batch()
-                    recs = [self.records[i] for i in idx]
-                    draws = [self._draw() for _ in idx]
-                    # futures pass through to _load_example so each
-                    # augment thread waits only on ITS record's decode
-                    # — decode and resize/augment overlap instead of
-                    # running as serial per-batch stages
-                    images = [None] * len(recs)
-                    if self._proc_pool is not None:
-                        try:
-                            for i, r in enumerate(recs):
-                                # known-bad records substitute in
-                                # _materialize (decoding them again in
-                                # a subprocess is pure wasted work);
-                                # injection-targeted paths stay inline
-                                # so the chaos hook fires even with a
-                                # process pool
-                                if (r.get("_image") is None
-                                        and not self._ledger
-                                        .is_quarantined(
-                                            r.get("image_id"))
-                                        and not self._reader
-                                        .matches_injection(r["path"])):
-                                    images[i] = self._proc_pool.submit(
-                                        load_image, r["path"])
-                        except BrokenProcessPool:
-                            # pool died between batches: flag for the
-                            # next heal; unsubmitted records decode
-                            # inline this batch
-                            self._note_pool_break()
-                    if pool is not None:
-                        exs = list(pool.map(
-                            self._load_example, recs,
-                            [d[0] for d in draws], [d[1] for d in draws],
-                            [pad_hw] * len(recs), images))
-                    else:
-                        exs = [self._load_example(r, s, f, pad_hw, img)
-                               for r, (s, f), img
-                               in zip(recs, draws, images)]
-                    batch = {k: np.stack([e[k] for e in exs])
-                             for k in exs[0].keys()}
-                    self.health.record_batch(
-                        (time.monotonic() - t_build) * 1000)
-                    # producer-lane span (no step: the producer runs
-                    # ahead of the step counter; seq joins batches in
-                    # the timeline).  Recorded BEFORE the queue put —
+                    # producer-lane span, started and ended on this
+                    # thread around the build (no step: the producer
+                    # runs ahead of the step counter; seq joins the
+                    # batch's batch_build, h2d_prefetch and data_wait
+                    # in the timeline).  It ends BEFORE the queue put —
                     # blocking on a full queue is healthy back-
                     # pressure, not build time.
-                    telemetry.complete_span("batch_build", t_span,
-                                            time.perf_counter(),
-                                            seq=produced)
+                    with telemetry.span("batch_build", attrs={
+                            "seq": produced, "rows": self.batch_size}):
+                        t_build = time.monotonic()
+                        # no-op unless a pool break is pending
+                        self._heal_proc_pool()
+                        pad_hw, idx = self._next_bucket_batch()
+                        recs = [self.records[i] for i in idx]
+                        draws = [self._draw() for _ in idx]
+                        # futures pass through to _load_example so each
+                        # augment thread waits only on ITS record's decode
+                        # — decode and resize/augment overlap instead of
+                        # running as serial per-batch stages
+                        images = [None] * len(recs)
+                        if self._proc_pool is not None:
+                            try:
+                                for i, r in enumerate(recs):
+                                    # known-bad records substitute in
+                                    # _materialize (decoding them again in
+                                    # a subprocess is pure wasted work);
+                                    # injection-targeted paths stay inline
+                                    # so the chaos hook fires even with a
+                                    # process pool
+                                    if (r.get("_image") is None
+                                            and not self._ledger
+                                            .is_quarantined(
+                                                r.get("image_id"))
+                                            and not self._reader
+                                            .matches_injection(r["path"])):
+                                        images[i] = self._proc_pool.submit(
+                                            load_image, r["path"])
+                            except BrokenProcessPool:
+                                # pool died between batches: flag for the
+                                # next heal; unsubmitted records decode
+                                # inline this batch
+                                self._note_pool_break()
+                        if pool is not None:
+                            exs = list(pool.map(
+                                self._load_example, recs,
+                                [d[0] for d in draws], [d[1] for d in draws],
+                                [pad_hw] * len(recs), images))
+                        else:
+                            exs = [self._load_example(r, s, f, pad_hw, img)
+                                   for r, (s, f), img
+                                   in zip(recs, draws, images)]
+                        batch = {k: np.stack([e[k] for e in exs])
+                                 for k in exs[0].keys()}
+                        self.health.record_batch(
+                            (time.monotonic() - t_build) * 1000)
                     if not put_or_stop(batch):
                         return
                     produced += 1
@@ -860,13 +861,12 @@ class DevicePrefetcher:
             for host_batch in it:
                 if self._stop.is_set():
                     return
-                t0 = time.perf_counter()
-                item = self._transfer(host_batch)
-                # transfer-lane span: the H2D copy overlapping (or
-                # not) the device's current step is the whole point
-                # of the prefetcher — now visible in the timeline
-                telemetry.complete_span("h2d_prefetch", t0,
-                                        time.perf_counter(), seq=seq)
+                # transfer-lane span, on this thread around the
+                # transfer alone (not the queue put): the H2D copy
+                # overlapping (or not) the device's current step is
+                # the whole point of the prefetcher
+                with telemetry.span("h2d_prefetch", attrs={"seq": seq}):
+                    item = self._transfer(host_batch)
                 seq += 1
                 if not self._put(item):
                     return

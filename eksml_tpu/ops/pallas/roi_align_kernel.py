@@ -573,6 +573,7 @@ def _pallas_forward(feats, rois, strides, out_size, sampling, min_level,
                 (n_rois, out_size, out_pad, c), feats[0].dtype),
             compiler_params=_compiler_params(),
             interpret=interpret,
+            name="roi_align_fwd",
         )(*chunk_scalars, *feats)
 
     if chunk == b * n:
@@ -632,6 +633,7 @@ def _to_hbm(x):
         # a >16 MiB input XLA elects to keep vmem-resident must not
         # bust THIS kernel's stack check either
         compiler_params=_compiler_params(),
+        name="roi_align_seed_copy",
     )(x)
 
 
@@ -742,6 +744,7 @@ def _pallas_backward(feats, rois, g, strides, out_size, sampling,
             input_output_aliases={9 + i: i for i in range(num_levels)},
             compiler_params=_compiler_params(extra_bytes=2 * extra),
             interpret=interpret,
+            name="roi_align_bwd",
         )(*chunk_scalars, g_chunk, *accs)
 
     # Pin the LARGEST accumulator levels to HBM (colored out avals +

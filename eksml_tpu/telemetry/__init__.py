@@ -55,8 +55,8 @@ from eksml_tpu.telemetry.recorder import (FlightRecorder,  # noqa: F401
 from eksml_tpu.telemetry.registry import (MetricRegistry,  # noqa: F401
                                           default_registry)
 from eksml_tpu.telemetry.tracing import (AnomalyDetector,  # noqa: F401
-                                         ProfileTrigger, Tracer,
-                                         complete_span, get_tracer,
-                                         install_span_sink,
+                                         ProfileTrigger, StepStamper,
+                                         Tracer, complete_span,
+                                         get_tracer, install_span_sink,
                                          install_tracer, span,
-                                         trace_path_for, traced)
+                                         trace_path_for)

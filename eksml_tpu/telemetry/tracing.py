@@ -8,13 +8,23 @@ moment it ends.  This module is the time-domain layer, following the
 span model of Dapper (Sigelman et al., 2010) and the capture-on-demand
 workflow of the TPU/XProf profiler:
 
-- **Spans** (:func:`span` / :func:`traced`): ~µs-overhead wall-clock
-  intervals recorded into a bounded per-host ring
-  (:class:`Tracer`), each carrying ``step``/``host`` attributes so it
-  joins against flight-recorder events and metric rows.  With no
-  tracer installed (or ``enabled=False``) the module-level API is a
-  TRUE no-op: it returns one shared null context manager and
-  allocates nothing.
+- **Spans** (:func:`span`): ~µs-overhead wall-clock intervals
+  recorded into a bounded per-host ring (:class:`Tracer`), each
+  carrying ``step``/``host`` attributes so it joins against
+  flight-recorder events and metric rows.  A live span is ALSO a
+  ``jax.profiler.TraceAnnotation`` of the same name (``step`` and the
+  attrs as its stats), entered on the thread that does the work: while
+  a profiler session is open (``/debugz/profile``, the benchmark's
+  capture) the host lines of the ``.xplane.pb`` carry the program's
+  phases on the profiler's clock, beside the device's ``XLA Ops``;
+  outside a session the annotation is a flag check.  With no tracer
+  installed (or ``enabled=False``) the module-level API is a TRUE
+  no-op: it returns one shared null context manager and allocates
+  nothing — no annotation either.
+- **Step completion stamps** (:class:`StepStamper`): one daemon thread
+  that blocks on each step's loss in turn inside a ``device_step``
+  span, so every step has a completion time without a sync in the
+  step loop.  Exists only while a traced ``fit`` runs.
 - **Trace files**: :meth:`Tracer.flush` writes the ring as
   Chrome-trace-event/Perfetto-compatible JSON to
   ``<logdir>/trace-host<i>.json`` (``pid`` = host, ``tid`` = thread),
@@ -32,8 +42,9 @@ workflow of the TPU/XProf profiler:
   persistent straggler survives K consecutive log intervals — the
   trace of a production incident exists *before* anyone is paged.
 
-Everything is stdlib-only and fails soft: tracing must never take
-down training.
+Everything fails soft: tracing must never take down training.  ``jax``
+is imported lazily (first live span); where it cannot be imported, or
+an annotation cannot be entered, spans stay ring-only.
 """
 
 from __future__ import annotations
@@ -42,11 +53,11 @@ import collections
 import json
 import logging
 import os
+import queue
 import sys
 import threading
 import time
 import traceback
-from functools import wraps
 from typing import Callable, Dict, List, Optional, Tuple
 
 log = logging.getLogger(__name__)
@@ -61,24 +72,71 @@ def trace_path_for(logdir: Optional[str], host_id: int) -> Optional[str]:
     return os.path.join(logdir, f"trace-host{host_id}.json")
 
 
-class _Span:
-    """One active span; records a complete ('X') event on exit."""
+# ``jax.profiler`` once a live span has asked for it; False where it
+# cannot be imported (spans are ring-only from then on)
+_profiler = None
 
-    __slots__ = ("_tracer", "name", "step", "attrs", "_t0")
+
+def _annotation(name: str, step: Optional[int], attrs: Optional[Dict],
+                step_trace: bool):
+    """The span's twin on the profiler's clock, entered, or None."""
+    global _profiler
+    if _profiler is None:
+        try:
+            import jax.profiler as _profiler
+        except Exception:  # noqa: BLE001 — ring-only from here on
+            log.warning("jax.profiler not importable: spans stay "
+                        "ring-only", exc_info=True)
+            _profiler = False
+    if not _profiler:
+        return None
+    stats = dict(attrs) if attrs else {}
+    if step is not None:
+        stats["step"] = int(step)
+    try:
+        if step_trace:
+            ann = _profiler.StepTraceAnnotation(
+                name, step_num=stats.get("step", 0), **stats)
+        else:
+            ann = _profiler.TraceAnnotation(name, **stats)
+        ann.__enter__()
+        return ann
+    except Exception:  # noqa: BLE001 — observability only
+        log.debug("profiler annotation %r failed", name, exc_info=True)
+        return None
+
+
+class _Span:
+    """One active span; records a complete ('X') event on exit and is
+    a profiler annotation of the same name in between."""
+
+    __slots__ = ("_tracer", "name", "step", "attrs", "_step_trace",
+                 "_ann", "_t0")
 
     def __init__(self, tracer: "Tracer", name: str,
-                 step: Optional[int], attrs: Optional[Dict]):
+                 step: Optional[int], attrs: Optional[Dict],
+                 step_trace: bool = False):
         self._tracer = tracer
         self.name = name
         self.step = step
         self.attrs = attrs
+        self._step_trace = step_trace
 
     def __enter__(self) -> "_Span":
+        self._ann = _annotation(self.name, self.step, self.attrs,
+                                self._step_trace)
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> None:
-        self._tracer._complete(self.name, self._t0, time.perf_counter(),
+        t1 = time.perf_counter()
+        if self._ann is not None:
+            try:
+                self._ann.__exit__(*exc)
+            except Exception:  # noqa: BLE001 — observability only
+                log.debug("profiler annotation %r failed on exit",
+                          self.name, exc_info=True)
+        self._tracer._complete(self.name, self._t0, t1,
                                self.step, self.attrs)
 
 
@@ -124,10 +182,10 @@ class Tracer:
     # -- recording -----------------------------------------------------
 
     def span(self, name: str, step: Optional[int] = None,
-             attrs: Optional[Dict] = None):
+             attrs: Optional[Dict] = None, step_trace: bool = False):
         if not self.enabled:
             return NULL_SPAN
-        return _Span(self, name, step, attrs)
+        return _Span(self, name, step, attrs, step_trace)
 
     def _ts_us(self, perf_t: float) -> float:
         return self._epoch_wall_us + (perf_t - self._epoch_perf) * 1e6
@@ -244,42 +302,84 @@ def get_tracer() -> Optional[Tracer]:
 
 
 def span(name: str, step: Optional[int] = None,
-         attrs: Optional[Dict] = None):
+         attrs: Optional[Dict] = None, step_trace: bool = False):
     """Context manager timing one named interval through the installed
-    tracer.  Without one (or with tracing disabled) this returns the
-    SHARED null span — no allocation, no lock, ~100 ns."""
+    tracer, in the ring and (inside a profiler session) in the
+    profiler's trace.  ``step_trace`` makes the annotation a
+    ``StepTraceAnnotation`` (the step's dispatch: the profiler groups
+    its per-step analysis by it).  Without a tracer (or with tracing
+    disabled) this returns the SHARED null span — no allocation, no
+    annotation, no lock, ~100 ns."""
     t = _tracer
     if t is None or not t.enabled:
         return NULL_SPAN
-    return _Span(t, name, step, attrs)
+    return _Span(t, name, step, attrs, step_trace)
 
 
 def complete_span(name: str, t0: float, t1: float,
                   step: Optional[int] = None, **attrs) -> None:
     """Record an already-measured interval (``time.perf_counter``
-    endpoints) as a span — for producer threads that time their work
-    anyway and must not hold a context manager across a blocking
-    queue put.  No-op without an installed tracer."""
+    endpoints) as a span — for intervals that start on another thread
+    than the one that ends them (the serve batcher's ``queue_wait``),
+    which no context manager can hold.  Ring-only: an annotation has to
+    be entered and left on one thread.  No-op without an installed
+    tracer."""
     t = _tracer
     if t is None or not t.enabled:
         return
     t._complete(name, t0, t1, step, attrs or None)
 
 
-def traced(name: Optional[str] = None) -> Callable:
-    """Decorator form of :func:`span` (span name defaults to the
-    function's qualified name)."""
-    def deco(fn: Callable) -> Callable:
-        span_name = name or fn.__qualname__
+class StepStamper:
+    """Per-step completion times without a sync in the step loop.
 
-        @wraps(fn)
-        def wrapper(*a, **kw):
-            with span(span_name):
-                return fn(*a, **kw)
+    ``fit`` hands each step's loss (a device scalar the step returns,
+    never donated) with its step number to :meth:`stamp`; one daemon
+    thread takes them in order and blocks on each inside a
+    ``device_step`` span.  The span starts at the later of the previous
+    step's completion and the hand-over and ends when step ``n`` is
+    done on the device, so consecutive ends are device step times; it
+    lands in the ring and, inside a profiler session, on the
+    profiler's clock.  Created only by a traced ``fit``."""
 
-        return wrapper
+    _DONE = object()
+    ERROR_EXIT_TIMEOUT_SEC = 30.0
 
-    return deco
+    def __init__(self, wait: Callable[[object], object]):
+        self._wait = wait
+        self._q: "queue.SimpleQueue" = queue.SimpleQueue()
+        self._thread = threading.Thread(
+            target=self._run, daemon=True, name="step-stamper")
+        self._thread.start()
+
+    def stamp(self, step: int, value) -> None:
+        self._q.put((step, value))
+
+    def _run(self) -> None:
+        while True:
+            item = self._q.get()
+            if item is self._DONE:
+                return
+            step, value = item
+            try:
+                with span("device_step", step=step):
+                    self._wait(value)
+            except Exception:  # noqa: BLE001 — the loop reports it
+                # a step that failed on the device fails the step
+                # loop's own sync too, with the traceback that matters
+                log.debug("device_step %s: wait failed", step,
+                          exc_info=True)
+
+    def close(self, failed: bool = False) -> None:
+        """Stamp what is queued (the device drains), then stop.  On the
+        way out of an error (``failed``) the wait is limited: a wedged
+        device must not hang the exit, and the thread is a daemon."""
+        self._q.put(self._DONE)
+        self._thread.join(self.ERROR_EXIT_TIMEOUT_SEC if failed else None)
+        if self._thread.is_alive():
+            log.warning("step stamper still waiting on the device "
+                        "after %.0fs — left behind",
+                        self.ERROR_EXIT_TIMEOUT_SEC)
 
 
 # -- on-demand profile capture ----------------------------------------

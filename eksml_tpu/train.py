@@ -754,6 +754,21 @@ class Trainer:
                                       max_rollbacks=res.MAX_ROLLBACKS)
         nan_injected = False
 
+        if self.tracer is not None:
+            # (re)install for THIS fit — a second fit() on the same
+            # Trainer must trace too, and the finally below uninstalls
+            # so a finished run's tracer can't swallow later spans.
+            # Before the prefetcher: its thread's first h2d_prefetch
+            # span (seq 0) starts with the thread
+            telemetry.install_tracer(self.tracer)
+        # per-step completion stamps (device_step spans): a thread of
+        # its own, only while a traced fit runs
+        stamper = None
+        # ordinal of the batch a data_wait took: the seq its
+        # batch_build and h2d_prefetch spans carry (single producer,
+        # FIFO), counted only where a span will hold it
+        taken = 0
+
         # TRAIN.PREFETCH_TO_DEVICE: the next batch's host-shard →
         # device transfer runs on a worker thread while the device
         # executes the current step, instead of blocking here every
@@ -771,11 +786,6 @@ class Trainer:
             source = prefetcher
 
         step = start_step
-        if self.tracer is not None:
-            # (re)install for THIS fit — a second fit() on the same
-            # Trainer must trace too, and the finally below uninstalls
-            # so a finished run's tracer can't swallow later spans
-            telemetry.install_tracer(self.tracer)
         try:
             # goodput ledger (telemetry/goodput.py): classify this
             # fit's wall-clock from the EXISTING span/event exhaust.
@@ -834,6 +844,8 @@ class Trainer:
                     "this pod in a loop. Set healthz_stale_seconds=0 "
                     "when disabling telemetry.",
                     self._telemetry["HEALTHZ_STALE_SEC"])
+            if self.tracer is not None:
+                stamper = telemetry.StepStamper(jax.block_until_ready)
             source_iter = iter(source)
             _end = object()
             while True:
@@ -848,7 +860,12 @@ class Trainer:
                 # to the checkpoint) — an untagged span beats one
                 # joined to the wrong train_step.
                 feeds = step + 1 if state is not None else None
-                with telemetry.span("data_wait", step=feeds):
+                wait_attrs = None
+                if self.tracer is not None:
+                    wait_attrs = {"seq": taken}
+                    taken += 1
+                with telemetry.span("data_wait", step=feeds,
+                                    attrs=wait_attrs):
                     batch = next(source_iter, _end)
                 if batch is _end:
                     break
@@ -893,9 +910,15 @@ class Trainer:
                         self.compiled_step(), state, device_batch)
                 # host-side dispatch of the compiled step (the device
                 # executes async; blocking shows up in data_wait /
-                # host_metrics instead — the Dapper-style host timeline)
-                with telemetry.span("train_step", step=step + 1):
+                # loss_sync instead — the Dapper-style host timeline)
+                with telemetry.span("train_step", step=step + 1,
+                                    step_trace=True):
                     state, metrics = step_fn(state, device_batch)
+                if stamper is not None:
+                    # the loss is an output of the step, never donated:
+                    # the stamper's thread waits on it, this loop
+                    # does not
+                    stamper.stamp(step + 1, metrics["total_loss"])
                 if watchdog and first_call:
                     # the compile happened inside that call; from here
                     # the steady-state deadline applies
@@ -974,9 +997,15 @@ class Trainer:
                     # sentinel observation: gated above on checkpoint/
                     # NAN_CHECK_PERIOD/log boundaries — the operator
                     # buys a tighter divergence guard with exactly one
-                    # device sync per check, documented at the knob
-                    action = sentinel.observe(
-                        step, float(np.asarray(metrics["total_loss"])))  # eksml-lint: disable=host-sync
+                    # device sync per check, documented at the knob.
+                    # loss_sync: on a log step THIS is where the loop
+                    # waits for the device to catch up (seconds, when
+                    # it ran ahead) — host_metrics below finds the
+                    # loss already there.  In no goodput bucket: the
+                    # device is at work while it lasts.
+                    with telemetry.span("loss_sync", step=step):
+                        loss_now = float(np.asarray(metrics["total_loss"]))  # eksml-lint: disable=host-sync
+                    action = sentinel.observe(step, loss_now)
                     if action == ROLLBACK:
                         t_rb = time.perf_counter()
                         state, step = self._rollback(sentinel, state,
@@ -995,14 +1024,14 @@ class Trainer:
                         continue
 
                 if log_step:
-                    # host_metrics: where the device sync actually
-                    # lands on log steps — a long one means the device
-                    # is still chewing on the interval's steps
+                    # host_metrics: the log row's host work.  With
+                    # the sentinel observing on log steps (the default)
+                    # the wait for the device has already landed in
+                    # loss_sync above; with NAN_CHECK_PERIOD > 0 it
+                    # lands here, on log steps the sentinel skips
                     with telemetry.span("host_metrics", step=step):
                         # loss materialization at LOG_PERIOD cadence —
-                        # the sync the log row needs anyway, and where
-                        # the device catching up is MEASURED (the
-                        # host_metrics span) rather than hidden
+                        # the sync the log row needs anyway
                         metrics = jax.tree.map(
                             lambda x: float(np.asarray(x)), metrics)  # eksml-lint: disable=host-sync
                     if data_health is not None:
@@ -1170,12 +1199,6 @@ class Trainer:
                 if watchdog:
                     watchdog.beat("next_batch", step)
         finally:
-            if capture is not None:
-                # run ended before the capture's steps elapsed — close
-                # the trace so it still lands (and a later start_trace
-                # won't raise)
-                self._finish_capture(capture, profile_trigger, step,
-                                     truncated=True)
             if self._goodput is not None:
                 # final snapshot: the exporter may already be gone but
                 # the banked line is the segment's authoritative
@@ -1191,15 +1214,6 @@ class Trainer:
                 telemetry.remove_event_sink(self._goodput.on_event)
                 telemetry.install_span_sink(prev_span_sink)
                 self._goodput = None
-            if self.tracer is not None:
-                # steady-state spans land even without a capture: the
-                # cross-host merge works from whatever the ring holds
-                self.tracer.flush()
-                # uninstall so later spans in this process (another
-                # Trainer, eval tooling) can't record into THIS run's
-                # ring and be flushed into its trace file
-                if telemetry.get_tracer() is self.tracer:
-                    telemetry.install_tracer(None)
             if watchdog:
                 watchdog.stop()
             if preempt is not None:
@@ -1213,6 +1227,32 @@ class Trainer:
                 # the scrape endpoint dies with the loop it describes;
                 # a relaunch (or a later fit) re-binds cleanly
                 exporter.stop()
+            if stamper is not None:
+                # stamp every dispatched step (the device drains) while
+                # the tracer, and a capture in flight, still take the
+                # spans; on the way out of an error a wedged device
+                # must not hang the exit (the thread is a daemon).
+                # After the teardown above, not before it: the loop
+                # runs ahead of the device, and an untraced fit stops
+                # its exporter (up to 0.5 s of server poll) while the
+                # device drains — so must a traced one (my chip runs,
+                # PR 25: 0.43 s a fit otherwise)
+                stamper.close(failed=sys.exc_info()[0] is not None)
+            if capture is not None:
+                # run ended before the capture's steps elapsed — close
+                # the trace so it still lands (and a later start_trace
+                # won't raise)
+                self._finish_capture(capture, profile_trigger, step,
+                                     truncated=True)
+            if self.tracer is not None:
+                # steady-state spans land even without a capture: the
+                # cross-host merge works from whatever the ring holds
+                self.tracer.flush()
+                # uninstall so later spans in this process (another
+                # Trainer, eval tooling) can't record into THIS run's
+                # ring and be flushed into its trace file
+                if telemetry.get_tracer() is self.tracer:
+                    telemetry.install_tracer(None)
             # always drain the async checkpoint thread and buffered
             # metrics — an exception mid-loop must not abandon an
             # in-flight save or lose the last metric rows.  A drain
@@ -1356,8 +1396,18 @@ class Trainer:
         capture must never take down training."""
         started = False
         try:
+            # Python tracer off: on, the first traced step of a
+            # process waits ~1 s for the host (my chip runs, PRs 24,
+            # 25).  The host tracer stays at its default level: it
+            # records the spans' annotations, and level 1 kept the TPU
+            # runtime's per-chunk transfer events all the same (2.3 M
+            # against 2.6 M in 5 steps; they are what stop_trace takes
+            # ~10 s to write, PERF.md §6)
+            options = jax.profiler.ProfileOptions()
+            options.python_tracer_level = 0
             jax.profiler.start_trace(
-                os.path.join(self.logdir, "profile"))
+                os.path.join(self.logdir, "profile"),
+                profiler_options=options)
             started = True
         except Exception:  # noqa: BLE001 — observability is best-effort
             log.warning("jax.profiler capture failed to start — "

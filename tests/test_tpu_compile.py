@@ -19,6 +19,7 @@ entry written for a described chip cannot be read back without one.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -69,8 +70,38 @@ def _shapes(head, dtype, sharding):
     return feats, rois, out_size
 
 
+def _roi_align(feats, rois, out_size):
+    """The kernels under the scope the program's dispatch gives them
+    (``ops/roi_align.dispatch_roi_align``).  An HLO instruction takes
+    its name from the innermost scope of its ``op_name``, and a
+    transform wraps the OUTERMOST one: with no scope around it the
+    backward call would come out as ``transpose_jvp_roi_align_bwd__``,
+    which no program path produces."""
+    with jax.named_scope("roi_align"):
+        return pallas_batched_multilevel_roi_align(
+            feats, rois, STRIDES, out_size, 2, 2)
+
+
 def _kernel_sites(compiled) -> int:
     return compiled.as_text().count("tpu_custom_call")
+
+
+_INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s")
+KERNEL_NAMES = ("roi_align_fwd", "roi_align_bwd", "roi_align_seed_copy")
+
+
+def _kernel_names(compiled) -> list:
+    """The kernel each ``tpu_custom_call`` instruction of the compiled
+    text is named for (``%roi_align_bwd.3 = ..`` -> ``roi_align_bwd``):
+    what the profiler's ``XLA Ops`` line, and so the per-kernel
+    rooflines, tell the three kernels apart by."""
+    names = []
+    for line in compiled.as_text().splitlines():
+        if ('custom_call_target="tpu_custom_call"' in line
+                and "custom-call(" in line):
+            names.append(_INSTRUCTION.match(line).group(1)
+                         .split(".", 1)[0])
+    return names
 
 
 @pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
@@ -79,11 +110,11 @@ def test_forward_compiles_for_v5e(one_chip, head, dtype):
     feats, rois, out_size = _shapes(head, dtype, one_chip)
 
     def fwd(fs, r):
-        return pallas_batched_multilevel_roi_align(
-            fs, r, STRIDES, out_size, 2, 2)
+        return _roi_align(fs, r, out_size)
 
     compiled = jax.jit(fwd).lower(feats, rois).compile()
     assert _kernel_sites(compiled) >= 1
+    assert set(_kernel_names(compiled)) == {"roi_align_fwd"}
 
 
 @pytest.mark.parametrize("overlap", ["1", "0"])
@@ -100,14 +131,45 @@ def test_grad_compiles_for_v5e(one_chip, monkeypatch, head, dtype,
     feats, rois, out_size = _shapes(head, dtype, one_chip)
 
     def loss(fs, r):
-        out = pallas_batched_multilevel_roi_align(
-            fs, r, STRIDES, out_size, 2, 2)
-        return out.astype(jnp.float32).sum()
+        return _roi_align(fs, r, out_size).astype(jnp.float32).sum()
 
     compiled = jax.jit(jax.grad(loss)).lower(feats, rois).compile()
     # the forward is dead code under grad-of-sum; what must be there is
     # the backward's read-modify-write kernel (plus its HBM laundering)
     assert _kernel_sites(compiled) >= 1
+    names = _kernel_names(compiled)
+    assert "roi_align_bwd" in names
+    assert set(names) <= set(KERNEL_NAMES), names
+
+
+def test_every_custom_call_carries_its_kernels_name(one_chip,
+                                                    monkeypatch):
+    """Forward kept alive beside the backward (``value_and_grad`` of a
+    loss that returns the pooled features too): no ``tpu_custom_call``
+    of the step is left unnamed, and the three kernels come out under
+    three names, with the scope the attribution rules match."""
+    monkeypatch.setenv("EKSML_ROI_BWD", "pallas")
+    feats, rois, out_size = _shapes("box", "bfloat16", one_chip)
+
+    def loss(fs, r):
+        out = _roi_align(fs, r, out_size)
+        return out.astype(jnp.float32).sum(), out
+
+    compiled = jax.jit(jax.value_and_grad(loss, has_aux=True)).lower(
+        feats, rois).compile()
+    names = _kernel_names(compiled)
+    assert set(names) == set(KERNEL_NAMES), names
+    # .../jvp(roi_align)/roi_align_fwd/pallas_call: the kernel's name
+    # is the innermost scope, and the attribution's roi rule still
+    # matches the path
+    from eksml_tpu.profiling.attribution import SCOPE_RULES
+
+    roi_rule = re.compile(dict((c, p) for c, p, _ in SCOPE_RULES)["roi"])
+    paths = [re.search(r'op_name="([^"]*)"', line).group(1)
+             for line in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert paths and all(roi_rule.search(p) for p in paths), paths
+    assert any(p.endswith("/roi_align_fwd/pallas_call") for p in paths)
 
 
 @pytest.fixture(scope="module")

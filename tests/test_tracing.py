@@ -87,16 +87,357 @@ def test_module_install_and_complete_span():
     assert tr.snapshot()[1]["args"]["seq"] == 2
 
 
-def test_traced_decorator():
-    tr = Tracer(capacity=16)
+# ---- spans on the profiler's clock -----------------------------------
+
+
+def _xplane_host_events(xplane_path):
+    """{event name: [stats dict]} of the host planes of an
+    ``.xplane.pb``."""
+    from jax.profiler import ProfileData
+
+    out = {}
+    for plane in ProfileData.from_file(xplane_path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                out.setdefault(ev.name, []).append(dict(ev.stats))
+    return out
+
+
+@pytest.fixture()
+def profiler_session(tmp_path):
+    """An open ``jax.profiler`` session (Python tracer off, as every
+    capture of this repo starts it); ``stop()`` returns the host
+    events of its ``.xplane.pb``."""
+    import glob
+
+    import jax
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    stopped = []
+
+    def stop():
+        jax.profiler.stop_trace()
+        stopped.append(True)
+        (path,) = glob.glob(os.path.join(
+            str(tmp_path), "plugins", "profile", "*", "*.xplane.pb"))
+        return _xplane_host_events(path)
+
+    yield stop
+    if not stopped:
+        jax.profiler.stop_trace()
+
+
+def test_spans_are_profiler_annotations_on_their_own_thread(
+        profiler_session):
+    """Main-thread and worker-thread spans land in the host plane of
+    the profiler's trace under their names, ``step`` and the attrs as
+    stats; the step's dispatch is a StepTraceAnnotation; an interval
+    measured elsewhere (``complete_span``) stays ring-only."""
+    tr = Tracer(capacity=32)
     telemetry.install_tracer(tr)
 
-    @telemetry.traced("hot_fn")
-    def fn(x):
-        return x + 1
+    def producer():
+        with telemetry.span("batch_build", attrs={"seq": 5, "rows": 4}):
+            time.sleep(0.002)
 
-    assert fn(1) == 2
-    assert tr.snapshot()[0]["name"] == "hot_fn"
+    with telemetry.span("data_wait", step=8, attrs={"seq": 5}):
+        t = threading.Thread(target=producer)
+        t.start()
+        t.join()
+    with telemetry.span("train_step", step=8, step_trace=True):
+        time.sleep(0.001)
+    t0 = time.perf_counter()
+    telemetry.complete_span("queue_wait", t0, t0 + 0.001, seq=1)
+    events = profiler_session()
+    assert events["batch_build"] == [{"seq": 5, "rows": 4}]
+    assert events["data_wait"] == [{"seq": 5, "step": 8}]
+    (step_stats,) = events["train_step"]
+    assert step_stats["step_num"] == 8 and step_stats["step"] == 8
+    assert step_stats["_r"] == 1            # what marks a step trace
+    assert "queue_wait" not in events
+    # and the ring holds all four, as before
+    assert [e["name"] for e in tr.snapshot()] == [
+        "batch_build", "data_wait", "train_step", "queue_wait"]
+
+
+@pytest.mark.parametrize("broken", ["no_jax", "annotation_raises"])
+def test_annotation_failure_degrades_to_ring_only(monkeypatch, broken):
+    """No importable jax.profiler, or an annotation that cannot be
+    entered: the span is still recorded, nothing raises."""
+    from eksml_tpu.telemetry import tracing
+
+    class Refuses:
+        @staticmethod
+        def TraceAnnotation(name, **kw):
+            raise RuntimeError("no profiler here")
+
+    monkeypatch.setattr(tracing, "_profiler",
+                        False if broken == "no_jax" else Refuses)
+    tr = Tracer(capacity=16)
+    telemetry.install_tracer(tr)
+    with telemetry.span("data_wait", step=1, attrs={"seq": 0}):
+        pass
+    (ev,) = tr.snapshot()
+    assert ev["name"] == "data_wait" and ev["args"]["seq"] == 0
+
+
+def test_no_tracer_builds_no_annotation(monkeypatch):
+    from eksml_tpu.telemetry import tracing
+
+    built = []
+    monkeypatch.setattr(tracing, "_annotation",
+                        lambda *a: built.append(a))
+    with telemetry.span("data_wait", step=1, attrs={"seq": 0},
+                        step_trace=True) as s:
+        assert s is NULL_SPAN
+    telemetry.install_tracer(Tracer(capacity=16, enabled=False))
+    with telemetry.span("train_step", step=1):
+        pass
+    assert built == []
+
+
+# ---- step completion stamps -----------------------------------------
+
+
+def test_step_stamper_stamps_in_order_and_close_drains():
+    """One ``device_step`` span per handed step, in hand-over order;
+    each ends when its wait returns, so ends increase; ``close``
+    returns only after the last queued step is stamped and the thread
+    is gone."""
+    tr = Tracer(capacity=64)
+    telemetry.install_tracer(tr)
+    waited = []
+
+    def wait(value):
+        time.sleep(0.002)
+        waited.append(value)
+
+    stamper = telemetry.StepStamper(wait)
+    assert any(t.name == "step-stamper" for t in threading.enumerate())
+    for step in range(3, 9):
+        stamper.stamp(step, f"loss{step}")
+    stamper.close()
+    assert not any(t.name == "step-stamper"
+                   for t in threading.enumerate())
+    assert waited == [f"loss{n}" for n in range(3, 9)]
+    spans = [e for e in tr.snapshot() if e["name"] == "device_step"]
+    assert [e["args"]["step"] for e in spans] == list(range(3, 9))
+    ends = [e["ts"] + e["dur"] for e in spans]
+    assert ends == sorted(ends) and len(set(ends)) == len(ends)
+
+
+def test_step_stamper_survives_a_failed_wait_and_a_wedged_device(
+        monkeypatch):
+    """A wait that raises (the step failed on the device: the loop's
+    own sync reports it) does not end the thread; on the error path a
+    wait that never returns is left behind after the time limit."""
+    tr = Tracer(capacity=16)
+    telemetry.install_tracer(tr)
+    wedged = threading.Event()
+
+    def wait(value):
+        if value == "bad":
+            raise RuntimeError("device error")
+        if value == "wedged":
+            wedged.wait(30)
+
+    monkeypatch.setattr(telemetry.StepStamper,
+                        "ERROR_EXIT_TIMEOUT_SEC", 0.05)
+    stamper = telemetry.StepStamper(wait)
+    stamper.stamp(1, "bad")
+    stamper.stamp(2, "fine")
+    stamper.stamp(3, "wedged")
+    t0 = time.perf_counter()
+    stamper.close(failed=True)
+    assert time.perf_counter() - t0 < 5.0
+    steps = [e["args"]["step"] for e in tr.snapshot()]
+    assert steps == [1, 2]         # the failed wait still ends its span
+    wedged.set()
+    stamper._thread.join(5)
+    assert not stamper._thread.is_alive()
+
+
+# ---- a traced fit against an untraced one ----------------------------
+
+TINY_FIT = [
+    "DATA.SYNTHETIC=True", "DATA.NUM_WORKERS=0",
+    "TRAIN.STEPS_PER_EPOCH=4", "TRAIN.MAX_EPOCHS=1",
+    "TRAIN.CHECKPOINT_PERIOD=100", "TRAIN.LOG_PERIOD=2",
+    "TPU.MESH_SHAPE=(1,1)", "TELEMETRY.PORT=0",
+    "TELEMETRY.TRACING.ANOMALY_TRIGGER=False",
+]
+
+
+class _Boom(RuntimeError):
+    pass
+
+
+def _fit(logdir, tracing_on, steps=4):
+    """One tiny ``Trainer.fit`` as a consumer wires it; returns what
+    the tests below look at."""
+    import jax
+
+    from eksml_tpu import config as config_mod
+    from eksml_tpu.config import SMOKE_OVERRIDES
+    from eksml_tpu.data import DetectionLoader, SyntheticDataset
+    from eksml_tpu.telemetry import tracing
+    from eksml_tpu.train import Trainer
+
+    cfg = config_mod.config
+    saved = cfg.to_dict()
+    cfg.freeze(False)
+    built = {"spans": 0, "annotations": 0, "stampers": 0}
+    patch = pytest.MonkeyPatch()
+
+    def count(key, owner, attr):
+        fn = getattr(owner, attr)
+
+        def counting(*a, **kw):
+            built[key] += 1
+            return fn(*a, **kw)
+        patch.setattr(owner, attr, counting)
+
+    count("spans", tracing._Span, "__init__")
+    count("annotations", tracing, "_annotation")
+    count("stampers", tracing.StepStamper, "__init__")
+    try:
+        cfg.update_args(list(SMOKE_OVERRIDES) + TINY_FIT + [
+            f"TRAIN.LOGDIR={logdir}",
+            f"TELEMETRY.TRACING.ENABLED={tracing_on}"])
+        config_mod.finalize_configs(is_training=True)
+        ds = SyntheticDataset(num_images=4, height=128, width=128,
+                              num_classes=cfg.DATA.NUM_CLASSES)
+        loader = DetectionLoader(ds.records(), cfg, batch_size=1,
+                                 with_masks=True, gt_mask_size=28,
+                                 seed=0)
+        trainer = Trainer(cfg, logdir)
+        out = {"built": built}
+        try:
+            state = trainer.fit(loader.batches(steps), total_steps=100)
+            jax.block_until_ready(state)
+            out["threads_after_fit"] = [
+                t.name for t in threading.enumerate()]
+            out["installed_after_fit"] = telemetry.get_tracer()
+            if trainer.tracer is not None:
+                out["spans"] = trainer.tracer.snapshot()
+                before = len(out["spans"])
+
+                def two_then_boom():
+                    gen = loader.batches(2)
+                    yield from gen
+                    raise _Boom("the input broke")
+
+                with pytest.raises(_Boom):
+                    trainer.fit(two_then_boom(), total_steps=100,
+                                start_step=steps, state=state)
+                out["threads_after_raise"] = [
+                    t.name for t in threading.enumerate()]
+                out["spans_of_raising_fit"] = \
+                    trainer.tracer.snapshot()[before:]
+        finally:
+            trainer.ckpt.close()
+        with open(os.path.join(logdir, "metrics.jsonl")) as f:
+            rows = [json.loads(line) for line in f]
+        out["losses"] = {r["step"]: r["total_loss"] for r in rows
+                         if "total_loss" in r and r["step"] <= steps}
+        return out
+    finally:
+        patch.undo()
+        telemetry.install_tracer(None)
+        cfg.freeze(False)
+        cfg.from_dict(saved)
+        cfg.freeze()
+
+
+@pytest.fixture(scope="module")
+def fits(tmp_path_factory):
+    """The same 4-step schedule with tracing off and on (and, on the
+    traced Trainer, a second fit whose input raises after 2 steps)."""
+    root = tmp_path_factory.mktemp("fits")
+    return {"off": _fit(str(root / "off"), False),
+            "on": _fit(str(root / "on"), True)}
+
+
+def test_untraced_fit_builds_no_span_annotation_or_thread(fits):
+    off = fits["off"]
+    assert off["built"] == {"spans": 0, "annotations": 0, "stampers": 0}
+    assert "spans" not in off               # no tracer on the Trainer
+    assert "step-stamper" not in off["threads_after_fit"]
+
+
+def test_traced_fit_leaves_one_device_step_span_per_step(fits):
+    on = fits["on"]
+    assert on["built"]["stampers"] == 2     # one per fit, none leaked
+    assert on["built"]["annotations"] == on["built"]["spans"] > 0
+    stamps = [e for e in on["spans"] if e["name"] == "device_step"]
+    assert [e["args"]["step"] for e in stamps] == [1, 2, 3, 4]
+    ends = [e["ts"] + e["dur"] for e in stamps]
+    assert all(b > a for a, b in zip(ends, ends[1:]))
+    # a step is done on the device no earlier than it was dispatched
+    dispatched = {e["args"]["step"]: e["ts"] for e in on["spans"]
+                  if e["name"] == "train_step"}
+    assert all(end > dispatched[e["args"]["step"]]
+               for e, end in zip(stamps, ends))
+    # one thread for all of them, not the step loop's
+    main = {e["tid"] for e in on["spans"] if e["name"] == "train_step"}
+    assert len({e["tid"] for e in stamps}) == 1
+    assert {e["tid"] for e in stamps}.isdisjoint(main)
+    assert "step-stamper" not in on["threads_after_fit"]
+    assert on["installed_after_fit"] is None
+
+
+def test_stamp_thread_is_gone_when_fit_raises(fits):
+    on = fits["on"]
+    assert "step-stamper" not in on["threads_after_raise"]
+    stamps = [e["args"]["step"] for e in on["spans_of_raising_fit"]
+              if e["name"] == "device_step"]
+    assert stamps == [5, 6]                 # drained before the exit
+
+
+def test_one_batchs_three_spans_share_seq(fits):
+    """batch_build (loader's producer), h2d_prefetch (prefetcher's
+    thread) and data_wait (step loop) of the n-th batch carry seq n,
+    each from a thread of its own; batch_build says how many rows."""
+    spans = fits["on"]["spans"]
+    by = {name: {e["args"]["seq"]: e for e in spans
+                 if e["name"] == name}
+          for name in ("batch_build", "h2d_prefetch", "data_wait")}
+    for seq in range(4):
+        trio = [by[name][seq] for name in by]
+        assert len({e["tid"] for e in trio}) == 3
+        build, h2d, wait = trio
+        # built, then transferred, then taken by the loop
+        assert build["ts"] + build["dur"] <= h2d["ts"] + h2d["dur"]
+        assert h2d["ts"] <= wait["ts"] + wait["dur"]
+    assert by["batch_build"][0]["args"]["rows"] == 1
+    # the wait that found the iterator exhausted took no batch
+    assert max(by["data_wait"]) == 4 and 4 not in by["batch_build"]
+    assert "device_step" not in telemetry.goodput.SPAN_BUCKETS
+
+
+def test_the_log_steps_wait_for_the_device_is_a_span(fits):
+    """``loss_sync`` (the sentinel's read of the loss, where a loop
+    that ran ahead waits for the device) comes before the same step's
+    ``host_metrics``, which then finds the loss there; neither it nor
+    ``device_step`` is in a goodput bucket (the device is at work)."""
+    spans = fits["on"]["spans"]
+    sync = {e["args"]["step"]: e for e in spans
+            if e["name"] == "loss_sync"}
+    metrics = {e["args"]["step"]: e for e in spans
+               if e["name"] == "host_metrics"}
+    assert sorted(sync) == sorted(metrics) == [2, 4]    # LOG_PERIOD=2
+    for step in sync:
+        assert sync[step]["ts"] + sync[step]["dur"] <= metrics[step]["ts"]
+    assert "loss_sync" not in telemetry.goodput.SPAN_BUCKETS
+
+
+def test_tracing_leaves_the_losses_alone(fits):
+    assert fits["on"]["losses"] == fits["off"]["losses"]
+    assert sorted(fits["on"]["losses"]) == [2, 4]
 
 
 def test_thread_safety_and_flush_is_valid_chrome_trace(tmp_path):
@@ -280,7 +621,12 @@ def test_merge_aligns_clocks_and_names_dominant_span(tmp_path):
     from tools import trace_summary
 
     logdir = str(tmp_path)
-    _write_host_trace(logdir, 0, _host_events(0, 0))
+    # the stamper's device_step spans overlap the loop's: in the
+    # timeline, not in a step's wall or its dominant span
+    stamps = [{"name": "device_step", "ph": "X", "ts": 1_000_000.0,
+               "dur": 50_000.0, "pid": 0, "tid": 2,
+               "args": {"host": 0, "step": step}} for step in (2, 3)]
+    _write_host_trace(logdir, 0, _host_events(0, 0) + stamps)
     # host 1's wall clock is 7 s ahead (NTP skew) and step 3 stalls
     # in data_wait
     _write_host_trace(logdir, 1,
@@ -297,6 +643,8 @@ def test_merge_aligns_clocks_and_names_dominant_span(tmp_path):
     assert slow["ms"] == 8.8
     assert slow["dominant_span"] == "data_wait"
     assert slow["dominant_ms"] == 8.0
+    assert sum(e.get("name") == "device_step"
+               for e in merged["traceEvents"]) == 2
     # merged timeline: host 1's aligned events interleave host 0's
     aligned = [e for e in merged["traceEvents"]
                if e.get("pid") == 1 and e.get("name") == "train_step"]

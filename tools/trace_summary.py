@@ -189,6 +189,11 @@ def summarize(trace_dir: str, top_n: int = 15,
 # ---------------------------------------------------------------------
 
 STEP_SPAN = "train_step"  # the per-step anchor span the fit loop emits
+# step-tagged spans of OTHER threads: they overlap the loop's own
+# spans (device_step runs from one step's completion on the device to
+# the next), so they are part of the timeline and no part of a step's
+# host wall or of its dominant span
+OVERLAPPING_SPANS = frozenset({"device_step"})
 
 
 def load_host_traces(logdir: str) -> tuple:
@@ -294,7 +299,7 @@ def merge_host_traces(logdir: str, slow_top: int = 5) -> dict:
             if ev.get("ph") != "X":
                 continue
             step = (ev.get("args") or {}).get("step")
-            if step is None:
+            if step is None or ev.get("name") in OVERLAPPING_SPANS:
                 continue
             step = int(step)
             dur = float(ev.get("dur", 0.0))
