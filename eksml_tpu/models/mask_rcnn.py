@@ -36,7 +36,7 @@ from eksml_tpu.models.rpn import (RPNHead, generate_proposals, match_anchors,
 from eksml_tpu.ops.anchors import generate_fpn_anchors
 from eksml_tpu.ops.boxes import clip_boxes, decode_boxes
 from eksml_tpu.ops.nms import class_aware_nms
-from eksml_tpu.ops.roi_align import dispatch_roi_align, roi_align
+from eksml_tpu.ops.roi_align import dispatch_roi_align, resample_masks
 
 
 class MaskRCNN(nn.Module):
@@ -393,12 +393,7 @@ class MaskRCNN(nn.Module):
         ry2 = (rois[:, 3] - g_boxes[:, 1]) / gh * mr0
         mask_rois = jnp.stack([rx1, ry1, rx2, ry2], axis=-1)
 
-        def one(mask, roi):
-            out = roi_align(mask[:, :, None].astype(jnp.float32),
-                            roi[None], 1.0, mr)
-            return out[0, :, :, 0]
-
-        sampled = jax.vmap(one)(g_masks, mask_rois)
+        sampled = resample_masks(g_masks, mask_rois, mr)
         return (sampled >= 0.5).astype(jnp.float32)
 
     # ---- inference ---------------------------------------------------

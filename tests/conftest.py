@@ -25,9 +25,13 @@ import pytest
 
 # shared tiny-model KEY=VALUE overrides for subprocess-driven tests —
 # canonical list lives in eksml_tpu.config.SMOKE_OVERRIDES
+from eksml_tpu import config as config_mod
 from eksml_tpu.config import SMOKE_OVERRIDES
 
 TINY_MODEL_OVERRIDES = list(SMOKE_OVERRIDES)
+
+# the one global config tree as the import left it, before any test
+_PRISTINE_CONFIG = config_mod.config.to_dict()
 
 
 @pytest.fixture(autouse=True)
@@ -37,11 +41,15 @@ def _seed():
 
 @pytest.fixture()
 def fresh_config():
-    """A finalized config clone; tests mutate freely without leaking."""
-    from eksml_tpu import config as config_mod
-
+    """The global config at its import-time defaults; tests mutate
+    freely without leaking.  Reset to the defaults, not to whatever an
+    earlier test of the same worker left in the tree (the benchmark's
+    ``program_config`` leaves its cell's overrides, e.g.
+    ``MODE_MASK=False``): which files share a worker under
+    ``--dist loadfile`` changes with their run times."""
     saved = config_mod.config.to_dict()
     config_mod.config.freeze(False)
+    config_mod.config.from_dict(_PRISTINE_CONFIG)
     yield config_mod.config
     config_mod.config.freeze(False)
     config_mod.config.from_dict(saved)

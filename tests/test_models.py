@@ -268,3 +268,130 @@ def test_mask_targets_identity_and_subregion_resample():
     want = np.kron(stored[:14, :14], np.ones((2, 2)))
     np.testing.assert_array_equal(out[1], (want >= 0.5).astype(
         np.float32))
+
+
+def _gather_mask_targets(rois, matched_gt, gt_boxes, gt_masks, mr):
+    """One image's mask targets before the threshold, by the gather
+    formulation, ROI by ROI: the oracle of the matmul path."""
+    from eksml_tpu.ops import roi_align
+
+    out = []
+    for roi, g in zip(np.asarray(rois), np.asarray(matched_gt)):
+        box, mask = np.asarray(gt_boxes[g]), gt_masks[g]
+        wh = np.maximum(box[2:] - box[:2], 1e-4)
+        frame = np.concatenate([(roi[:2] - box[:2]) / wh,
+                                (roi[2:] - box[:2]) / wh]) * mask.shape[-1]
+        out.append(roi_align(jnp.asarray(mask, jnp.float32)[:, :, None],
+                             jnp.asarray(frame, jnp.float32)[None],
+                             1.0, mr)[0, :, :, 0])
+    return np.stack(out)
+
+
+def test_mask_targets_56_to_28_at_the_cell_sizes():
+    """The benchmark cells' sizes (``gt_mask_size`` 56 →
+    ``mask_resolution`` 28): a ROI equal to its GT box averages 2×2
+    stored pixels per bin with its samples on pixel centres, so bin
+    means are multiples of 0.25 and ``== 0.5`` is common — the targets
+    must be exact there, not close."""
+    model = tiny_model(mask_resolution=28)
+    rng = np.random.RandomState(5)
+    stored = (rng.rand(56, 56) > 0.5).astype(np.float32)
+    gt_boxes = jnp.asarray([[10.0, 20.0, 74.0, 116.0]])
+    gt_masks = jnp.asarray(stored)[None]
+    rois = jnp.asarray([
+        [10.0, 20.0, 74.0, 116.0],   # identical to the GT box
+        [42.0, 68.0, 74.0, 116.0],   # bottom-right quadrant
+        [17.3, 31.9, 66.1, 97.7],    # inside, off the pixel grid
+        [-5.0, 40.0, 50.0, 140.0],   # overhangs the GT box: zero padding
+    ])
+    matched = jnp.zeros((4,), jnp.int32)
+    out = np.asarray(model.apply({}, rois, matched, gt_boxes, gt_masks,
+                                 method=MaskRCNN._mask_targets))
+    blocks = stored.reshape(28, 2, 28, 2).mean(axis=(1, 3))
+    assert (blocks == 0.5).any()
+    np.testing.assert_array_equal(out[0], (blocks >= 0.5))
+    # quadrant: one stored pixel per bin, centre tap 0.75² ≥ 0.5
+    np.testing.assert_array_equal(out[1], stored[28:, 28:])
+    want = _gather_mask_targets(rois, matched, gt_boxes, gt_masks, 28)
+    decided = np.abs(want - 0.5) > 1e-6
+    np.testing.assert_array_equal(out[decided], (want >= 0.5)[decided])
+    assert out[3][:, :4].sum() == 0 and out[3].sum() > 0
+
+
+def test_mask_targets_under_vmap_pick_each_rois_own_gt_row():
+    """The train forward's call: ``vmap`` over images, ``matched_gt``
+    pointing every ROI at its own GT row (box and stored mask)."""
+    model = tiny_model(mask_resolution=28)
+    rng = np.random.RandomState(7)
+    b, g, s = 2, 3, 6
+    lo = rng.rand(b, g, 2) * 60
+    gt_boxes = np.concatenate([lo, lo + 20 + rng.rand(b, g, 2) * 50],
+                              -1).astype(np.float32)
+    gt_masks = (rng.rand(b, g, 56, 56) > 0.5).astype(np.float32)
+    matched = np.asarray([[2, 0, 1, 1, 2, 0], [1, 1, 0, 2, 0, 2]], np.int32)
+    rois = np.take_along_axis(gt_boxes, matched[..., None], 1)
+    jitter = rng.randn(b, s, 4).astype(np.float32) * 4
+    jitter[:, :g] = 0        # the first three ROIs ARE their GT boxes
+    rois = rois + jitter
+
+    out = np.asarray(jax.vmap(
+        lambda r, m, gb, gm: model.apply(
+            {}, r, m, gb, gm, method=MaskRCNN._mask_targets)
+    )(jnp.asarray(rois), jnp.asarray(matched), jnp.asarray(gt_boxes),
+      jnp.asarray(gt_masks)))
+    assert out.shape == (b, s, 28, 28)
+    for i in range(b):
+        for j in range(g):
+            blocks = gt_masks[i, matched[i, j]].reshape(
+                28, 2, 28, 2).mean(axis=(1, 3))
+            # (x − x1)/w·56 is exactly 0 and 56 whatever the box, so
+            # the samples sit on pixel centres: exact, 0.5s included
+            np.testing.assert_array_equal(out[i, j], blocks >= 0.5)
+        want = _gather_mask_targets(rois[i], matched[i], gt_boxes[i],
+                                    gt_masks[i], 28)
+        decided = np.abs(want - 0.5) > 1e-6
+        np.testing.assert_array_equal(out[i][decided],
+                                      (want >= 0.5)[decided])
+    # rows differ: a wrong pick would repeat one GT's mask
+    assert not np.array_equal(out[0, 0], out[0, 1])
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr, nested ones included."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _eqns(sub)
+
+
+@pytest.mark.parametrize("under_vmap", [False, True])
+def test_mask_targets_is_two_highest_precision_matmuls(under_vmap):
+    """Structure, so the one-lane gathers (four fusions, 83 ms of a
+    388 ms step on a v5e: PERF.md §6, PR 26) cannot come back unseen:
+    ``_mask_targets`` resamples with two ``dot_general``s at
+    ``Precision.HIGHEST`` (default precision rounds the weights to
+    bfloat16 on a TPU and flips boundary pixels), and its only gathers
+    are the ``[matched_gt]`` row picks of boxes and masks."""
+    model = tiny_model(mask_resolution=28)
+
+    def targets(r, m, gb, gm):
+        return model.apply({}, r, m, gb, gm, method=MaskRCNN._mask_targets)
+
+    args = (jnp.zeros((5, 4)), jnp.zeros((5,), jnp.int32),
+            jnp.zeros((3, 4)), jnp.zeros((3, 56, 56)))
+    if under_vmap:
+        targets = jax.vmap(targets)
+        args = tuple(jnp.stack([a, a]) for a in args)
+    eqns = list(_eqns(jax.make_jaxpr(targets)(*args).jaxpr))
+    dots = [e for e in eqns if e.primitive.name == "dot_general"]
+    assert len(dots) == 2
+    for e in dots:
+        assert e.params["precision"] == (jax.lax.Precision.HIGHEST,) * 2
+        assert e.params["preferred_element_type"] == jnp.float32
+        assert all(v.aval.dtype == jnp.float32 for v in e.invars)
+    gathers = [e for e in eqns if e.primitive.name == "gather"]
+    picked = sorted(e.invars[0].aval.shape[-2:] for e in gathers)
+    assert picked == [(3, 4), (56, 56)], picked   # gt_boxes, gt_masks rows

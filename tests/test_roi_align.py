@@ -1,10 +1,12 @@
 """ROIAlign tests: analytic cases + numpy bilinear reference."""
 
 import numpy as np
+import jax
 import jax.numpy as jnp
+import pytest
 
 from eksml_tpu.ops import multilevel_roi_align, roi_align
-from eksml_tpu.ops.roi_align import assign_fpn_levels
+from eksml_tpu.ops.roi_align import assign_fpn_levels, resample_masks
 
 
 def _np_roi_align(feat, roi, scale, out, sr=2):
@@ -168,3 +170,76 @@ def test_bf16_features_keep_float32_coordinates():
     err = float(jnp.abs(out.astype(jnp.float32) - ref).max()
                 / jnp.abs(ref).max())
     assert err < 2e-2, err
+
+
+# ---- resample_masks: the matmul formulation against the gather one ----
+
+_OUT = 28
+
+
+def _disc_masks(rng, n, size):
+    """0/1 discs of random centre and radius on a ``size``² grid."""
+    yy, xx = np.mgrid[:size, :size]
+    cy, cx = rng.rand(2, n, 1, 1) * size
+    r = rng.rand(n, 1, 1) * size * 0.6 + 2
+    return ((yy - cy) ** 2 + (xx - cx) ** 2 <= r ** 2).astype(np.float32)
+
+
+def _mask_rois(kind, size, rng, n=64):
+    """ROIs ``[n, 4]`` in the pixel frame of a ``size``² mask."""
+    s = float(size)
+    if kind == "inside":
+        lo = rng.rand(n, 2) * s * 0.6
+        wh = rng.rand(n, 2) * (s - lo - 1) + 1
+        return np.concatenate([lo, lo + wh], 1)
+    if kind.startswith("overhang"):
+        rois = np.tile([s * 0.2, s * 0.25, s * 0.7, s * 0.8], (n, 1))
+        col, sign = {"overhang_left": (0, -1), "overhang_top": (1, -1),
+                     "overhang_right": (2, 1),
+                     "overhang_bottom": (3, 1)}[kind]
+        rois[:, col] = (s if sign > 0 else 0.0) + sign * rng.rand(n) * s
+        return rois
+    if kind == "zero_area":     # the 1e-4 floor on width and height
+        p = rng.rand(n, 2) * s
+        return np.concatenate([p, p], 1)
+    if kind == "whole_frame":
+        return np.tile([0.0, 0.0, s, s], (n, 1))
+    assert kind == "quadrant"
+    h = s / 2
+    corners = np.asarray([[0, 0], [h, 0], [0, h], [h, h]])[np.arange(n) % 4]
+    return np.concatenate([corners, corners + h], 1)
+
+
+_KINDS = ("inside", "overhang_left", "overhang_top", "overhang_right",
+          "overhang_bottom", "zero_area", "whole_frame", "quadrant")
+
+
+@pytest.mark.parametrize("size", [56, 28])
+@pytest.mark.parametrize("kind", _KINDS)
+def test_resample_masks_matches_gather_roi_align(kind, size):
+    """``resample_masks`` (Ry · M · Cxᵀ) is the gather ``roi_align`` on
+    one single-channel map per ROI: within float32 summation order
+    before the mask targets' 0.5 threshold, equal after it wherever the
+    gather value is not at the threshold itself, and bit-equal where
+    the ROI is the GT box (which the sampler feeds every step) or a
+    quadrant of it: the tap weights are then dyadic, every sum is
+    exact in either order, and at 56² → 28² the bin means are
+    multiples of 0.25, so ``== 0.5`` is common."""
+    rng = np.random.RandomState(_KINDS.index(kind) * 100 + size)
+    rois = jnp.asarray(_mask_rois(kind, size, rng), jnp.float32)
+    masks = jnp.asarray(_disc_masks(rng, rois.shape[0], size))
+
+    def gather_one(mask, roi):
+        return roi_align(mask[:, :, None], roi[None], 1.0, _OUT)[0, :, :, 0]
+
+    want = np.asarray(jax.jit(jax.vmap(gather_one))(masks, rois))
+    got = jax.jit(lambda m, r: resample_masks(m, r, _OUT))(masks, rois)
+    assert got.dtype == jnp.float32 and got.shape == want.shape
+    got = np.asarray(got)
+    assert want.max() > 0.5, "the case samples no mask at all"
+    if kind in ("whole_frame", "quadrant"):
+        np.testing.assert_array_equal(got, want)
+    np.testing.assert_allclose(got, want, rtol=0, atol=1e-6)
+    decided = np.abs(want - 0.5) > 1e-6
+    np.testing.assert_array_equal((got >= 0.5)[decided],
+                                  (want >= 0.5)[decided])
