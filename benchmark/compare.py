@@ -5,21 +5,21 @@ control, the fault readings and the CPU tests.
 Numbers compared (each with a limit of its own, from the cell's file):
 
 * ``loss_step1`` .. ``loss_step3``: |program - reference| / |reference|.
-* ``rpn_loss_step1``: the same gap of the first step's RPN loss
-  (objectness + box).  Its anchors are labelled from the anchors and
-  the ground truth alone and drawn with the same keys, so both sides
-  sum over the SAME anchors: no discrete choice of the model enters,
-  and the gap is the arithmetic's alone.
+* what the cell's task adds of its own (``tasks/<task>.py``
+  ``extra_numbers``), e.g. one loss term that no discrete choice of
+  the model enters.
 * ``first_grad_worst_leaf``: the gap between the program's and the
   reference's norm of the first gradient as the optimizer gets it
-  (its momentum after one step: gradient plus weight decay), by the
+  (the task's ``first_moment`` of the optimizer's state after one
+  step: under SGD the momentum, gradient plus weight decay), by the
   worst leaf, over the larger of the reference's norm of that leaf and
   of the median leaf.
 * ``delta3_worst_leaf``: the same for the parameters' change after
   the three steps.  Leaves whose reference gradient is under a
   thousandth of the median leaf's are left out (rule on the
-  reference's gradient, not on names; under this SGD they are the
-  frozen stem and stage, whose change is exactly zero on both sides).
+  reference's gradient, not on names; in the detection task's SGD
+  they are the frozen stem and stage, whose change is exactly zero on
+  both sides).
 * ``first_grad_median_leaf``, ``delta3_median_leaf``: the median
   leaf's gap of the same two: an update of the wrong size shows here
   first (both swing with the discrete choices; PERF.md section 4).
@@ -51,10 +51,11 @@ def _leaf_gaps(prog, ref, leaves):
     return gaps[worst], worst, statistics.median(gaps.values())
 
 
-def numbers(program, reference):
+def numbers(program, reference, extra=None):
     """``program``/``reference``: dicts with ``loss`` (list),
     ``first_trace_norm`` and ``delta_norm`` ({leaf: norm}); the
-    reference also has ``grad_norm``.  Returns ({name: value},
+    reference also has ``grad_norm``.  ``extra(program, reference)``:
+    the task's own numbers.  Returns ({name: value},
     {name: worst leaf})."""
     out, where = {}, {}
     n = len(reference["loss"])
@@ -63,12 +64,8 @@ def numbers(program, reference):
         r = reference["loss"][i]
         gap = abs(p - r) / max(abs(r), 1e-30)
         out[f"loss_step{i + 1}"] = gap if math.isfinite(gap) else math.inf
-    if program.get("terms") and reference.get("terms"):
-        def rpn(terms):
-            return terms[0]["rpn_cls_loss"] + terms[0]["rpn_box_loss"]
-        gap = abs(rpn(program["terms"]) - rpn(reference["terms"])) / max(
-            abs(rpn(reference["terms"])), 1e-30)
-        out["rpn_loss_step1"] = gap if math.isfinite(gap) else math.inf
+    if extra is not None:
+        out.update(extra(program, reference))
     g = reference["grad_norm"]
     trained = [k for k in g if g[k] > 0.0]
     med = statistics.median(g[k] for k in trained) if trained else 0.0

@@ -32,7 +32,6 @@ def main(argv=None) -> int:
     p.add_argument("--seeds", default="1,2,3")
     args = p.parse_args(argv)
     from benchmark import compare, harness, traffic
-    from benchmark.reference import train as ref_train
     from eksml_tpu.utils.compile_cache import enable_persistent_cache
 
     enable_persistent_cache()
@@ -47,19 +46,20 @@ def main(argv=None) -> int:
     for seed in (int(s) for s in args.seeds.split(",")):
         t0 = time.perf_counter()
         batches = harness.first_batches(cell, seed, follow)
-        rows = batches[0]["images"].shape[0]
+        rows = cell.hyper["global_batch"]
 
         def run(**kw):
-            return ref_train.run_steps(cell.spec, cell.hyper,
-                                       traffic.effective_seed(seed),
-                                       batches, **kw)
+            return cell.task.reference_steps(
+                cell.spec, cell.hyper, traffic.effective_seed(seed),
+                batches, **kw)
 
         exact = run()
         line = {"workload": cell.name, "seed": seed, "loss": exact["loss"]}
         readings = {"control_int8": {"precision": "int8"},
                     "fault_half_batch": {"rows": list(range(rows // 2))}}
         for name, kw in readings.items():
-            values, where = compare.numbers(run(**kw), exact)
+            values, where = compare.numbers(run(**kw), exact,
+                                            cell.task.extra_numbers)
             ok, _ = compare.judge(values, limits)
             line[name] = {"numbers": values, "worst_leaf": where,
                           "passes_limits": ok}

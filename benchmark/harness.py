@@ -1,13 +1,14 @@
-"""One run of one cell: ``Trainer.fit`` over ``DetectionLoader`` on the
-benchmark's seeded records, warm-up fit as set-up, a second fit on the
+"""One run of one cell: ``Trainer.fit`` over the task's loader on the
+benchmark's seeded traffic, warm-up fit as set-up, a second fit on the
 same ``Trainer`` as the measured window, then the comparison with the
-plain reference.  The only file of the benchmark that imports the
-program.
+plain reference.  ``harness.py`` and the task modules are the only
+files of the benchmark that import the program.
 
-Everything that belongs to one cell, configuration or per-layer metric
-is found by name from ``BENCHMARK.json``: ``configs/<config>.json``,
-``workloads/<cell>.json``, ``mixes/<traffic>.json``,
-``metrics/<metric>.py``.
+Everything that belongs to one cell, configuration, task or per-layer
+metric is found by name from ``BENCHMARK.json``:
+``configs/<config>.json``, ``workloads/<cell>.json``,
+``mixes/<traffic>.json``, ``metrics/<metric>.py`` and, by the ``"task"``
+key of the configuration's file, ``tasks/<task>.py``.
 """
 
 from __future__ import annotations
@@ -46,6 +47,7 @@ class Cell:
     chips: int
     config: dict           # configs/<config>.json
     workload: dict         # workloads/<cell>.json
+    task: object           # tasks/<task>.py, the module
     end_to_end: list
     per_layer: list
 
@@ -63,6 +65,25 @@ def _reports(metric, cell_name):
     return "workloads" not in metric or cell_name in metric["workloads"]
 
 
+def _module(package: str, name: str):
+    return importlib.import_module(
+        f"benchmark.{package}." + name.replace(".", "_").replace("-", "_"))
+
+
+def load_task(config: dict, path: str):
+    """The module ``tasks/<task>.py`` that the configuration's file (at
+    ``path``) names under ``"task"``.  There is no default task."""
+    if "task" not in config:
+        raise KeyError(f"{path}: no \"task\" key (benchmark/tasks/<task>.py)")
+    try:
+        return _module("tasks", config["task"])
+    except ModuleNotFoundError as e:
+        if not (e.name or "").startswith("benchmark.tasks."):
+            raise
+        raise KeyError(f"{path}: task {config['task']!r} has no module "
+                       f"benchmark/tasks/{config['task']}.py") from e
+
+
 def load_cell(root: str, name: str, manifest: dict | None = None) -> Cell:
     if manifest is None:
         with open(os.path.join(root, "BENCHMARK.json")) as f:
@@ -73,7 +94,8 @@ def load_cell(root: str, name: str, manifest: dict | None = None) -> Cell:
         raise KeyError(f"no workload {name!r} in BENCHMARK.json")
     cfg_entry = next(c for c in manifest["configs"]
                      if c["name"] == entry["config"])
-    with open(os.path.join(root, cfg_entry["file"])) as f:
+    config_path = os.path.join(root, cfg_entry["file"])
+    with open(config_path) as f:
         config = json.load(f)
     bench = os.path.join(root, manifest["paths"][0])
     with open(os.path.join(bench, "workloads", f"{name}.json")) as f:
@@ -83,7 +105,7 @@ def load_cell(root: str, name: str, manifest: dict | None = None) -> Cell:
         workload["traffic"] = json.load(f)     # the mix's parameters
     return Cell(
         name=name, chips=int(entry["chips"]), config=config,
-        workload=workload,
+        workload=workload, task=load_task(config, config_path),
         end_to_end=[m for m in manifest["end_to_end"] if _reports(m, name)],
         per_layer=[m for m in manifest["per_layer"] if _reports(m, name)])
 
@@ -146,66 +168,6 @@ def program_config(cell: Cell, seed: int, logdir: str, trace: bool):
 _DEFAULTS = None
 
 
-def spec_mismatches(cfg, spec: dict, hyper: dict) -> list:
-    """Where the configuration file's ``model``/``optimizer`` blocks
-    (what the reference computes) and the program's finalized config
-    (what the program computes) differ."""
-    want = {
-        "canvas": [cfg.PREPROC.MAX_SIZE] * 2,
-        "resnet_blocks": list(cfg.BACKBONE.RESNET_NUM_BLOCKS),
-        "freeze_at": cfg.BACKBONE.FREEZE_AT,
-        "fpn_channels": cfg.FPN.NUM_CHANNEL,
-        "strides": list(cfg.FPN.ANCHOR_STRIDES),
-        "anchor_sizes": list(cfg.RPN.ANCHOR_SIZES),
-        "anchor_ratios": list(cfg.RPN.ANCHOR_RATIOS),
-        "rpn_pos_thresh": cfg.RPN.POSITIVE_ANCHOR_THRESH,
-        "rpn_neg_thresh": cfg.RPN.NEGATIVE_ANCHOR_THRESH,
-        "rpn_batch_per_im": cfg.RPN.BATCH_PER_IM,
-        "rpn_fg_ratio": cfg.RPN.FG_RATIO,
-        "rpn_nms_thresh": cfg.RPN.PROPOSAL_NMS_THRESH,
-        "rpn_pre_nms_topk": cfg.RPN.TRAIN_PRE_NMS_TOPK,
-        "rpn_post_nms_topk": cfg.RPN.TRAIN_POST_NMS_TOPK,
-        "frcnn_batch_per_im": cfg.FRCNN.BATCH_PER_IM,
-        "frcnn_fg_thresh": cfg.FRCNN.FG_THRESH,
-        "frcnn_fg_ratio": cfg.FRCNN.FG_RATIO,
-        "bbox_reg_weights": list(cfg.FRCNN.BBOX_REG_WEIGHTS),
-        "fc_head_dim": cfg.FPN.FRCNN_FC_HEAD_DIM,
-        "num_classes": cfg.DATA.NUM_CLASSES,
-        "mask": bool(cfg.MODE_MASK),
-        "mask_head_dim": cfg.MRCNN.HEAD_DIM,
-        "mask_resolution": cfg.MRCNN.RESOLUTION,
-        "max_gt_boxes": cfg.DATA.MAX_GT_BOXES,
-        "pixel_mean": list(cfg.PREPROC.PIXEL_MEAN),
-        "pixel_std": list(cfg.PREPROC.PIXEL_STD),
-        "base_lr": cfg.TRAIN.BASE_LR,
-        "warmup_steps": cfg.TRAIN.WARMUP_STEPS,
-        "warmup_init_factor": cfg.TRAIN.WARMUP_INIT_FACTOR,
-        "lr_schedule": list(cfg.TRAIN.LR_SCHEDULE),
-        "weight_decay": cfg.TRAIN.WEIGHT_DECAY,
-        "momentum": cfg.TRAIN.MOMENTUM,
-        "gradient_clip": cfg.TRAIN.GRADIENT_CLIP,
-        "global_batch": cfg.TRAIN.NUM_CHIPS * cfg.TRAIN.BATCH_SIZE_PER_CHIP,
-    }
-    have = dict(spec, **hyper)
-    return [f"{k}: file {have.get(k)!r}, program {v!r}"
-            for k, v in want.items()
-            if json.dumps(have.get(k)) != json.dumps(v)]
-
-
-def build_loader(cell: Cell, cfg, seed: int, logdir: str):
-    """(loader over the cell's seeded records, rows per step), wired as
-    ``python -m eksml_tpu.train --synthetic`` wires its loader."""
-    from eksml_tpu.data import DetectionLoader
-
-    records = traffic.generate(cell.workload["traffic"], seed)
-    rows_per_step = cfg.TRAIN.BATCH_SIZE_PER_CHIP * cell.chips
-    loader = DetectionLoader(
-        records, cfg, rows_per_step, is_training=True, num_hosts=1,
-        host_id=0, seed=cfg.TRAIN.SEED, with_masks=cfg.MODE_MASK,
-        ledger_dir=logdir, num_slices=int(cfg.TPU.NUM_SLICES))
-    return loader, rows_per_step
-
-
 def first_batches(cell: Cell, seed: int, n: int):
     """The first ``n`` host batches the cell's loader yields for
     ``seed`` (no Trainer): what the control and the fault readings
@@ -215,7 +177,7 @@ def first_batches(cell: Cell, seed: int, n: int):
     logdir = tempfile.mkdtemp(prefix="bench_feed_")
     try:
         cfg = program_config(cell, seed, logdir, False)
-        loader, _ = build_loader(cell, cfg, seed, logdir)
+        loader, _ = cell.task.build_loader(cell, cfg, seed, logdir)
         gen = loader.batches(n)
         try:
             return [{k: np.array(v) for k, v in b.items()} for b in gen]
@@ -279,12 +241,14 @@ class Capture:
 class StepTap:
     """Observes (does not alter) the trainer's step callable during the
     warm-up fit: the parameters before step 1, each followed step's
-    loss, the optimizer's momentum after step 1 and the parameters
-    after the last followed step, reduced to per-leaf norms.  Removed
-    before the window."""
+    loss, what the optimizer holds of the first gradient after step 1
+    (``first_moment(opt_state)``, the task's) and the parameters after
+    the last followed step, reduced to per-leaf norms.  Removed before
+    the window."""
 
-    def __init__(self, trainer, follow: int):
+    def __init__(self, trainer, follow: int, first_moment):
         self.trainer, self.follow = trainer, follow
+        self.first_moment = first_moment
         self.inner = trainer._step_fn_with_prediction
         self.calls = 0
         self.terms = []
@@ -304,21 +268,6 @@ class StepTap:
         flat, _ = jax.tree_util.tree_flatten_with_path(tree)
         return {"/".join(str(getattr(p, "key", p)) for p in path):
                 np.asarray(leaf, np.float32) for path, leaf in flat}
-
-    @staticmethod
-    def _momentum(opt_state):
-        import jax
-        import optax
-
-        def is_trace(x):
-            return isinstance(x, optax.TraceState)
-
-        found = [x for x in jax.tree_util.tree_leaves(
-            opt_state, is_leaf=is_trace) if is_trace(x)]
-        if len(found) != 1:
-            raise RuntimeError("expected one momentum trace in the "
-                               f"optimizer state, found {len(found)}")
-        return found[0].trace
 
     def __call__(self, jit_step, state, batch):
         import numpy as np
@@ -341,7 +290,7 @@ class StepTap:
             if i == 0:
                 self.first_trace_norm = {
                     k: norm(v) for k, v in self._host(
-                        self._momentum(s2.opt_state)).items()}
+                        self.first_moment(s2.opt_state)).items()}
             if i == self.follow - 1:
                 pn = self._host(s2.params)
                 self.delta_norm = {k: norm(pn[k] - self.p0[k])
@@ -365,7 +314,7 @@ class StepTap:
 class TraceContext:
     """What a per-layer metric's reader may read."""
     spec: dict
-    canvas: tuple
+    task: object                     # the cell's task module
     chips: int
     images_per_step: int
     images_per_sec_per_chip: float
@@ -382,10 +331,7 @@ class TraceContext:
 def read_per_layer(cell: Cell, ctx: TraceContext) -> dict:
     out = {}
     for m in cell.per_layer:
-        mod = importlib.import_module(
-            "benchmark.metrics." + m["name"].replace(".", "_")
-            .replace("-", "_"))
-        value = mod.read(ctx)
+        value = _module("metrics", m["name"]).read(ctx)
         if value is not None and math.isfinite(value):
             out[m["name"]] = {"value": value, "unit": m["unit"]}
     return out
@@ -418,14 +364,15 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     trainer = None
     try:
         cfg = program_config(cell, seed, logdir, trace)
-        wrong = spec_mismatches(cfg, cell.spec, cell.hyper)
+        wrong = cell.task.spec_mismatches(cfg, cell.spec, cell.hyper)
         if wrong:
             raise RuntimeError("configuration file and program disagree: "
                                + "; ".join(wrong))
         trainer = Trainer(cfg, logdir)
         if on_trainer is not None:
             on_trainer(trainer)
-        loader, rows_per_step = build_loader(cell, cfg, seed, logdir)
+        loader, rows_per_step = cell.task.build_loader(
+            cell, cfg, seed, logdir)
         gen = loader.batches(None)
         phases["records_trainer_loader"] = time.perf_counter() - t_start
 
@@ -439,7 +386,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
             if len(followed) < follow:
                 followed.append({k: np.array(v) for k, v in batch.items()})
 
-        tap = StepTap(trainer, follow)
+        tap = StepTap(trainer, follow, cell.task.first_moment)
         state = trainer.fit(
             traffic.feed(gen, count=warm, on_batch=remember), BIG_STEPS,
             data_health=loader.health)
@@ -495,8 +442,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
                 trace_file, custom, trace_reduce.hlo_module_name(hlo))
                 if trace_file else None)
             ctx = TraceContext(
-                spec=cell.spec, canvas=tuple(cell.spec["canvas"]),
-                chips=cell.chips, images_per_step=rows_per_step,
+                spec=cell.spec, task=cell.task, chips=cell.chips,
+                images_per_step=rows_per_step,
                 images_per_sec_per_chip=ips_chip, window_s=window_s,
                 window_steps=steps,
                 traced_steps=summary.steps if summary else 0,
@@ -523,11 +470,10 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
         gc.collect()
         jax.clear_caches()
         t_ref = time.perf_counter()
-        from benchmark.reference import train as ref_train
-
-        reference = ref_train.run_steps(
+        reference = cell.task.reference_steps(
             cell.spec, cell.hyper, traffic.effective_seed(seed), followed)
-        values, where = compare.numbers(program, reference)
+        values, where = compare.numbers(program, reference,
+                                        cell.task.extra_numbers)
         values["compiles_in_window"] = float(window_compiles)
         limits = dict(cell.workload["limits"], compiles_in_window=0.0)
         correct, rows = compare.judge(values, limits)

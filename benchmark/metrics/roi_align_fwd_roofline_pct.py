@@ -27,7 +27,8 @@ def pass_roofline_pct(ctx, direction, kernels):
     need = sum(max(call["bytes"] / ctx.peak["hbm_bytes_per_s"],
                    call["ops"] / ctx.peak["bf16_flops_per_s"])
                for call in flops.roi_align_calls(
-                   ctx.spec, *ctx.canvas, itemsize=ctx.feature_itemsize)
+                   ctx.spec, *ctx.spec["canvas"],
+                   itemsize=ctx.feature_itemsize)
                if call["pass"] == direction)
     need *= ctx.images_per_step / ctx.chips
     return 100.0 * need * ctx.traced_steps / spent

@@ -11,7 +11,7 @@ from benchmark import flops
 def bound_seconds(ctx):
     """(seconds per step per chip, {"bytes" | "ops": seconds})."""
     by = {"bytes": 0.0, "ops": 0.0}
-    for call in flops.roi_align_calls(ctx.spec, *ctx.canvas,
+    for call in flops.roi_align_calls(ctx.spec, *ctx.spec["canvas"],
                                       itemsize=ctx.feature_itemsize):
         t_b = call["bytes"] / ctx.peak["hbm_bytes_per_s"]
         t_o = call["ops"] / ctx.peak["bf16_flops_per_s"]

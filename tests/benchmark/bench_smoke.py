@@ -2,14 +2,34 @@
 own path (``harness.run_cell``) at ``config.SMOKE_OVERRIDES`` widths in
 float32.  Never a device number."""
 
-import json
 import os
+
+import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
 CPU_PEAK = {"bf16_flops_per_s": 1e12, "hbm_bytes_per_s": 1e11,
             "hbm_bytes": 16e9}
+
+@pytest.fixture(autouse=True)
+def program_config_put_back():
+    """A test module that calls ``harness.program_config`` (through
+    ``run_cell`` or ``first_batches``) imports this beside the module:
+    the call writes a cell's overrides into the program's one global
+    config tree (``TRAIN.BATCH_SIZE_PER_CHIP=4`` rescales
+    ``TRAIN.STEPS_PER_EPOCH``), and tests elsewhere read the tree bare
+    (``tests/test_config.py``); which files share a worker under
+    ``--dist loadfile`` changes with their run times.  No conftest.py
+    here: ``tests/`` modules import theirs by that name."""
+    from eksml_tpu.config import config
+
+    saved, frozen = config.to_dict(), config._frozen
+    yield
+    config.freeze(False)
+    config.from_dict(saved)
+    config.freeze(frozen)
+
 
 def smoke_overrides(batch_per_chip):
     from eksml_tpu.config import SMOKE_OVERRIDES
@@ -39,12 +59,10 @@ def smoke_cell(mask: bool, chips: int = 1, limits=None,
                batch_per_chip: int = 2):
     from benchmark import harness
 
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        manifest = json.load(f)
-    name = "maskrcnn-r50-fpn" if mask else "fasterrcnn-r50-fpn"
-    with open(os.path.join(ROOT, "benchmark", "configs",
-                           f"{name}.json")) as f:
-        config = json.load(f)
+    # the real cell's configuration, task and metrics, cut to smoke size
+    real = harness.load_cell(ROOT, "mask-r50-train-1344-b4" if mask
+                             else "frcnn-r50-train-1344-b4")
+    config, name = real.config, real.config["name"]
     model = dict(config["model"], canvas=[128, 128], resnet_blocks=[1, 1, 1, 1],
                  fpn_channels=32, rpn_pre_nms_topk=64, rpn_post_nms_topk=32,
                  frcnn_batch_per_im=16, fc_head_dim=64, num_classes=5,
@@ -64,6 +82,6 @@ def smoke_cell(mask: bool, chips: int = 1, limits=None,
                 "follow_steps": 3, "trace_steps": 3,
                 "limits": dict(SMOKE_LIMITS if limits is None else limits)}
     return harness.Cell(name="smoke", chips=chips, config=config,
-                        workload=workload,
-                        end_to_end=manifest["end_to_end"],
-                        per_layer=manifest["per_layer"])
+                        workload=workload, task=real.task,
+                        end_to_end=real.end_to_end,
+                        per_layer=real.per_layer)

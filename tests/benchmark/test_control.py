@@ -8,27 +8,30 @@ than sound runs do (``python benchmark/control.py`` on the chip;
 PERF.md section 4 and its first open question)."""
 
 import bench_smoke
+from bench_smoke import program_config_put_back  # noqa: F401
 from benchmark import compare, harness
-from benchmark.reference import train as ref_train
 
 
 def test_int8_control_and_half_batch_fail_the_comparison():
     cell = bench_smoke.smoke_cell(mask=True)
     batches = harness.first_batches(cell, 41, 3)
-    run = lambda **kw: ref_train.run_steps(  # noqa: E731
+    run = lambda **kw: cell.task.reference_steps(  # noqa: E731
         cell.spec, cell.hyper, 41, batches, **kw)
+    numbers = lambda got: compare.numbers(  # noqa: E731
+        got, exact, cell.task.extra_numbers)
     exact = run()
-    again, _ = compare.numbers(run(), exact)
+    again, _ = numbers(run())
+    assert "rpn_loss_step1" in again
     assert all(v <= 1e-12 for v in again.values()), again  # deterministic
     limits = cell.workload["limits"]
 
-    control, _ = compare.numbers(run(precision="int8"), exact)
+    control, _ = numbers(run(precision="int8"))
     ok, rows = compare.judge(control, limits)
     assert not ok, rows
     assert control["first_grad_median_leaf"] > 10 * limits[
         "first_grad_median_leaf"], control
 
-    half, _ = compare.numbers(run(rows=[0]), exact)
+    half, _ = numbers(run(rows=[0]))
     ok, rows = compare.judge(half, limits)
     assert not ok, rows
     assert half["loss_step1"] > 10 * limits["loss_step1"], half

@@ -7,6 +7,7 @@ this benchmark does not have yet: PERF.md, Open questions.)"""
 import pytest
 
 import bench_smoke
+from bench_smoke import program_config_put_back  # noqa: F401
 from benchmark import harness
 
 

@@ -10,6 +10,7 @@ import re
 import pytest
 
 import bench_smoke
+from bench_smoke import program_config_put_back  # noqa: F401
 from benchmark import harness
 
 ROOT = bench_smoke.ROOT
@@ -64,9 +65,12 @@ def test_every_workload_has_its_files(manifest):
         assert cell.workload["config"] == w["config"]
         assert cell.workload["chips"] == w["chips"]
         assert cell.workload["why"] == w["why"]
-        # the mix is a data file of its own, found by the traffic's name
+        # the mix is a data file of its own, found by the traffic's
+        # name, and the task's loader yields a first batch from it
         assert cell.workload["traffic"]["name"] == w["traffic"]
-        assert cell.workload["traffic"]["records"] > 0
+        (batch,) = harness.first_batches(cell, 7, 1)
+        assert batch and all(v.shape[0] == cell.hyper["global_batch"]
+                             for v in batch.values())
         assert cell.config["source"] == cfg["source"]
         assert sorted(cell.config["reduced"]) == sorted(cfg["reduced"])
         assert set(cell.workload["limits"]) >= {
@@ -99,7 +103,7 @@ def test_every_per_layer_metric_has_a_reader_and_moves_something(manifest):
 def test_readers_return_nothing_when_there_is_nothing(manifest):
     cell = bench_smoke.smoke_cell(mask=True)
     ctx = harness.TraceContext(
-        spec=cell.spec, canvas=(128, 128), chips=1, images_per_step=2,
+        spec=cell.spec, task=cell.task, chips=1, images_per_step=2,
         images_per_sec_per_chip=0.0, window_s=1.0, window_steps=0,
         traced_steps=0, feature_itemsize=4, peak=bench_smoke.CPU_PEAK)
     assert harness.read_per_layer(cell, ctx) == {}

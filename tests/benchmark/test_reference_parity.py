@@ -27,6 +27,7 @@ import numpy as np
 import pytest
 
 import bench_smoke
+from bench_smoke import program_config_put_back  # noqa: F401
 from benchmark import harness
 from benchmark.reference import init as ref_init
 

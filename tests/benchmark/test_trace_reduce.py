@@ -150,13 +150,20 @@ def test_readers_on_hand_made_context():
     cell = bench_smoke.smoke_cell(mask=True)
     summary = trace_reduce.TraceSummary(
         devices=1, steps=5, window_s=2.0, busy_s=1.5, custom_call_s=0.02,
-        custom_call_events=10)
+        custom_call_events=10,
+        op_seconds={"roi_align_fwd.8": 0.004, "roi_align_bwd.3": 0.015,
+                    "roi_align_seed_copy.1": 0.001, "fusion.37": 0.5})
     spans = [{"name": "data_wait", "dur": 2000.0},
              {"name": "train_step", "dur": 9.0},
              {"name": "data_wait", "dur": 4000.0},
-             {"name": "data_wait", "dur": 99000.0}]   # ends the iterator
+             {"name": "data_wait", "dur": 99000.0},   # ends the iterator
+             {"name": "batch_build", "dur": 30000.0},
+             {"name": "h2d_prefetch", "dur": 6000.0}]
+    # 44 step completions 250 ms apart: enough for both percentiles
+    spans += [{"name": "device_step", "ts": 1e9 + 250e3 * i - 100.0,
+               "dur": 100.0, "args": {"step": 6 + i}} for i in range(45)]
     ctx = harness.TraceContext(
-        spec=cell.spec, canvas=(128, 128), chips=1, images_per_step=2,
+        spec=cell.spec, task=cell.task, chips=1, images_per_step=2,
         images_per_sec_per_chip=10.0, window_s=4.0, window_steps=20,
         traced_steps=5, feature_itemsize=4, peak=bench_smoke.CPU_PEAK,
         spans=spans, trace=summary,
@@ -167,6 +174,7 @@ def test_readers_on_hand_made_context():
     assert device_peak_hbm_gb.read(ctx) == pytest.approx(8.1)
     from benchmark import flops
     ops = flops.train_ops_per_image(cell.spec, 128, 128)
+    assert cell.task.train_ops_per_row(cell.spec) == ops
     assert step_mfu_pct.read(ctx) == pytest.approx(100 * ops * 10 / 1e12)
     need, by = roi_align_kernels_roofline_pct.bound_seconds(ctx)
     assert by["ops"] == 0.0 and need == pytest.approx(by["bytes"])
@@ -174,6 +182,9 @@ def test_readers_on_hand_made_context():
         100 * need * 5 / 0.02)
     out = harness.read_per_layer(cell, ctx)
     assert set(out) == {m["name"] for m in cell.per_layer}
+    assert len(out) >= 11
+    assert out["step_ms_p50"]["value"] == pytest.approx(250.0)
+    assert out["batch_build_ms"]["value"] == pytest.approx(30.0)
     # nothing traced: the trace's readers return nothing, never 0
     ctx.trace = None
     assert device_idle_pct.read(ctx) is None

@@ -9,6 +9,7 @@ import os
 import numpy as np
 
 import bench_smoke
+from bench_smoke import program_config_put_back  # noqa: F401
 from benchmark import harness, traffic
 
 ROOT = bench_smoke.ROOT
