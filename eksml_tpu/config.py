@@ -466,6 +466,53 @@ def _define_defaults() -> None:
     _C.MODE_FPN = True
     _C.MODE_CASCADE = False        # Cascade R-CNN stretch config
 
+    # ---- model selection (eksml_tpu/models/__init__.py build_model) --
+    # "maskrcnn" = the detector the MODE_*/BACKBONE/FPN/RPN/FRCNN/MRCNN
+    # blocks describe; "joyai_llm_flash" = the sequence model the LM
+    # block describes (models/lm/, imported only when selected)
+    _C.MODEL.NAME = "maskrcnn"
+
+    # ---- sequence model (models/lm/): JoyAI-LLM-Flash's config.json
+    # (DeepSeek-V3 family, arXiv:2412.19437), every width as published;
+    # NUM_LAYERS, EXPERTS_HELD and VOCAB_ROWS are this chip's share of
+    # an expert-parallel deployment (ARCHITECTURE.md "Second engine")
+    _C.LM.HIDDEN_SIZE = 2048
+    _C.LM.NUM_HEADS = 32
+    _C.LM.Q_LORA_RANK = 1536
+    _C.LM.KV_LORA_RANK = 512
+    _C.LM.QK_NOPE_HEAD_DIM = 128
+    _C.LM.QK_ROPE_HEAD_DIM = 64
+    _C.LM.V_HEAD_DIM = 128
+    _C.LM.ROPE_THETA = 32000000
+    _C.LM.RMS_NORM_EPS = 1e-6
+    _C.LM.INTERMEDIATE_SIZE = 7168      # the leading dense layers' width
+    _C.LM.MOE_INTERMEDIATE_SIZE = 768   # one expert's width
+    _C.LM.FIRST_K_DENSE = 1
+    _C.LM.N_ROUTED_EXPERTS = 256        # the router's outputs, never cut
+    _C.LM.NUM_EXPERTS_PER_TOK = 8
+    _C.LM.N_SHARED_EXPERTS = 1
+    _C.LM.ROUTED_SCALING_FACTOR = 2.5
+    # trunk layers run here (published: 40): FIRST_K_DENSE dense ones,
+    # then expert layers; the layers left out are further pipeline stages
+    _C.LM.NUM_LAYERS = 5
+    _C.LM.NUM_MTP = 1                   # multi-token-prediction modules
+    _C.LM.MTP_LOSS_WEIGHT = 0.3         # lambda, DeepSeek-V3 section 4.2
+    # (first, count): the contiguous routed experts THIS chip holds; the
+    # router still scores all N_ROUTED_EXPERTS and picks 8 a token
+    _C.LM.EXPERTS_HELD = (0, 16)
+    # rows of the vocabulary held here (published: 129280): embedding,
+    # head, logits and loss are over the slice
+    _C.LM.VOCAB_ROWS = 16160
+    _C.LM.SEQ_LEN = 4096
+    _C.LM.INIT_STD = 0.006              # the family's normal init
+    # block of positions of the attention core's jax.numpy formulation
+    # (off a TPU; on one the core is jax's splash-attention kernel)
+    _C.LM.ATTENTION_BLOCK = 512
+    _C.LM.LOSS_CHUNK = 2048             # positions per cross-entropy chunk
+    # the synthetic token stream (data/tokens.py)
+    _C.LM.DATA.DOC_LEN_MEDIAN = 600.0
+    _C.LM.DATA.DOC_LEN_CLIP = (16, 16384)
+
     # ---- trainer selection ------------------------------------------
     # Reference sets TRAINER=horovod (templates/maskrcnn.yaml:71); here
     # the only value is the SPMD mesh trainer.
@@ -566,6 +613,13 @@ def _define_defaults() -> None:
     _C.TRAIN.WARMUP_INIT_FACTOR = 0.33
     _C.TRAIN.WEIGHT_DECAY = 1e-4
     _C.TRAIN.MOMENTUM = 0.9
+    # "sgd" = momentum SGD with the decay added to the gradient (the
+    # detectors'); "adamw" = Adam moments, then decoupled decay; clip
+    # and schedule are the same chain either way (train.make_optimizer)
+    _C.TRAIN.OPTIMIZER = "sgd"
+    _C.TRAIN.ADAM_B1 = 0.9
+    _C.TRAIN.ADAM_B2 = 0.95
+    _C.TRAIN.ADAM_EPS = 1e-8
     _C.TRAIN.GRADIENT_CLIP = 0.0   # optimized chart uses 0.36 (values.yaml:32)
     _C.TRAIN.STEPS_PER_EPOCH = 120000  # "must equal 120000/chips" values.yaml:14
     _C.TRAIN.LR_SCHEDULE = (240000, 320000, 360000)
@@ -724,6 +778,7 @@ def finalize_configs(is_training: bool) -> AttrDict:
 
     assert _C.BACKBONE.NORM in ("FreezeBN", "GN"), _C.BACKBONE.NORM
     assert _C.TRAIN.PRECISION in ("float32", "bfloat16"), _C.TRAIN.PRECISION
+    assert _C.TRAIN.OPTIMIZER in ("sgd", "adamw"), _C.TRAIN.OPTIMIZER
     assert _C.TRAIN.PARAM_DTYPE in ("float32", "bfloat16"), (
         _C.TRAIN.PARAM_DTYPE)
     assert _C.RESILIENCE.DATA.VALIDATE in ("off", "warn", "strict"), (
@@ -842,6 +897,23 @@ SMOKE_OVERRIDES = (
     "FRCNN.BATCH_PER_IM=16", "FPN.NUM_CHANNEL=32",
     "FPN.FRCNN_FC_HEAD_DIM=64", "MRCNN.HEAD_DIM=16",
     "BACKBONE.RESNET_NUM_BLOCKS=(1,1,1,1)", "TEST.RESULTS_PER_IM=8",
+)
+
+
+# The sequence model at a size the CPU tests compile in seconds (2
+# expert layers after the dense one, 8 experts of which 4 held, hidden
+# 64, S 64, float32); widths are cut HERE only, never in a chip cell.
+LM_TINY_OVERRIDES = (
+    "MODEL.NAME=joyai_llm_flash", "TRAIN.OPTIMIZER=adamw",
+    "TRAIN.PRECISION=float32", "TRAIN.REMAT=True",
+    "LM.HIDDEN_SIZE=64", "LM.NUM_HEADS=4", "LM.Q_LORA_RANK=48",
+    "LM.KV_LORA_RANK=32", "LM.QK_NOPE_HEAD_DIM=16",
+    "LM.QK_ROPE_HEAD_DIM=8", "LM.V_HEAD_DIM=16",
+    "LM.INTERMEDIATE_SIZE=160", "LM.MOE_INTERMEDIATE_SIZE=32",
+    "LM.N_ROUTED_EXPERTS=8", "LM.NUM_EXPERTS_PER_TOK=2",
+    "LM.NUM_LAYERS=3", "LM.EXPERTS_HELD=(0,4)", "LM.VOCAB_ROWS=96",
+    "LM.SEQ_LEN=64", "LM.ATTENTION_BLOCK=16", "LM.LOSS_CHUNK=32",
+    "LM.DATA.DOC_LEN_MEDIAN=24.0", "LM.DATA.DOC_LEN_CLIP=(4,256)",
 )
 
 

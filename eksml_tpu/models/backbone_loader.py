@@ -19,9 +19,12 @@ values (so a partially-matching npz still loads).
 
 from __future__ import annotations
 
+import logging
 from typing import Dict, Tuple
 
 import numpy as np
+
+log = logging.getLogger(__name__)
 
 
 def _bn_map(src: Dict[str, np.ndarray], prefix: str):
@@ -106,3 +109,23 @@ def save_r50_npz(path: str, params: Dict) -> int:
                      f"{name}/{conv_name}")
     np.savez(path, **out)
     return len(out)
+
+
+def load_backbone_into(params, param_sh, replicated, path: str):
+    """``params`` with its ``backbone`` subtree filled from the npz at
+    ``path`` (the Trainer's init-time hook, models.pretrained_loader).
+    Gathers ONLY the backbone subtree to ``replicated`` (under fsdp the
+    shards can live on other hosts' devices, where a bare np.asarray
+    would fail); a full-tree gather would put a complete replica on
+    every device and hand back the init-time memory win in exactly the
+    configs fsdp exists for."""
+    import jax
+
+    bb = jax.tree.map(np.asarray,
+                      jax.device_put(params["backbone"], replicated))
+    bb, loaded, expected = load_r50_npz(path, bb)
+    log.info("backbone weights: loaded %d/%d arrays from %s",
+             loaded, expected, path)
+    params = dict(params)
+    params["backbone"] = jax.device_put(bb, param_sh["backbone"])
+    return params

@@ -475,3 +475,28 @@ class MaskRCNN(nn.Module):
             sel = jnp.einsum("bdhwk,bdk->bdhw", mask_logits, onehot)
             out["masks"] = jax.nn.sigmoid(sel)
         return out
+
+
+def decay_mask(freeze_at: int):
+    """Weight decay on *trainable* conv/dense kernels only — biases,
+    norm params, and frozen backbone stages excluded.  The frozen
+    stages get zero gradient (stop_gradient in the backbone), so any
+    decay on them would silently shrink the pretrained weights."""
+    def mask_fn(params):
+        def mask(path, leaf):
+            if path[-1].key != "kernel":
+                return False
+            keys = [p.key for p in path]
+            if keys[0] == "backbone":
+                name = keys[1]
+                if name == "conv0" and freeze_at >= 1:
+                    return False
+                if name.startswith("group"):
+                    stage = int(name[len("group")])
+                    if stage + 2 <= freeze_at:
+                        return False
+            return True
+
+        return jax.tree_util.tree_map_with_path(mask, params)
+
+    return mask_fn

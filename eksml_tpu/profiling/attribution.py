@@ -182,6 +182,17 @@ SCOPE_RULES: Tuple[Tuple[str, str, bool], ...] = (
     ("box-head", r"(^|[/(])(fastrcnn|cascade\d*)($|[/)])", True),
     ("mask-head", r"(^|[/(])maskrcnn($|[/)])", True),
     ("mask-targets", r"(^|[/(])mask_targets($|[/)])", False),
+    # the sequence model's scopes (models/lm/)
+    ("mla-proj", r"(^|[/(])mla($|[/)])", True),
+    ("mla-core", r"(^|[/(])mla_core($|[/)])", True),
+    ("moe-route", r"(^|[/(])moe_route($|[/)])", False),
+    ("moe-dispatch", r"(^|[/(])moe_dispatch($|[/)])", True),
+    ("moe-experts", r"(^|[/(])moe_experts($|[/)])", True),
+    ("moe-combine", r"(^|[/(])moe_combine($|[/)])", True),
+    ("moe-shared", r"(^|[/(])moe_shared($|[/)])", True),
+    ("dense-mlp", r"(^|[/(])dense_mlp($|[/)])", True),
+    ("mtp", r"(^|[/(])mtp($|[/)])", True),
+    ("lm-loss", r"(^|[/(])lm_loss($|[/)])", False),
 )
 _SCOPE_RULES_C = tuple((comp, re.compile(pat), bwd)
                        for comp, pat, bwd in SCOPE_RULES)

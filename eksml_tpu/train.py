@@ -41,7 +41,7 @@ from flax import struct
 
 from eksml_tpu.config import config as global_config
 from eksml_tpu.config import config_from_env, finalize_configs
-from eksml_tpu.models import MaskRCNN
+from eksml_tpu import models
 from eksml_tpu.parallel import (build_mesh, current_topology,
                                 initialize_from_env,
                                 replicated_sharding, validate_topology,
@@ -96,43 +96,27 @@ def lr_schedule(cfg) -> optax.Schedule:
     return sched
 
 
-def _decay_mask(freeze_at: int):
-    """Weight decay on *trainable* conv/dense kernels only — biases,
-    norm params, and frozen backbone stages excluded.  The frozen
-    stages get zero gradient (stop_gradient in the backbone), so any
-    decay on them would silently shrink the pretrained weights."""
-    def mask_fn(params):
-        def mask(path, leaf):
-            if path[-1].key != "kernel":
-                return False
-            keys = [p.key for p in path]
-            if keys[0] == "backbone":
-                name = keys[1]
-                if name == "conv0" and freeze_at >= 1:
-                    return False
-                if name.startswith("group"):
-                    stage = int(name[len("group")])
-                    if stage + 2 <= freeze_at:
-                        return False
-            return True
-
-        return jax.tree_util.tree_map_with_path(mask, params)
-
-    return mask_fn
-
-
 def make_optimizer(cfg):
+    """clip -> [sgd: decay, momentum | adamw: Adam moments, decoupled
+    decay] -> schedule.  What decays is the model's own answer
+    (``models.decay_mask``)."""
     sched = lr_schedule(cfg)
     chain = []
     if cfg.TRAIN.GRADIENT_CLIP > 0:
         # reference optimized chart: TRAIN.GRADIENT_CLIP=0.36
         # (charts/maskrcnn-optimized/values.yaml:32)
         chain.append(optax.clip_by_global_norm(cfg.TRAIN.GRADIENT_CLIP))
+    decay = []
     if cfg.TRAIN.WEIGHT_DECAY > 0:
-        chain.append(optax.add_decayed_weights(
-            cfg.TRAIN.WEIGHT_DECAY,
-            mask=_decay_mask(cfg.BACKBONE.FREEZE_AT)))
-    chain.append(optax.sgd(sched, momentum=cfg.TRAIN.MOMENTUM))
+        decay.append(optax.add_decayed_weights(
+            cfg.TRAIN.WEIGHT_DECAY, mask=models.decay_mask(cfg)))
+    if cfg.TRAIN.OPTIMIZER == "adamw":
+        chain += [optax.scale_by_adam(b1=cfg.TRAIN.ADAM_B1,
+                                      b2=cfg.TRAIN.ADAM_B2,
+                                      eps=cfg.TRAIN.ADAM_EPS),
+                  *decay, optax.scale_by_learning_rate(sched)]
+    else:
+        chain += [*decay, optax.sgd(sched, momentum=cfg.TRAIN.MOMENTUM)]
     return optax.chain(*chain), sched
 
 
@@ -333,7 +317,10 @@ class Trainer:
         # first-collective connect otherwise races per-host compile
         # skew against a fixed deadline (collectives.py)
         warm_mesh_collectives(self.mesh)
-        self.model = MaskRCNN.from_config(cfg)
+        self.model = models.build_model(cfg)
+        # {host span: step-metric keys it carries at log steps}: the
+        # model's counters (none for the detector)
+        self._counter_spans = models.counter_spans(cfg)
         self.tx, self.sched = make_optimizer(cfg)
         # write_metrics=False gives read-only consumers (eval_ckpt) a
         # Trainer that never touches the run's metrics.jsonl/TB events
@@ -420,8 +407,9 @@ class Trainer:
             return self.model.init(r, b, r)["params"]
 
         params, param_sh = self.plan.init_sharded(init_fn, rng, sample)
-        if self.cfg.BACKBONE.WEIGHTS:
-            params = self._load_backbone(params, param_sh)
+        load_pretrained = models.pretrained_loader(self.cfg)
+        if load_pretrained is not None:
+            params = load_pretrained(params, param_sh, self._replicated)
         params = cast_params_for_storage(
             params, getattr(self.cfg.TRAIN, "PARAM_DTYPE", "float32"))
         opt_state, opt_sh = self.plan.init_sharded(
@@ -449,24 +437,6 @@ class Trainer:
             self.plan.describe())
         if log.isEnabledFor(logging.DEBUG):
             log.debug("%s", self.plan.explain(state.params, "params"))
-
-    def _load_backbone(self, params, param_sh):
-        from eksml_tpu.models import load_r50_npz
-
-        # gather ONLY the backbone subtree to replicated (under fsdp
-        # the shards can live on other hosts' devices, where a bare
-        # np.asarray would fail); a full-tree gather would put a
-        # complete replica on every device and hand back the init-time
-        # memory win in exactly the configs fsdp exists for
-        bb = jax.tree.map(
-            np.asarray,
-            jax.device_put(params["backbone"], self._replicated))
-        bb, loaded, expected = load_r50_npz(self.cfg.BACKBONE.WEIGHTS, bb)
-        log.info("backbone weights: loaded %d/%d arrays from %s",
-                 loaded, expected, self.cfg.BACKBONE.WEIGHTS)
-        params = dict(params)
-        params["backbone"] = jax.device_put(bb, param_sh["backbone"])
-        return params
 
     def _alt_restore_target(self, state):
         """Replicated-layout restore target for
@@ -1034,6 +1004,13 @@ class Trainer:
                         # the sync the log row needs anyway
                         metrics = jax.tree.map(
                             lambda x: float(np.asarray(x)), metrics)  # eksml-lint: disable=host-sync
+                    for name, keys in self._counter_spans.items():
+                        # the model's counters of THIS step, where a
+                        # reader of the span ring can see them
+                        with telemetry.span(name, step=step, attrs={
+                                k: metrics[k] for k in keys
+                                if k in metrics}):
+                            pass
                     if data_health is not None:
                         metrics.update(
                             {f"data/{k}": float(v) for k, v
@@ -1615,10 +1592,10 @@ def main(argv=None):
     log.info("process %d/%d, devices: %d", jax.process_index(),
              jax.process_count(), len(jax.devices()))
 
-    from eksml_tpu.data import DetectionLoader, SyntheticDataset
+    from eksml_tpu.data import build_train_loader
 
     eval_fn = None
-    if not cfg.DATA.SYNTHETIC:
+    if cfg.MODEL.NAME == "maskrcnn" and not cfg.DATA.SYNTHETIC:
         from eksml_tpu.evalcoco import make_eval_fn
 
         eval_fn = make_eval_fn(cfg)
@@ -1638,31 +1615,10 @@ def main(argv=None):
                           for d in trainer.mesh.devices.flat)
         per_host_batch = cfg.TRAIN.BATCH_SIZE_PER_CHIP * max(
             1, local_chips)
-        if cfg.DATA.SYNTHETIC:
-            records = SyntheticDataset(
-                num_images=64, height=cfg.PREPROC.MAX_SIZE,
-                width=cfg.PREPROC.MAX_SIZE,
-                num_classes=cfg.DATA.NUM_CLASSES).records()
-        else:
-            from eksml_tpu.data import CocoDataset
-
-            records = []
-            for split in cfg.DATA.TRAIN:
-                # preflight: unknown categories / degenerate fields /
-                # sampled file-existence probe, BEFORE the first step —
-                # warn-and-continue or strict-abort (RESILIENCE.DATA.*)
-                records += CocoDataset(
-                    cfg.DATA.BASEDIR, split,
-                    validate=cfg.RESILIENCE.DATA.VALIDATE,
-                    validate_sample=cfg.RESILIENCE.DATA.VALIDATE_SAMPLE,
-                ).records()
-
-        loader = DetectionLoader(
-            records, cfg, per_host_batch, is_training=True,
-            num_hosts=jax.process_count(), host_id=jax.process_index(),
-            seed=cfg.TRAIN.SEED, with_masks=cfg.MODE_MASK,
-            ledger_dir=cfg.TRAIN.LOGDIR,
-            num_slices=int(cfg.TPU.NUM_SLICES))
+        # the model's loader, chosen by MODEL.NAME as the model is
+        loader = build_train_loader(
+            cfg, per_host_batch, num_hosts=jax.process_count(),
+            host_id=jax.process_index())
 
         total_steps = (args.total_steps
                        if args.total_steps is not None

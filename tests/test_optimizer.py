@@ -10,7 +10,8 @@ stopped, so decay would silently shrink pretrained weights).
 import jax.numpy as jnp
 import pytest
 
-from eksml_tpu.train import _decay_mask, lr_schedule
+from eksml_tpu.models.mask_rcnn import decay_mask as _decay_mask
+from eksml_tpu.train import lr_schedule
 
 
 def test_lr_boundaries_scale_with_global_batch(fresh_config):

@@ -211,3 +211,64 @@ def test_dispatch_compiles_on_a_four_chip_mesh(four_chip_mesh,
     assert _kernel_sites(compiled) >= 2  # forward and backward
     with pytest.raises(NotImplementedError, match="partitioned"):
         jax.jit(loss).lower(feats, rois).compile()
+
+
+# ---- the sequence model's kernels (models/lm) at the published widths
+
+
+def test_lm_attention_core_compiles_for_v5e(one_chip, monkeypatch):
+    """jax's splash-attention kernel, forward and the fused backward
+    kernel, at the cell's shapes: 2 rows x 4096 positions, 32 heads,
+    q/k 192 wide and v 128 (a value width of its own), bf16.  The
+    kernels keep the names the roofline readers look for."""
+    from eksml_tpu.models.lm import attention
+
+    # the gate asks jax.default_backend(), the CPU here: compile the
+    # kernel itself, not the interpreter
+    monkeypatch.setattr(attention.jax, "default_backend", lambda: "tpu")
+    attention._splash_kernel.cache_clear()
+
+    def s(width):
+        return jax.ShapeDtypeStruct((2, 4096, 32, width), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    def loss(q, k, v):
+        with jax.named_scope("mla_core"):
+            o = attention.causal_attention(q, k, v, 512)
+        return o.astype(jnp.float32).sum()
+
+    try:
+        compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            s(192), s(192), s(128)).compile()
+    finally:
+        attention._splash_kernel.cache_clear()
+    names = set(_kernel_names(compiled))
+    # one fused backward kernel (dq, dk, dv) beside the forward
+    assert names == {"splash_mha_fwd_residuals",
+                     "splash_mha_dkv_no_residuals"}, names
+
+
+def test_lm_grouped_product_compiles_for_v5e(one_chip):
+    """The expert layer's routed part at the cell's size: 8192 tokens x
+    8 pairs through 16 held experts of 2048 x 768.  XLA lowers
+    ``ragged_dot`` to its grouped Mosaic kernel (work follows the group
+    sizes), named ``ragged-dot-none*``: forward, input and weight
+    gradients alike."""
+    from eksml_tpu.models.lm import moe
+
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def loss(h, ids, gates, wg, wu, wd):
+        out, _ = moe.held_experts(h, ids, gates, wg, wu, wd, 0)
+        return out.astype(jnp.float32).sum()
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 3, 4, 5))).lower(
+        s((8192, 2048)), s((8192, 8), jnp.int32),
+        s((8192, 8), jnp.float32), s((16, 2048, 768)),
+        s((16, 2048, 768)), s((16, 768, 2048))).compile()
+    names = _kernel_names(compiled)
+    # 2 forward products feed the gradient (the third's value is dead
+    # code under grad-of-sum), 3 input-gradient and 3 weight-gradient
+    assert names.count("ragged-dot-none") == 8, names
+    assert "ragged-dot-metadata" in names
