@@ -2,6 +2,7 @@
 """Chip smoke: the training main path, once, on the attached TPU.
 
     python chip_smoke.py               # one chip: device, kernel, train, resume
+    python chip_smoke.py --phases kernel  # one chip: device, then only these
     python chip_smoke.py --four-chips  # four chips: 1-device vs (4,1) mesh only
     python chip_smoke.py --rehearse    # control flow at smoke widths, any
                                        # platform; never prints "ok": true
@@ -32,6 +33,7 @@ from __future__ import annotations
 
 import argparse
 import gc
+import itertools
 import json
 import math
 import os
@@ -85,10 +87,14 @@ def result_line(ok: bool, device: dict | None, **extra) -> dict:
     return {"ok": False, "device": device, **extra}
 
 
+ONE_CHIP_PHASES = ("kernel", "train", "resume")
+
+
 def phases_for(args) -> tuple:
     if args.four_chips:
         return ("device", "four_chips")
-    return ("device", "kernel", "train", "resume")
+    return ("device",) + tuple(
+        p for p in ONE_CHIP_PHASES if p in (args.phases or ONE_CHIP_PHASES))
 
 
 # ---------------------------------------------------------------- device
@@ -158,12 +164,25 @@ def phase_kernel(args) -> None:
                  ("box", 512, 7, jnp.float32)]
     interpret = bool(args.rehearse)
     rng = np.random.RandomState(args.seed)
-    for head, n, out_size, dtype in cases:
+
+    def roi_sides(roi_set, shape):
+        if roi_set == "log_uniform":
+            # boxes from 16 px to most of the image: every level and
+            # every strip count of the backward is hit
+            return np.exp(rng.uniform(np.log(16), np.log(img * 0.9),
+                                      shape))
+        # anchor-sized: what a fresh RPN proposes (32-64 px anchors at
+        # three aspect ratios) — the benchmark cells' distribution
+        side = np.exp(rng.uniform(np.log(32), np.log(64), shape[:-1]))
+        ratio = rng.choice([0.5, 1.0, 2.0], shape[:-1])
+        return np.stack([side * np.sqrt(ratio), side / np.sqrt(ratio)],
+                        -1)
+
+    for roi_set, (head, n, out_size, dtype) in itertools.product(
+            ("log_uniform", "anchor"), cases):
         feats = tuple(jnp.asarray(
             rng.randn(b, img // s, img // s, c), dtype) for s in strides)
-        # boxes from 16 px to most of the image: every level is hit
-        side = np.exp(rng.uniform(np.log(16), np.log(img * 0.9),
-                                  (b, n, 2)))
+        side = roi_sides(roi_set, (b, n, 2))
         xy = rng.uniform(0, 1, (b, n, 2)) * (img - 1 - side)
         rois = jnp.asarray(np.concatenate([xy, xy + side], -1),
                            jnp.float32)
@@ -215,7 +234,8 @@ def phase_kernel(args) -> None:
         fwd_err = rel_err(out_k, out_r)
         bwd_err = max(rel_err(a, r) for a, r in zip(grads_k, grads_r))
         used = sorted(set(np.asarray(levels).ravel().tolist()))
-        emit({"phase": "kernel", "head": head, "rois": n,
+        emit({"phase": "kernel", "rois_set": roi_set, "head": head,
+              "rois": n,
               "out_size": out_size, "dtype": np.dtype(dtype).name,
               "channels": c, "levels_hit": used, "interpret": interpret,
               "fwd_rel_err": fwd_err, "fwd_tol": KERNEL_FWD_TOL,
@@ -224,7 +244,8 @@ def phase_kernel(args) -> None:
         if not (fwd_err <= KERNEL_FWD_TOL and bwd_err <= KERNEL_BWD_TOL):
             raise AssertionError(
                 f"ROIAlign kernel disagrees with the XLA formulation "
-                f"({head} head, {np.dtype(dtype).name}): fwd "
+                f"({head} head, {np.dtype(dtype).name}, {roi_set} "
+                f"ROIs): fwd "
                 f"{fwd_err:.3g}, bwd {bwd_err:.3g}")
 
 
@@ -535,10 +556,17 @@ def parse_args(argv=None):
                    help="smoke widths, any platform, interpret-mode "
                         "kernel; reports ok:false and exits "
                         f"{REHEARSAL_EXIT} when every phase passed")
+    p.add_argument("--phases", type=lambda v: tuple(v.split(",")),
+                   help="one chip: run only these of "
+                        f"{','.join(ONE_CHIP_PHASES)} (after device)")
     p.add_argument("--steps", type=int, default=5,
                    help="train steps per trainer run [%(default)s]")
     p.add_argument("--seed", type=int, default=0)
-    return p.parse_args(argv)
+    args = p.parse_args(argv)
+    unknown = set(args.phases or ()) - set(ONE_CHIP_PHASES)
+    if unknown:
+        p.error(f"--phases: unknown {sorted(unknown)}")
+    return args
 
 
 def main(argv=None) -> int:

@@ -70,5 +70,9 @@ def pretrained_loader(cfg):
 def counter_spans(cfg) -> dict:
     """{host span name: keys of the step's metrics it carries at log
     steps}: the model's counters, where the span ring's readers see
-    them.  The detector has none."""
-    return {} if _is_detector(cfg) else dict(_lm().COUNTER_SPANS)
+    them."""
+    if _is_detector(cfg):
+        from eksml_tpu.models import mask_rcnn
+
+        return dict(mask_rcnn.COUNTER_SPANS)
+    return dict(_lm().COUNTER_SPANS)

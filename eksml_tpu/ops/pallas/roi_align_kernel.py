@@ -33,12 +33,20 @@ Semantics notes:
   extreme aspect ratios.
 
 The backward wrt features is the TRANSPOSE of the same separable
-linear map, so it is also two MXU matmuls per ROI — no scatter at all:
-``d_tile = RyPᵀ @ g @ CxP`` with the *pooled* weight matrices
-(``RyP[i,t] = mean_a Ry[i·s+a, t]``; pooling is linear so it folds into
-the weights), accumulated into per-level HBM buffers via sequential
+linear map, accumulated into per-level f32 HBM buffers by sequential
 read-modify-write DMA (the grid is sequential per core — no write
-races; buffers start zeroed through ``input_output_aliases``).
+races; buffers start zeroed through ``input_output_aliases``).  It
+moves and multiplies the ROI's FOOTPRINT, not the tile: the rows and
+columns that carry a non-zero weight are covered by strips of one
+fixed shape (``STRIP_H × STRIP_W``, ``_bwd_prep``; an ROI that fills
+the tile still gets all of it), and a strip's update is one MXU
+product with channels in lanes, ``d[(y x), c] = Σ_(i j) RyP[i,y] ·
+CxP[j,x] · g[(i j), c]`` with the *pooled* weights
+(``RyP[i,t] = mean_a Ry[i·s+a, t]``; pooling is linear so it folds
+into the weights) — no scatter, no transpose.  Write-back is
+asynchronous over two staging strips (``_bwd_kernel``).
+``bwd_tile_share`` is the share of the tile the strips cover, the
+step's ``roi_bwd_tile_share`` counter.
 ``EKSML_ROI_BWD={auto,pallas,xla}`` selects it (auto = the kernel on
 a TPU backend, the XLA gather-transpose formulation via
 ``jax.custom_vjp`` elsewhere).
@@ -55,6 +63,15 @@ import jax.numpy as jnp
 import numpy as np
 
 TILE = 64  # T: per-ROI feature tile (covers √area/stride ≲ 56 + taps)
+# The backward moves its f32 accumulators in strips of this one shape
+# (rows × columns; DMA shapes are static); 4 × 4 of them cover a tile.
+# Chosen on the chip (PERF.md §6, PR 29): the kernel's time is 0.9 µs a
+# ROI + 0.5 µs a strip + 3.1 ns a strip pixel (the MXU), and 16 × 16
+# gave the least on both ROI sets among 8×32, 16×16, 16×32, 32×32, 8×64.
+STRIP_H, STRIP_W = 16, 16
+# W origin of a strip: the f32 accumulators' sublane tile, whatever the
+# features' dtype (the forward's origin follows ``sublane_align``)
+_BWD_ALIGN = 8
 
 # Mosaic's default per-kernel scoped-vmem stack is 16 MiB, and the
 # production mask-head call (double-buffered 64×64×256 tile scratch +
@@ -74,15 +91,13 @@ def _scoped_vmem_kib() -> int:
                               str(_SCOPED_VMEM_KIB)))
 
 
-def _compiler_params(extra_bytes: int = 0):
+def _compiler_params():
     """Per-kernel Mosaic params carrying the scoped-vmem stack limit
-    IN the compiled module.  The ONE construction site for the limit:
-    callers whose kernel carries extra scratch (the bwd overlap
-    pipeline) declare it here."""
+    IN the compiled module.  The ONE construction site for the limit."""
     from jax.experimental.pallas import tpu as pltpu
 
     return pltpu.CompilerParams(
-        vmem_limit_bytes=_scoped_vmem_kib() * 1024 + extra_bytes)
+        vmem_limit_bytes=_scoped_vmem_kib() * 1024)
 
 
 def sublane_align(dtype) -> int:
@@ -121,12 +136,22 @@ def pallas_roi_align_supported() -> bool:
     return _use_kernel("EKSML_ROI_BACKEND")
 
 
-def _bilinear_weights(start, binsz, out_size: int, sampling: int):
-    """[S, T] two-tap bilinear weight matrix for sample coords
+def _tap_weights(start, binsz, s_idx, t_idx, sampling: int):
+    """Two-tap bilinear weight of feature position ``t_idx`` for sample
+    ``s_idx`` (float arrays of one shape), sample coords
     ``start + (bin + (j+0.5)/sampling) * binsz`` — the ONE definition
     of the sampling semantics; forward contracts it directly, backward
     uses its bin-pooled mean.  Any change here keeps fwd/bwd transposed
     by construction."""
+    bins = jnp.floor(s_idx / sampling)
+    off = (s_idx - bins * sampling + 0.5) / sampling
+    coord = start + (bins + off) * binsz
+    return jnp.maximum(0.0, 1.0 - jnp.abs(coord - t_idx))
+
+
+def _bilinear_weights(start, binsz, out_size: int, sampling: int):
+    """[S, T] weight matrix of the forward: samples down, tile
+    positions across."""
     s_total = out_size * sampling
     f32 = jnp.float32
     # Mosaic's iota is integer-only; build int32 and convert
@@ -134,10 +159,7 @@ def _bilinear_weights(start, binsz, out_size: int, sampling: int):
         jnp.int32, (s_total, TILE), 0).astype(f32)
     t_idx = jax.lax.broadcasted_iota(
         jnp.int32, (s_total, TILE), 1).astype(f32)
-    bins = jnp.floor(s_idx / sampling)
-    off = (s_idx - bins * sampling + 0.5) / sampling
-    coord = start + (bins + off) * binsz
-    return jnp.maximum(0.0, 1.0 - jnp.abs(coord - t_idx))
+    return _tap_weights(start, binsz, s_idx, t_idx, sampling)
 
 
 def _kernel(out_size: int, sampling: int, num_levels: int, align: int,
@@ -242,199 +264,157 @@ def _kernel(out_size: int, sampling: int, num_levels: int, align: int,
 
 
 def _bwd_kernel(out_size: int, sampling: int, num_levels: int,
-                align: int, overlap: bool,
                 # scalar prefetch (SMEM), one entry per ROI:
-                lvl_ref, b_ref, y0_ref, x0_ref,
-                ys_ref, xs_ref, bh_ref, bw_ref,
+                lvl_ref, b_ref, ya_ref, xa_ref,   # level/batch/strip origin
+                ny_ref, nx_ref,                   # strips down / across
+                ys_ref, xs_ref, bh_ref, bw_ref,   # f32 start/bin size
                 *refs):
-    """Transpose of ``_kernel``: d_tile = RyPᵀ @ g @ CxP, accumulated
-    into the per-level gradient buffer by RMW DMA.
+    """Transpose of ``_kernel``, over the ROI's footprint only: the
+    rows and columns of the level's map that carry a non-zero weight
+    are covered by ``ny × nx`` strips of ONE shape
+    ``[STRIP_H, STRIP_W, C]`` (``_bwd_prep``), and each strip of the
+    f32 accumulator is read, updated and written back by DMA.
 
-    With ``overlap=True`` the write-back is ASYNC: ROI r's out-DMA
-    stays in flight while ROI r+1's tile read and matmuls run (the RMW
-    moves 2×4 MiB per ROI at TILE=64/C=256/f32 — fully serialized
-    read→compute→write was the measured bwd bottleneck at 1344 px).
-    Correctness bookkeeping, all in SMEM scalar flags:
+    A strip's update is one MXU product with channels in lanes on both
+    sides: ``d[(y x), c] = Σ_(i j) RyP[i, y]·CxP[j, x] · g[(i j), c]``.
+    The ``[STRIP_H·STRIP_W, out²]`` weight matrix is the outer product
+    of the two pooled ``_tap_weights`` vectors, built on the VPU; the
+    product streams one LHS row per strip pixel, so the MXU's time
+    follows the footprint too.  (The separable order — columns, then
+    rows — streams ``STRIP_W·C`` rows of a 7-long contraction per
+    strip; that, not the DMA, was the 64×64 kernel's 26 of 31 µs.)
 
-    - two staging slots (``acc_tile[2]``), so the in-flight write's
-      buffer is never the one being refilled;
-    - a RAW-hazard drain: if ROI r's tile REGION (level, batch, y/x
-      origin within TILE) can overlap ROI r-1's, the previous write is
-      waited before r's read — overlapping writes are thereby also
-      ordered (WAW safe);
-    - slot reuse drains the write issued two steps ago, and the final
-      grid step drains everything.
+    The write-back is asynchronous over two staging slots: strip s's
+    write stays in flight while strip s+1 is read and formed.
+    Bookkeeping in SMEM (``state``: one pending flag per slot, and the
+    count of strips issued, which picks the slot):
 
-    Every out-DMA moves the same [T,T,C] f32 byte count, so waits are
-    issued against a fixed level-0 region descriptor — a DMA wait is
-    semaphore + byte-count accounting, not an address match."""
+    - a slot is drained (its write of two strips ago waited) before it
+      is refilled, so the only write in flight during a read is the
+      previous strip's;
+    - strips of one ROI are disjoint; the previous strip can overlap
+      only across an ROI boundary, so at an ROI's start both slots are
+      drained when its strips' bounding box meets the previous ROI's
+      (same level and image) — RAW and WAW safe;
+    - the final grid step drains everything.
+
+    Every DMA moves one strip's byte count, so waits are issued
+    against a fixed level-0 descriptor — a DMA wait is semaphore +
+    byte-count accounting, not an address match."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    g_ref = refs[0]                         # VMEM [1, out, out, C]
+    g_ref = refs[0]                         # VMEM [1, out*out, C]
     # refs[1 : 1+L] are the zero-initialized ANY inputs aliased to the
     # outputs — unused directly; the RMW goes through the out refs
     acc_refs = refs[1 + num_levels: 1 + 2 * num_levels]  # ANY outputs
-    if overlap:
-        acc_tile = refs[1 + 2 * num_levels]   # VMEM [2, T, T, C] f32
-        in_sem = refs[1 + 2 * num_levels + 1]
-        out_sem = refs[1 + 2 * num_levels + 2]   # DMA sems (2,)
-        pending = refs[1 + 2 * num_levels + 3]   # SMEM (2,) int32
-    else:
-        acc_tile = refs[1 + 2 * num_levels]   # VMEM scratch [T, T, C]
-        sem = refs[1 + 2 * num_levels + 1]    # DMA semaphore
+    strips, in_sem, out_sem, state = refs[1 + 2 * num_levels:]
 
+    f32 = jnp.float32
+    oo = out_size * out_size
     r = pl.program_id(0)
     lvl = lvl_ref[r]
     b = b_ref[r]
-    y0 = y0_ref[r]
-    x0 = x0_ref[r] * align                  # see _kernel: provable align
+    ya = ya_ref[r]
+    xa = xa_ref[r] * _BWD_ALIGN             # see _kernel: provable align
+    ny = ny_ref[r]
+    nx = nx_ref[r]
 
-    if overlap:
-        n = pl.num_programs(0)
-        slot = r % 2
+    @pl.when(r == 0)
+    def _():
+        state[0] = 0
+        state[1] = 0
+        state[2] = 0
 
-        @pl.when(r == 0)
-        def _():
-            pending[0] = 0
-            pending[1] = 0
+    def on_level(op):
+        for i in range(num_levels):
+            pl.when(lvl == i)(functools.partial(op, acc_refs[i]))
 
-        def wait_out(s):
-            # fixed-region descriptor: same byte count as every
-            # out-DMA (see docstring)
-            pltpu.make_async_copy(
-                acc_tile.at[s],
-                acc_refs[0].at[0, pl.ds(0, TILE), pl.ds(0, TILE), :],
-                out_sem.at[s]).wait()
+    def window(acc, bb, y, x):
+        return acc.at[bb, pl.ds(y, STRIP_H), pl.ds(x, STRIP_W), :]
 
+    fixed = window(acc_refs[0], 0, 0, 0)
+
+    def drain(s, cond):
         # All SMEM flag accesses use STATIC indices (slot-parity
-        # branches): the forward kernel proves dynamic VMEM slot
-        # indexing on hardware, but a dynamically-indexed SMEM STORE
-        # is an unproven Mosaic construct — don't bet the kernel on it.
-        def drain(s, extra_cond):
-            @pl.when(extra_cond & (pending[s] == 1))
-            def _():
-                wait_out(s)
-                pending[s] = 0
+        # branches); a dynamically-indexed SMEM store is an unproven
+        # Mosaic construct.
+        @pl.when(cond & (state[s] == 1))
+        def _():
+            pltpu.make_async_copy(strips.at[s], fixed,
+                                  out_sem.at[s]).wait()
+            state[s] = 0
 
-        # slot reuse: drain the write issued two grid steps ago
-        drain(0, slot == 0)
-        drain(1, slot == 1)
+    rp = jnp.maximum(r - 1, 0)
+    yp = ya_ref[rp]
+    xp = xa_ref[rp] * _BWD_ALIGN
+    clash = ((r >= 1) & (lvl_ref[rp] == lvl) & (b_ref[rp] == b)
+             & (ya < yp + ny_ref[rp] * STRIP_H)
+             & (yp < ya + ny * STRIP_H)
+             & (xa < xp + nx_ref[rp] * STRIP_W)
+             & (xp < xa + nx * STRIP_W))
+    drain(0, clash)
+    drain(1, clash)
 
-        # RAW hazard vs the previous ROI's in-flight write (lives on
-        # the OTHER slot): conservative region-overlap test on
-        # (level, batch, tile origins)
-        rp = jnp.maximum(r - 1, 0)
-        xp = x0_ref[rp] * align
-        same = ((r >= 1) & (lvl_ref[rp] == lvl) & (b_ref[rp] == b)
-                & (jnp.abs(y0_ref[rp] - y0) < TILE)
-                & (jnp.abs(xp - x0) < TILE))
-        drain(0, same & (slot == 1))
-        drain(1, same & (slot == 0))
+    def pooled(start, binsz, t0, t_axis, size):
+        """Bin-pooled tap weights of strip positions ``t0 .. t0+size``
+        (down ``t_axis`` of a ``[STRIP_H|1, STRIP_W|1, out²]`` array)
+        for the output bins of the other axis's flattened ``(i j)``
+        index: ``i = q // out`` for rows, ``j = q % out`` for columns."""
+        shape = [1, 1, oo]
+        shape[t_axis] = size
+        t_idx = (jax.lax.broadcasted_iota(jnp.int32, shape, t_axis)
+                 + t0).astype(f32)
+        q = jax.lax.broadcasted_iota(jnp.int32, shape, 2).astype(f32)
+        i_idx = jnp.floor((q + 0.5) / out_size)
+        bin_idx = i_idx if t_axis == 0 else q - i_idx * out_size
+        return sum(
+            _tap_weights(start, binsz, bin_idx * sampling + a, t_idx,
+                         sampling)
+            for a in range(sampling)) / sampling
 
-        # read the current accumulation tile (blocking)
-        for i in range(num_levels):
-            @pl.when(lvl == i)
-            def _(i=i):
-                dma = pltpu.make_async_copy(
-                    acc_refs[i].at[b, pl.ds(y0, TILE),
-                                   pl.ds(x0, TILE), :],
-                    acc_tile.at[slot], in_sem)
-                dma.start()
-                dma.wait()
-    else:
-        # read the current accumulation tile
-        for i in range(num_levels):
-            @pl.when(lvl == i)
-            def _(i=i):
-                dma = pltpu.make_async_copy(
-                    acc_refs[i].at[b, pl.ds(y0, TILE),
-                                   pl.ds(x0, TILE), :],
-                    acc_tile, sem)
-                dma.start()
-                dma.wait()
-
+    g = g_ref[0].astype(f32)                                # [oo, C]
+    c = g.shape[-1]
     y_start = ys_ref[r]
     x_start = xs_ref[r]
     bin_h = bh_ref[r]
     bin_w = bw_ref[r]
 
-    f32 = jnp.float32
+    def column(cx, issued):
+        x = xa + cx * STRIP_W
+        cxp = pooled(x_start, bin_w, cx * STRIP_W, 1, STRIP_W)
 
-    def pooled_weights(start, binsz):
-        """[out, T]: the fwd's weight matrix averaged over each bin's
-        ``sampling`` sample points (pooling is linear, so the sample
-        axis folds into the weights)."""
-        w = _bilinear_weights(start, binsz, out_size, sampling)  # [S, T]
-        return w.reshape(out_size, sampling, TILE).mean(axis=1)
+        def update_strip(ry, issued):
+            slot = issued % 2
+            y = ya + ry * STRIP_H
+            # slot reuse: drain the write issued two strips ago
+            drain(0, slot == 0)
+            drain(1, slot == 1)
+            on_level(lambda acc: pltpu.make_async_copy(
+                window(acc, b, y, x), strips.at[slot], in_sem).start())
+            ryp = pooled(y_start, bin_h, ry * STRIP_H, 0, STRIP_H)
+            # HIGHEST precision: the MXU multiplies in bf16 passes
+            d = jnp.dot((ryp * cxp).reshape(STRIP_H * STRIP_W, oo), g,
+                        preferred_element_type=f32,
+                        precision=jax.lax.Precision.HIGHEST)
+            pltpu.make_async_copy(fixed, strips.at[slot], in_sem).wait()
+            strips[slot] = strips[slot] + d.reshape(STRIP_H, STRIP_W, c)
+            on_level(lambda acc: pltpu.make_async_copy(
+                strips.at[slot], window(acc, b, y, x),
+                out_sem.at[slot]).start())
+            for s in range(2):
+                @pl.when(slot == s)
+                def _(s=s):
+                    state[s] = 1
+            return issued + 1
 
-    ryp = pooled_weights(y_start, bin_h)                       # [out, T]
-    cxp = pooled_weights(x_start, bin_w)                       # [out, T]
+        return jax.lax.fori_loop(0, ny, update_strip, issued)
 
-    g_tile = g_ref[0].astype(f32)                              # [o, o, C]
-    c = g_tile.shape[-1]
-    # rows: [T, out] @ [out, out*C] → [T, out, C]
-    rows = jnp.dot(ryp.T, g_tile.reshape(out_size, out_size * c),
-                   preferred_element_type=f32,
-                   precision=jax.lax.Precision.HIGHEST
-                   ).reshape(TILE, out_size, c)
-    # cols: contract out with cxp → [T, C, T] → [T, T, C]
-    d_tile = jax.lax.dot_general(
-        rows, cxp,
-        dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=f32,
-        precision=jax.lax.Precision.HIGHEST).transpose(0, 2, 1)
+    state[2] = jax.lax.fori_loop(0, nx, column, state[2])
 
-    if overlap:
-        acc_tile[slot] = acc_tile[slot] + d_tile
-
-        # async write-back: overlaps the next ROI's read + matmuls
-        for i in range(num_levels):
-            @pl.when(lvl == i)
-            def _(i=i):
-                pltpu.make_async_copy(
-                    acc_tile.at[slot],
-                    acc_refs[i].at[b, pl.ds(y0, TILE),
-                                   pl.ds(x0, TILE), :],
-                    out_sem.at[slot]).start()
-
-        @pl.when(slot == 0)
-        def _():
-            pending[0] = 1
-
-        @pl.when(slot == 1)
-        def _():
-            pending[1] = 1
-
-        # final grid step: nothing after this to drain us — wait both
-        # (static slot-parity branches; own slot's pending was just
-        # set, the other's may have been hazard-drained already)
-        last = r == n - 1
-
-        def final_drain(s, my_slot):
-            @pl.when(last & (slot == my_slot) & (pending[s] == 1))
-            def _():
-                wait_out(s)
-                pending[s] = 0
-
-        final_drain(1, 0)   # other slot first (the older write)
-        final_drain(0, 1)
-        final_drain(0, 0)   # then the write this very step issued
-        final_drain(1, 1)
-    else:
-        acc_tile[:] = acc_tile[:] + d_tile
-
-        # write the updated tile back (sequential grid — no races)
-        for i in range(num_levels):
-            @pl.when(lvl == i)
-            def _(i=i):
-                dma = pltpu.make_async_copy(
-                    acc_tile,
-                    acc_refs[i].at[b, pl.ds(y0, TILE),
-                                   pl.ds(x0, TILE), :],
-                    sem)
-                dma.start()
-                dma.wait()
+    last = r == pl.num_programs(0) - 1
+    drain(0, last)
+    drain(1, last)
 
 
 def _prep(feats, rois, strides, out_size, min_level, align):
@@ -477,6 +457,52 @@ def _prep(feats, rois, strides, out_size, min_level, align):
             ys, xs, bin_h, bin_w)
 
 
+def _bwd_prep(feats, rois, strides, out_size, min_level, align):
+    """The backward's twin of ``_prep``: same levels, sample starts and
+    bin sizes, but instead of one tile origin the cover of the ROI's
+    FOOTPRINT by ``ny × nx`` strips of ``[STRIP_H, STRIP_W]``.  A row
+    carries a non-zero weight from ``floor(y1 − 0.5)`` (the first
+    sample's upper tap) to ``floor(y2 − 0.5) + 1`` (the last sample's
+    lower tap), columns likewise; the column origin is rounded down to
+    ``_BWD_ALIGN`` and shipped as a block count, and both origins are
+    pulled in so the last strip ends inside the padded map.  The
+    tile-fit level assignment bounds the footprint by the tile, so at
+    most ``TILE/STRIP_H × TILE/STRIP_W`` strips are ever needed."""
+    levels, batch_idx, y0, x0, ys, xs, bin_h, bin_w = _prep(
+        feats, rois, strides, out_size, min_level, align)
+    shapes = jnp.asarray([f.shape[1:3] for f in feats], jnp.int32)[levels]
+
+    def cover(origin, start, binsz, size, strip, align_to):
+        lo = origin.astype(jnp.float32) + start      # y1 − 0.5 on the map
+        first = jnp.clip(jnp.floor(lo).astype(jnp.int32), 0, size - 1)
+        last = jnp.clip(
+            jnp.floor(lo + out_size * binsz).astype(jnp.int32) + 1,
+            first, size - 1)
+        first = first // align_to * align_to
+        count = jnp.clip((last - first) // strip + 1, 1, TILE // strip)
+        first = jnp.minimum(first, size - count * strip)
+        return first, count, lo - first.astype(jnp.float32)
+
+    ya, ny, ys = cover(y0, ys, bin_h, shapes[:, 0], STRIP_H, 1)
+    xa, nx, xs = cover(x0 * align, xs, bin_w, shapes[:, 1], STRIP_W,
+                       _BWD_ALIGN)
+    return (levels, batch_idx, ya, xa // _BWD_ALIGN, ny, nx,
+            ys, xs, bin_h, bin_w)
+
+
+def bwd_tile_share(feats, rois, strides, out_size: int = 7,
+                   min_level: int = 2):
+    """Mean over ROIs of the accumulator bytes the backward kernel
+    moves (its strips) over the bytes of a ``TILE × TILE`` tile, from
+    ``_bwd_prep``'s own strip counts.  A pure function of the ROIs and
+    the levels' shapes: 1.0 when every ROI fills its tile."""
+    align = sublane_align(feats[0].dtype)
+    padded = jax.eval_shape(lambda fs: _pad_levels(fs, align), list(feats))
+    prep = _bwd_prep(padded, rois, strides, out_size, min_level, align)
+    strips = (prep[4] * prep[5]).astype(jnp.float32)
+    return strips.mean() * (STRIP_H * STRIP_W / (TILE * TILE))
+
+
 def _pad_levels(feats, align):
     """Zero-pad each level's spatial dims to ≥ TILE, and W additionally
     to a multiple of ``align`` so the clamped tile x-origin stays
@@ -506,17 +532,17 @@ _VMEM_STACK_BUDGET = 13 * 2 ** 20   # leave ~3 MiB for spills/semaphores
 
 
 def _roi_chunk(n_total: int, out_size: int, c: int, dtype,
-               scratch_bytes: int, extra_budget: int = 0) -> int:
+               scratch_bytes: int) -> int:
     """Largest divisor of ``n_total`` whose per-call stack estimate
     (chunk's output + kernel scratch) fits the scoped-vmem budget
     (module-level ``_VMEM_STACK_BUDGET``, read at call time so tests
-    can monkeypatch it, plus the caller's ``extra_budget``).
+    can monkeypatch it).
     The per-ROI size uses the TILED output layout (W padded to the
     sublane tile, 7→8 / 14→16) — the buffer XLA would actually pack."""
     esize = jnp.dtype(dtype).itemsize
     out_pad = out_size + (-out_size % 8)
     per_roi = out_size * out_pad * c * esize
-    room = max(_VMEM_STACK_BUDGET + extra_budget - scratch_bytes, per_roi)
+    room = max(_VMEM_STACK_BUDGET - scratch_bytes, per_roi)
     bound = max(room // per_roi, 1)
     if n_total <= bound:
         return n_total
@@ -637,6 +663,15 @@ def _to_hbm(x):
     )(x)
 
 
+def _bwd_scratch_bytes(out_size: int, c: int) -> int:
+    """The backward kernel's own vmem: two staging strips, and the two
+    values a strip's update holds at once (its ``[strip, out²]`` weight
+    matrix, lanes padded to 128, and the ``[strip, C]`` product)."""
+    strip = STRIP_H * STRIP_W * 4
+    lanes = -(-out_size * out_size // 128) * 128
+    return strip * (3 * c + lanes)
+
+
 def _pallas_backward(feats, rois, g, strides, out_size, sampling,
                      min_level, interpret):
     """Per-level feature gradients via the transpose kernel.  Returns
@@ -648,24 +683,21 @@ def _pallas_backward(feats, rois, g, strides, out_size, sampling,
     padded = _pad_levels(feats, align)
     b, n = rois.shape[0], rois.shape[1]
     c = padded[0].shape[-1]
-    scalars = _prep(padded, rois, strides, out_size, min_level, align)
+    scalars = _bwd_prep(padded, rois, strides, out_size, min_level, align)
     num_levels = len(padded)
-    # async write-back pipeline (see _bwd_kernel docstring); A/B knob
-    overlap = os.environ.get("EKSML_BWD_OVERLAP", "1") != "0"
     kern = functools.partial(_bwd_kernel, out_size, sampling,
-                             num_levels, align, overlap)
+                             num_levels)
 
-    g_flat = g.reshape(b * n, out_size, out_size, c)
+    # (i j) flattened on the XLA side: the kernel contracts it whole
+    g_flat = g.reshape(b * n, out_size * out_size, c)
 
     # De-cluster the grid order: accumulation is order-independent, so
     # walk ROIs by a fixed coprime stride (golden-ratio spacing).
     # Consecutive proposals/fg-ROIs are spatially CLUSTERED (score
     # order; objects), which is exactly when the async write-back's
     # RAW-hazard drain must serialize — a stride walk makes adjacent
-    # grid steps land on unrelated tiles so the overlap pipeline
-    # actually overlaps.  Applied regardless of the overlap flag so
-    # serial/overlap A/B (and the bitwise equality test) see the same
-    # accumulation order.
+    # grid steps land on unrelated strips so the pipeline actually
+    # overlaps.
     bn = b * n
     if bn > 2:
         from math import gcd
@@ -688,29 +720,15 @@ def _pallas_backward(feats, rois, g, strides, out_size, sampling,
     # through the aliased accumulators — each call RMWs the previous
     # call's partial feature gradients, so memory stays bounded and no
     # extra adds are emitted.
-    esize = jnp.dtype(jnp.float32).itemsize
-    scratch_bytes = (2 if overlap else 1) * TILE * TILE * c * esize
-    # Overlap doubles the tile scratch (2×4 MiB at TILE=64/C=256).
-    # Keep the chunk count unchanged by granting the bwd call a larger
-    # stack budget — and, now that the per-kernel compiler params
-    # demonstrably reach the compiler (see _compiler_params), declare
-    # the extra scratch in THIS call's vmem limit instead of trying to
-    # squeeze the accumulator pin budget: on r5b hardware the 1344/b4
-    # bf16 overlap compile needed 35.94 MiB (= the measured serial-path
-    # stack + one extra staging slot) against the base 32 MiB, and
-    # shrinking the pin budget did NOT keep the pinned accumulator off
-    # the stack.  base + 2×extra gives the observed need ~4 MiB of
-    # headroom while staying far under v5e's 128 MiB of vmem.
-    extra = TILE * TILE * c * esize if overlap else 0
-    chunk = _roi_chunk(b * n, out_size, c, g_flat.dtype, scratch_bytes,
-                       extra_budget=extra)
+    chunk = _roi_chunk(b * n, out_size, c, g_flat.dtype,
+                       _bwd_scratch_bytes(out_size, c))
 
     def call(chunk_scalars, g_chunk, accs, n_rois):
         grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=8,
+            num_scalar_prefetch=10,
             grid=(n_rois,),
-            in_specs=[pl.BlockSpec((1, out_size, out_size, c),
-                                   lambda r, *_: (r, 0, 0, 0),
+            in_specs=[pl.BlockSpec((1, out_size * out_size, c),
+                                   lambda r, *_: (r, 0, 0),
                                    memory_space=pltpu.VMEM)]
             + [pl.BlockSpec(memory_space=pltpu.HBM)] * num_levels,
             # the f32 feature-grad accumulators are the BIG buffers
@@ -721,15 +739,11 @@ def _pallas_backward(feats, rois, g, strides, out_size, sampling,
             # XLA's buffer placement — the with_memory_space_constraint
             # on the aliased inputs below is what pins them to HBM.
             out_specs=[pl.BlockSpec(memory_space=pltpu.HBM)] * num_levels,
-            scratch_shapes=(
-                [pltpu.VMEM((2, TILE, TILE, c), jnp.float32),
-                 pltpu.SemaphoreType.DMA(()),
-                 pltpu.SemaphoreType.DMA((2,)),
-                 pltpu.SMEM((2,), jnp.int32)]
-                if overlap else
-                [pltpu.VMEM((TILE, TILE, c), jnp.float32),
-                 pltpu.SemaphoreType.DMA(()),
-                 ]),
+            scratch_shapes=[
+                pltpu.VMEM((2, STRIP_H, STRIP_W, c), jnp.float32),
+                pltpu.SemaphoreType.DMA(()),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SMEM((3,), jnp.int32)],
         )
         out_shape = tuple(
             _hbm_out(f.shape, jnp.float32) if pinned[i]
@@ -739,10 +753,10 @@ def _pallas_backward(feats, rois, g, strides, out_size, sampling,
             kern,
             grid_spec=grid_spec,
             out_shape=out_shape,
-            # accumulator i (flat arg index 8 scalars + 1 g + i) owns
+            # accumulator i (flat arg index 10 scalars + 1 g + i) owns
             # output buffer i: the kernel RMWs it through the out refs
-            input_output_aliases={9 + i: i for i in range(num_levels)},
-            compiler_params=_compiler_params(extra_bytes=2 * extra),
+            input_output_aliases={11 + i: i for i in range(num_levels)},
+            compiler_params=_compiler_params(),
             interpret=interpret,
             name="roi_align_bwd",
         )(*chunk_scalars, g_chunk, *accs)
@@ -756,11 +770,8 @@ def _pallas_backward(feats, rois, g, strides, out_size, sampling,
     # (XLA vmem-placed the zeros broadcasts and the aliased chain
     # dragged 29 MiB onto the Mosaic stack).  The budgets below keep
     # the unpinned sum small enough that unpinned + g-chunk + tile
-    # scratch fits the limit the RMW kernel itself declares — base
-    # 32 MiB plus, on the overlap path, 2x the extra staging slot
-    # (r5b hardware: 35.94 MiB observed need at 1344/b4 bf16, ~4 MiB
-    # headroom under the 40 MiB grant) — even if XLA packs every
-    # unpinned buffer.
+    # scratch (two strips, ~1 MiB) fits the 32 MiB limit the RMW
+    # kernel declares, even if XLA packs every unpinned buffer.
     sizes = [int(np.prod(f.shape)) * 4 for f in padded]
     pinned = [False] * num_levels
     if not interpret and os.environ.get("EKSML_BWD_PIN", "1") != "0":
@@ -786,11 +797,6 @@ def _pallas_backward(feats, rois, g, strides, out_size, sampling,
             # 512px/b4 on v5e); a level that cannot fit the scoped
             # limit at all is left unpinned for free
             kept = 0
-            # the overlap path's extra scratch is paid for by the
-            # per-call extra_bytes grant in _compiler_params, NOT by
-            # shrinking this budget — r5b hardware showed evicting a
-            # pinned aliased accumulator doesn't reliably keep it off
-            # the stack anyway
             budget = min(18 * 2 ** 20, limit - 14 * 2 ** 20)
             for i in range(num_levels):
                 if sizes[i] >= limit:
@@ -840,8 +846,8 @@ def _fwd(feats, rois, strides, out_size, sampling_ratio, min_level,
 
 
 def _bwd(strides, out_size, sampling_ratio, min_level, interpret, res, g):
-    """Backward: the transpose Pallas kernel when enabled (two MXU
-    matmuls + sequential RMW accumulation, no scatter), else the XLA
+    """Backward: the transpose Pallas kernel when enabled (one MXU
+    product a strip + sequential RMW accumulation, no scatter), else the XLA
     formulation's VJP — both with the SAME tile-fit level assignment as
     the forward kernel, so fwd/bwd never diverge."""
     from eksml_tpu.ops.roi_align import (assign_fpn_levels_tile_fit,

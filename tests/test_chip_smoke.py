@@ -66,6 +66,8 @@ def test_last_line_schema():
 @pytest.mark.parametrize("argv,phases", [
     ([], ("device", "kernel", "train", "resume")),
     (["--four-chips"], ("device", "four_chips")),
+    (["--phases", "kernel"], ("device", "kernel")),
+    (["--phases", "resume,kernel"], ("device", "kernel", "resume")),
 ])
 def test_phase_selection(argv, phases):
     assert chip_smoke.phases_for(chip_smoke.parse_args(argv)) == phases

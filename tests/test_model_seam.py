@@ -15,7 +15,8 @@ import optax
 import pytest
 
 from eksml_tpu import models
-from eksml_tpu.config import LM_TINY_OVERRIDES, finalize_configs
+from eksml_tpu.config import (LM_TINY_OVERRIDES, SMOKE_OVERRIDES,
+                              finalize_configs)
 from eksml_tpu.models.mask_rcnn import decay_mask as detector_decay_mask
 from eksml_tpu.train import make_optimizer
 
@@ -26,7 +27,8 @@ def test_the_default_is_the_detector_built_as_before(fresh_config):
     cfg = finalize_configs(is_training=True)
     assert cfg.MODEL.NAME == "maskrcnn" and cfg.TRAIN.OPTIMIZER == "sgd"
     assert models.build_model(cfg) == models.MaskRCNN.from_config(cfg)
-    assert models.counter_spans(cfg) == {}
+    assert models.counter_spans(cfg) == {
+        "roi_bwd_strips": ("roi_bwd_tile_share",)}
     assert models.pretrained_loader(cfg) is None
     fresh_config.freeze(False)
     fresh_config.BACKBONE.WEIGHTS = "/no/such/file.npz"
@@ -183,3 +185,38 @@ def test_main_trains_the_sequence_model_as_it_trains_the_detector(tmp_path):
     assert [e["args"]["step"] for e in routes] == [2, 4]
     assert routes[0]["args"]["moe_pairs_held"] == logged[0]["moe_pairs_held"]
     assert os.path.isdir(os.path.join(logdir, "checkpoints", "4"))
+
+
+def test_the_detectors_step_carries_its_counter_to_a_span(tmp_path):
+    """The detector's side of the counter seam: the step's output holds
+    ``roi_bwd_tile_share`` (a share of the 64 x 64 tile, outside the
+    summed loss), the log rows carry it, and at log steps it rides a
+    zero-length ``roi_bwd_strips`` span, as ``moe_route`` does."""
+    logdir = str(tmp_path / "run")
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=ROOT)
+    out = subprocess.run(
+        [sys.executable, "-m", "eksml_tpu.train", "--synthetic",
+         "--logdir", logdir, "--total-steps", "4", "--config",
+         *SMOKE_OVERRIDES, "TRAIN.BATCH_SIZE_PER_CHIP=1",
+         "TRAIN.LOG_PERIOD=2", "TPU.MESH_SHAPE=(1,1)",
+         "TRAIN.STEPS_PER_EPOCH=4", "TRAIN.MAX_EPOCHS=1",
+         "TRAIN.EVAL_PERIOD=0", "TELEMETRY.TRACING.ENABLED=True",
+         "TELEMETRY.PORT=0"],
+        env=env, capture_output=True, text=True, timeout=900, cwd=ROOT)
+    assert out.returncode == 0, out.stderr[-3000:]
+    with open(os.path.join(logdir, "metrics.jsonl")) as f:
+        rows = [json.loads(line) for line in f]
+    logged = [r for r in rows if "total_loss" in r]
+    assert [r["step"] for r in logged] == [2, 4]
+    for r in logged:
+        # 16 x 16 strips: a sixteenth of the tile at the least
+        assert 1 / 16 <= r["roi_bwd_tile_share"] <= 1.0
+        assert r["total_loss"] == pytest.approx(sum(
+            v for k, v in r.items()
+            if k.endswith("_loss") and k != "total_loss"), rel=1e-5)
+    with open(os.path.join(logdir, "trace-host0.json")) as f:
+        events = json.load(f)["traceEvents"]
+    strips = [e for e in events if e["name"] == "roi_bwd_strips"]
+    assert [e["args"]["step"] for e in strips] == [2, 4]
+    assert [e["args"]["roi_bwd_tile_share"] for e in strips] == [
+        r["roi_bwd_tile_share"] for r in logged]

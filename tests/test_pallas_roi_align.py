@@ -352,7 +352,7 @@ def test_backward_chunked_matches_unchunked(monkeypatch):
     g = jnp.asarray(rng.randn(1, 6, 7, 7, 32).astype(np.float32))
     whole = rk._pallas_backward(feats, rois, g, STRIDES, 7, 2, 2, True)
     esize = 4
-    scratch = rk.TILE * rk.TILE * 32 * esize
+    scratch = rk._bwd_scratch_bytes(7, 32)
     monkeypatch.setattr(rk, "_VMEM_STACK_BUDGET",
                         scratch + 2 * 7 * 8 * 32 * esize)
     # per-ROI size uses the TILED layout (W 7→8)
@@ -363,31 +363,191 @@ def test_backward_chunked_matches_unchunked(monkeypatch):
                                    atol=1e-5)
 
 
-def test_backward_overlap_matches_serial(monkeypatch):
-    """The async write-back pipeline (EKSML_BWD_OVERLAP=1, default)
-    must reproduce the serial RMW path bit-for-bit in interpret mode —
-    including on DUPLICATED ROIs, where consecutive grid steps RMW the
-    same accumulator tiles (the hazard the pipeline's drain logic
-    exists for)."""
+def _grads_vs_xla(feats, rois, strides, out_size, levels=None):
+    """(kernel grads, XLA-formulation grads at the kernel's levels) of
+    ``sum(roi_align · w)`` with a fixed random cotangent ``w``."""
+    from eksml_tpu.ops.pallas import roi_align_kernel as rk
+    from eksml_tpu.ops.roi_align import assign_fpn_levels_tile_fit
+
+    b, n = rois.shape[:2]
+    if levels is None:
+        levels = assign_fpn_levels_tile_fit(
+            rois.reshape(b * n, 4), strides, len(feats), TILE,
+            align=rk.sublane_align(feats[0].dtype)).reshape(b, n)
+    c = feats[0].shape[-1]
+    w = jnp.asarray(np.random.RandomState(99).randn(
+        b, n, out_size, out_size, c).astype(np.float32))
+    feats32 = tuple(f.astype(jnp.float32) for f in feats)
+    gp = jax.grad(lambda fs: (pallas_batched_multilevel_roi_align(
+        fs, rois, strides, out_size, 2, 2, True).astype(jnp.float32)
+        * w).sum())(feats)
+    gr = jax.grad(lambda fs: (batched_multilevel_roi_align(
+        fs, rois, strides, out_size, 2, 2, levels=levels) * w).sum())(
+            feats32)
+    return gp, gr
+
+
+def _assert_grads_close(gp, gr, dtype):
+    for a, b in zip(gp, gr):
+        assert a.dtype == dtype
+        if dtype == jnp.bfloat16:   # output rounding, 2^-8 relative
+            np.testing.assert_allclose(np.asarray(a, dtype=np.float32),
+                                       np.asarray(b), atol=0.05, rtol=0.02)
+        else:
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       atol=2e-4)
+
+
+def _strip_counts(feats, rois, strides):
     from eksml_tpu.ops.pallas import roi_align_kernel as rk
 
-    rng = np.random.RandomState(11)
-    feats = _feats(rng, b=1)
-    # ALL-same-box ROIs: every pair of grid steps hits the same tile
-    # region, so the hazard path fires under ANY grid order — the
-    # de-clustering stride permutation in _pallas_backward reorders
-    # the grid, and merely-interleaved duplicates would be split apart
-    # and never adjacent (code review r5)
-    one = np.asarray(_rois(rng, 1, 1))
-    rois = jnp.asarray(np.repeat(one, 8, axis=1))
-    g = jnp.asarray(rng.randn(1, 8, 7, 7, 32).astype(np.float32))
+    align = rk.sublane_align(feats[0].dtype)
+    prep = rk._bwd_prep(rk._pad_levels(feats, align), rois, strides, 7, 2,
+                        align)
+    return set(zip(np.asarray(prep[4]).tolist(),
+                   np.asarray(prep[5]).tolist()))
 
-    monkeypatch.setenv("EKSML_BWD_OVERLAP", "0")
-    serial = rk._pallas_backward(feats, rois, g, STRIDES, 7, 2, 2, True)
-    monkeypatch.setenv("EKSML_BWD_OVERLAP", "1")
-    overlap = rk._pallas_backward(feats, rois, g, STRIDES, 7, 2, 2, True)
-    for s, o in zip(serial, overlap):
-        np.testing.assert_array_equal(np.asarray(s), np.asarray(o))
+
+@pytest.mark.parametrize("out_size", [7, 14])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_bwd_every_strip_count_matches_xla_vjp(dtype, out_size):
+    """A two-level pyramid whose coarsest level takes every large ROI:
+    extents of 10, 20, 36 and 50 feature pixels down and across give
+    every strip count from 1 × 1 to the tile-filling 4 × 4 (bf16: the
+    tile fit stops an ROI at 46 pixels, so 3 strips down)."""
+    from eksml_tpu.ops.pallas import roi_align_kernel as rk
+
+    dtype = jnp.dtype(dtype)
+    strides, img = (4, 8), 512
+    rng = np.random.RandomState(12)
+    feats = tuple(jnp.asarray(rng.randn(1, img // s, img // s, 8), dtype)
+                  for s in strides)
+    top = 50 if dtype == jnp.float32 else 44
+    sizes = [10, 20, 36, top]
+    boxes = [[20.3, 30.6, 20.3 + 40, 30.6 + 40]]      # P2, one strip
+    for hf in sizes:
+        for wf in sizes:
+            # the first column on a multiple of 8 (the strips' origin),
+            # or 7 past one where only the round-down makes 4 strips
+            off = 7.6 if wf == 44 else 0.6
+            x1 = 8 * (8 * rng.randint(0, (img // 8 - wf) // 8) + off)
+            y1 = 8 * (rng.randint(0, img // 8 - hf - 1) + 0.6)
+            boxes.append([x1, y1, x1 + 8 * wf, y1 + 8 * hf])
+    rois = jnp.asarray([boxes], jnp.float32)
+    counts = _strip_counts(feats, rois, strides)
+    downs = (1, 2, 3, 4) if dtype == jnp.float32 else (1, 2, 3)
+    assert counts >= {(ny, nx) for ny in downs for nx in (1, 2, 3, 4)}
+    assert max(counts) <= (TILE // rk.STRIP_H, TILE // rk.STRIP_W)
+    _assert_grads_close(*_grads_vs_xla(feats, rois, strides, out_size),
+                        dtype)
+
+
+@pytest.mark.parametrize("out_size", [7, 14])
+def test_bwd_every_level_and_border_matches_xla_vjp(out_size):
+    """ROIs on each of the four levels (P4 and P5 of a 512 px canvas
+    are 32 and 16 wide: smaller than the tile, zero-extended), hugging
+    each border and reaching past it (the strips are pulled inside the
+    padded map; rows outside it get nothing, as zero padding wants)."""
+    rng = np.random.RandomState(13)
+    feats = _feats(rng, img=512, c=8)
+    rois = jnp.asarray([[
+        [100.2, 200.7, 140.9, 236.1],     # P2
+        [60.5, 300.1, 220.3, 420.8],      # P3
+        [101.0, 90.0, 400.0, 390.0],      # P4
+        [10.0, 12.0, 500.0, 505.0],       # P5
+        [0.0, 0.0, 30.0, 22.0],           # top-left corner
+        [470.0, 0.0, 511.0, 41.0],        # top-right
+        [0.0, 480.0, 40.0, 511.9],        # bottom-left
+        [300.0, 330.0, 511.5, 511.5],     # bottom-right, P3
+        [-14.0, -9.0, 31.0, 28.0],        # past the top-left
+        [490.0, 495.0, 540.0, 530.0],     # past the bottom-right
+        [0.0, 0.0, 0.0, 0.0],             # a padded (empty) proposal
+    ]], jnp.float32)
+    gp, gr = _grads_vs_xla(feats, rois, STRIDES, out_size)
+    assert all(float(jnp.abs(g).max()) > 0 for g in gr)  # every level
+    _assert_grads_close(gp, gr, jnp.float32)
+
+
+@pytest.mark.parametrize("kind", ["identical", "half_overlapping"])
+def test_bwd_overlapping_rois_accumulate(kind):
+    """The hazard path: consecutive grid steps whose strips meet must
+    add, not overwrite.  All-identical ROIs meet under ANY grid order
+    (the de-clustering walk reorders the grid); a chain of
+    half-overlapping ones meets its neighbours on both sides."""
+    rng = np.random.RandomState(14)
+    feats = _feats(rng, c=8)
+    if kind == "identical":
+        rois = jnp.tile(_rois(rng, 1, 1), (1, 8, 1))
+    else:
+        x1 = 10.0 + 12.0 * np.arange(8)
+        rois = jnp.asarray([np.stack(
+            [x1, 0.5 * x1 + 20, x1 + 24, 0.5 * x1 + 44], 1)], jnp.float32)
+    _assert_grads_close(*_grads_vs_xla(feats, rois, STRIDES, 7),
+                        jnp.float32)
+
+
+def _share_in_numpy(feats, rois, strides):
+    """bwd_tile_share recomputed from the ROIs: rows floor(y1 − 0.5) ..
+    floor(y2 − 0.5) + 1 on the ROI's level, columns likewise from an
+    origin rounded down to 8, in 16 × 16 strips, over the tile."""
+    from eksml_tpu.ops.pallas import roi_align_kernel as rk
+    from eksml_tpu.ops.roi_align import assign_fpn_levels_tile_fit
+
+    flat = np.asarray(rois, np.float64).reshape(-1, 4)
+    levels = np.asarray(assign_fpn_levels_tile_fit(
+        jnp.asarray(flat, jnp.float32), strides, len(feats), TILE,
+        align=rk.sublane_align(feats[0].dtype)))
+    total = 0
+    for (x1, y1, x2, y2), lv in zip(flat, levels):
+        per_axis = []
+        for lo, hi, size, align in (
+                (y1, y2, feats[lv].shape[1], 1),
+                (x1, x2, feats[lv].shape[2], 8)):
+            size = max(size, TILE)
+            first = int(np.clip(np.floor(lo / strides[lv] - 0.5), 0,
+                                size - 1))
+            last = int(np.clip(np.floor(hi / strides[lv] - 0.5) + 1,
+                               first, size - 1))
+            first = first // align * align
+            per_axis.append(min((last - first) // 16 + 1, TILE // 16))
+        total += per_axis[0] * per_axis[1]
+    return total / len(flat) * 16 * 16 / (TILE * TILE)
+
+
+@pytest.mark.parametrize("case", ["random", "tile_filling", "p2_32px"])
+def test_bwd_tile_share(case):
+    from eksml_tpu.ops.pallas import roi_align_kernel as rk
+
+    assert (rk.STRIP_H, rk.STRIP_W) == (16, 16)   # _share_in_numpy's
+    rng = np.random.RandomState(15)
+    if case == "tile_filling":
+        # two levels: a 400 px box is 50 px on the coarsest, 4 × 4 strips
+        strides = (4, 8)
+        feats = tuple(jnp.zeros((2, 512 // s, 512 // s, 8), jnp.float32)
+                      for s in strides)
+        xy = rng.uniform(2, 100, (2, 5, 2))
+        rois = jnp.asarray(np.concatenate([xy, xy + 402.0], -1),
+                           jnp.float32)
+    else:
+        strides = STRIDES
+        feats = tuple(jnp.zeros((2, 1344 // s, 1344 // s, 8), jnp.bfloat16)
+                      for s in strides)
+        if case == "random":
+            side = np.exp(rng.uniform(np.log(16), np.log(1200), (2, 64, 2)))
+        else:
+            side = np.full((2, 64, 2), 32.0)
+        xy = rng.uniform(0, 1, (2, 64, 2)) * (1343 - side)
+        rois = jnp.asarray(np.concatenate([xy, xy + side], -1),
+                           jnp.float32)
+    share = float(rk.bwd_tile_share(feats, rois, strides))
+    assert share == pytest.approx(_share_in_numpy(feats, rois, strides))
+    if case == "tile_filling":
+        assert share == 1.0
+    elif case == "p2_32px":
+        # 8 px + taps: one strip down, one or two across (8-aligned)
+        assert 1 / 16 <= share < 0.2
+    else:
+        assert 0.1 < share < 0.9
 
 
 def _pallas_eqn_compiler_params(fn, *args):
@@ -413,15 +573,12 @@ def _pallas_eqn_compiler_params(fn, *args):
     return found
 
 
-def _assert_vmem_limit(params_list, kib, extra_bytes=0):
-    """Every emitted kernel must declare at least the base limit; the
-    bwd RMW kernel may additionally carry its overlap-scratch grant
-    (base .. base + extra_bytes)."""
+def _assert_vmem_limit(params_list, kib):
+    """Every emitted kernel declares exactly the limit."""
     assert params_list, "no pallas_call equation found"
     for cp in params_list:
         mosaic = cp["mosaic_tpu"] if "mosaic_tpu" in cp else cp
-        assert kib * 1024 <= mosaic.vmem_limit_bytes \
-            <= kib * 1024 + extra_bytes, mosaic
+        assert mosaic.vmem_limit_bytes == kib * 1024, mosaic
 
 
 def test_vmem_limit_rides_in_the_kernel(monkeypatch):
@@ -442,27 +599,9 @@ def test_vmem_limit_rides_in_the_kernel(monkeypatch):
         feats, rois)
     _assert_vmem_limit(fwd, rk._SCOPED_VMEM_KIB)
 
-    # bwd path includes the _to_hbm laundering kernels for the pinned
-    # accumulators plus the chained RMW kernel, which under the
-    # overlap pipeline declares its doubled staging scratch in its OWN
-    # limit (r5b hardware: 35.94 MiB needed vs the base 32 — the
-    # extra must ride per-call, base + 2x the extra staging slot)
-    # derive from the fixture exactly as the kernel does
-    # (extra = TILE*TILE*c*esize, granted 2x)
-    overlap_grant = (2 * rk.TILE * rk.TILE * feats[0].shape[-1]
-                     * np.dtype(np.float32).itemsize)
-    monkeypatch.setenv("EKSML_BWD_OVERLAP", "1")
-    bwd = _pallas_eqn_compiler_params(
-        lambda f, r, gg: rk._pallas_backward(
-            f, r, gg, STRIDES, 7, 2, 2, True),
-        feats, rois, g)
-    _assert_vmem_limit(bwd, rk._SCOPED_VMEM_KIB, overlap_grant)
-    assert any(
-        (cp["mosaic_tpu"] if "mosaic_tpu" in cp else cp).vmem_limit_bytes
-        == rk._SCOPED_VMEM_KIB * 1024 + overlap_grant for cp in bwd)
-
-    # serial path: no grant, exact base everywhere
-    monkeypatch.setenv("EKSML_BWD_OVERLAP", "0")
+    # bwd path: the _to_hbm laundering kernels for the pinned
+    # accumulators plus the chained RMW kernel, whose two staging
+    # strips fit the base limit — no per-call grant
     bwd = _pallas_eqn_compiler_params(
         lambda f, r, gg: rk._pallas_backward(
             f, r, gg, STRIDES, 7, 2, 2, True),
