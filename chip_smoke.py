@@ -431,7 +431,6 @@ def phase_train(args, device: dict, logdir: str,
           "precision": cfg.TRAIN.PRECISION,
           "batch_per_chip": cfg.TRAIN.BATCH_SIZE_PER_CHIP,
           "remat": bool(cfg.TRAIN.REMAT),
-          "roi_backend": os.environ.get("EKSML_ROI_BACKEND", "auto"),
           "roi_kernel_selected": pallas_roi_align_supported(),
           "steps": n, "losses": losses,
           "fit_step_time_ms": [r.get("step_time_ms") for r in rows],

@@ -17,7 +17,7 @@ whole-program :class:`~eksml_tpu.analysis.graph.ProjectGraph`:
   ``.submit``/``.map`` callees, ``BaseHTTPRequestHandler`` subclass
   ``do_*`` methods, ``signal.signal`` handlers, ``atexit`` hooks,
   plus the main-thread entry points (``Trainer.fit``,
-  ``train.main``, ``bench.main``).  All main-thread entries share ONE
+  ``train.main``).  All main-thread entries share ONE
   root identity (``main`` calling ``fit`` is one thread, not two).
 - **lock inventory** — ``self.<attr>`` and module-global names
   assigned from ``threading.Lock/RLock/Condition/Semaphore``,
@@ -85,7 +85,6 @@ _LOCK_FACTORIES = ("threading.Lock", "threading.RLock",
 #: copies linted from another root still engage the rules.
 _MAIN_ROOTS: Sequence[Tuple[str, Tuple[str, ...]]] = (
     ("eksml_tpu/train.py", ("Trainer.fit", "main")),
-    ("bench.py", ("main",)),
 )
 
 #: Barrier spellings shared with the collective-order checker — a

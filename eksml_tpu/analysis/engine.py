@@ -28,7 +28,7 @@ from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 #: Production code the default lint pass covers.  tests/ is excluded
 #: on purpose: fixtures simulate violations, and test code may freely
 #: read clocks or write files non-atomically.
-DEFAULT_TARGETS = ("eksml_tpu", "tools", "bench.py", "__graft_entry__.py")
+DEFAULT_TARGETS = ("eksml_tpu", "tools", "__graft_entry__.py")
 
 _SUPPRESS_RE = re.compile(r"#\s*eksml-lint:\s*disable=([\w\-,]+)")
 

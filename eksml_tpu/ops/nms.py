@@ -18,7 +18,6 @@ is a re-design, not a port:
 
 from __future__ import annotations
 
-import os
 from functools import partial
 
 import jax
@@ -27,11 +26,16 @@ import jax.numpy as jnp
 from eksml_tpu.ops.boxes import pairwise_iou
 
 
+# 256 balances outer-step count against the [tile, tile] fixed-point
+# block staying VMEM-cheap
+NMS_TILE = 256
+
+
 # "nms" scope → the rpn-nms attribution component (eksml_tpu/profiling
 # SCOPE_RULES); keeps NMS fusions nameable in profiles
 @jax.named_scope("nms")
 def nms_mask(boxes: jnp.ndarray, scores: jnp.ndarray,
-             iou_threshold: float, tile: int | None = None) -> jnp.ndarray:
+             iou_threshold: float, tile: int = NMS_TILE) -> jnp.ndarray:
     """Greedy NMS keep-mask for boxes ``[K, 4]`` (any order).
 
     Returns a bool ``[K]`` mask in the *input* order.  Padding entries
@@ -56,18 +60,9 @@ def nms_mask(boxes: jnp.ndarray, scores: jnp.ndarray,
     K/tile outer steps plus per-tile chain depth on a [tile,tile]
     block that lives in VMEM.  The result is exact greedy NMS
     (tests/test_nms.py cross-checks the sequential recurrence).
-
-    ``tile`` defaults from ``EKSML_NMS_TILE`` (read at trace time,
-    like the EKSML_ROI_* knobs) so a hardware sweep can tune it
-    without code edits; 256 balances outer-step count against the
-    [tile, tile] fixed-point block staying VMEM-cheap.
     """
-    if tile is None:
-        tile = int(os.environ.get("EKSML_NMS_TILE", "256"))
     if tile <= 0:
-        raise ValueError(
-            f"NMS tile size must be positive, got {tile} "
-            "(check EKSML_NMS_TILE)")
+        raise ValueError(f"NMS tile size must be positive, got {tile}")
     k = boxes.shape[0]
     order = jnp.argsort(-scores)
     sboxes = boxes[order]

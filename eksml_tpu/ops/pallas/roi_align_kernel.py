@@ -28,8 +28,8 @@ Semantics notes:
 - level assignment is the shared tile-fit variant
   (``assign_fpn_levels_tile_fit``): ROIs whose extent would overflow
   the tile at the heuristic level are bumped to a coarser level, so
-  the forward kernel and the XLA backward (which receives the SAME
-  levels) compute identical values — no silent fwd/bwd divergence for
+  the forward and backward kernels (both read ``_prep``'s levels)
+  compute the same linear map — no silent fwd/bwd divergence for
   extreme aspect ratios.
 
 The backward wrt features is the TRANSPOSE of the same separable
@@ -46,16 +46,13 @@ CxP[j,x] · g[(i j), c]`` with the *pooled* weights
 into the weights) — no scatter, no transpose.  Write-back is
 asynchronous over two staging strips (``_bwd_kernel``).
 ``bwd_tile_share`` is the share of the tile the strips cover, the
-step's ``roi_bwd_tile_share`` counter.
-``EKSML_ROI_BWD={auto,pallas,xla}`` selects it (auto = the kernel on
-a TPU backend, the XLA gather-transpose formulation via
-``jax.custom_vjp`` elsewhere).
+step's ``roi_bwd_tile_share`` counter.  Whoever runs the forward
+kernel runs this one (``_bwd``): there is no mixed path.
 """
 
 from __future__ import annotations
 
 import functools
-import os
 from typing import Sequence
 
 import jax
@@ -82,22 +79,13 @@ _BWD_ALIGN = 8
 _SCOPED_VMEM_KIB = 32768
 
 
-def _scoped_vmem_kib() -> int:
-    """The ONE read point for the EKSML_SCOPED_VMEM_KIB override, read
-    at trace time: the value is baked into the jitted program and keyed
-    into the persistent compile cache, so set it before the first
-    compile."""
-    return int(os.environ.get("EKSML_SCOPED_VMEM_KIB",
-                              str(_SCOPED_VMEM_KIB)))
-
-
 def _compiler_params():
     """Per-kernel Mosaic params carrying the scoped-vmem stack limit
     IN the compiled module.  The ONE construction site for the limit."""
     from jax.experimental.pallas import tpu as pltpu
 
     return pltpu.CompilerParams(
-        vmem_limit_bytes=_scoped_vmem_kib() * 1024)
+        vmem_limit_bytes=_SCOPED_VMEM_KIB * 1024)
 
 
 def sublane_align(dtype) -> int:
@@ -113,27 +101,13 @@ def tile_margin(dtype) -> int:
     return 3 + sublane_align(dtype) - 1
 
 
-def _use_kernel(env_var: str) -> bool:
-    """Kernel gate, decidable BEFORE anything compiles: the explicit
-    ``xla`` / ``pallas`` setting wins; ``auto`` means the kernel exactly
-    when the default backend is a TPU.  A kernel the compiler or the
-    runtime refuses raises from the caller's own compile with the
-    compiler's message — nothing substitutes the XLA formulation after
-    a failure."""
-    mode = os.environ.get(env_var, "auto").lower()
-    if mode not in ("auto", "pallas", "xla"):
-        raise ValueError(f"{env_var}={mode!r}: expected auto, pallas "
-                         "or xla")
-    if mode == "auto":
-        return jax.default_backend() == "tpu"
-    return mode == "pallas"
-
-
 def pallas_roi_align_supported() -> bool:
-    """True when the forward kernel path should be used
-    (``EKSML_ROI_BACKEND={auto,pallas,xla}`` — the A/B switch bench.py
-    exposes as ``--roi-backend``)."""
-    return _use_kernel("EKSML_ROI_BACKEND")
+    """The kernel gate, decidable BEFORE anything compiles: the kernels
+    (forward and backward together) exactly when the default backend is
+    a TPU.  A kernel the compiler or the runtime refuses raises from
+    the caller's own compile with the compiler's message — nothing
+    substitutes the XLA formulation after a failure."""
+    return jax.default_backend() == "tpu"
 
 
 def _tap_weights(start, binsz, s_idx, t_idx, sampling: int):
@@ -774,8 +748,8 @@ def _pallas_backward(feats, rois, g, strides, out_size, sampling,
     # kernel declares, even if XLA packs every unpinned buffer.
     sizes = [int(np.prod(f.shape)) * 4 for f in padded]
     pinned = [False] * num_levels
-    if not interpret and os.environ.get("EKSML_BWD_PIN", "1") != "0":
-        limit = _scoped_vmem_kib() * 1024
+    if not interpret:
+        limit = _SCOPED_VMEM_KIB * 1024
         if jnp.dtype(feats[0].dtype) == jnp.float32:
             # f32 graphs carry double-size temps everywhere and the
             # packer runs much hotter (the round-5 f32 convergence
@@ -817,13 +791,6 @@ def _pallas_backward(feats, rois, g, strides, out_size, sampling,
         for o, f in zip(outs, feats))
 
 
-def pallas_roi_bwd_supported() -> bool:
-    """Backward-kernel gate: ``EKSML_ROI_BWD={auto,pallas,xla}`` —
-    auto is the kernel on a TPU backend, xla forces the gather-
-    transpose formulation, pallas forces the kernel."""
-    return _use_kernel("EKSML_ROI_BWD")
-
-
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4, 5, 6))
 def pallas_batched_multilevel_roi_align(
         feats, rois, strides: Sequence[int], out_size: int,
@@ -831,9 +798,8 @@ def pallas_batched_multilevel_roi_align(
         interpret: bool = False):
     """Drop-in for ops.roi_align.batched_multilevel_roi_align:
     feats ``[(B, Hl, Wl, C), ...]``, rois ``[B, N, 4]`` →
-    ``[B, N, out, out, C]``.  Pallas forward; backward is the
-    transpose Pallas kernel when enabled (``EKSML_ROI_BWD``, see
-    ``_bwd``) and the XLA formulation's VJP otherwise."""
+    ``[B, N, out, out, C]``.  Pallas forward; the backward is the
+    transpose Pallas kernel (``_bwd``)."""
     return _pallas_forward(tuple(feats), rois, strides, out_size,
                            sampling_ratio, min_level, interpret)
 
@@ -846,30 +812,12 @@ def _fwd(feats, rois, strides, out_size, sampling_ratio, min_level,
 
 
 def _bwd(strides, out_size, sampling_ratio, min_level, interpret, res, g):
-    """Backward: the transpose Pallas kernel when enabled (one MXU
-    product a strip + sequential RMW accumulation, no scatter), else the XLA
-    formulation's VJP — both with the SAME tile-fit level assignment as
-    the forward kernel, so fwd/bwd never diverge."""
-    from eksml_tpu.ops.roi_align import (assign_fpn_levels_tile_fit,
-                                         batched_multilevel_roi_align)
-
+    """Backward: the transpose Pallas kernel (one MXU product a strip +
+    sequential RMW accumulation, no scatter), with the SAME tile-fit
+    level assignment as the forward kernel, so fwd/bwd never diverge."""
     feats, rois = res
-    mode = os.environ.get("EKSML_ROI_BWD", "auto").lower()
-    if mode != "xla" and (interpret or pallas_roi_bwd_supported()):
-        g_feats = _pallas_backward(feats, rois, g, strides, out_size,
-                                   sampling_ratio, min_level, interpret)
-        return g_feats, jnp.zeros_like(rois)
-    b, n = rois.shape[0], rois.shape[1]
-    levels = assign_fpn_levels_tile_fit(
-        rois.reshape(b * n, 4), strides, len(feats), TILE,
-        min_level=min_level,
-        align=sublane_align(feats[0].dtype)).reshape(b, n)
-    _, vjp = jax.vjp(
-        lambda fs: batched_multilevel_roi_align(
-            fs, rois, strides, out_size, sampling_ratio, min_level,
-            levels=levels),
-        feats)
-    (g_feats,) = vjp(g)
+    g_feats = _pallas_backward(feats, rois, g, strides, out_size,
+                               sampling_ratio, min_level, interpret)
     return g_feats, jnp.zeros_like(rois)
 
 

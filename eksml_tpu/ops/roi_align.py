@@ -39,7 +39,6 @@ from __future__ import annotations
 import contextlib
 import contextvars
 import logging
-import os
 from typing import Sequence
 
 import jax
@@ -53,8 +52,9 @@ from jax.sharding import PartitionSpec as P
 # round-3 bench.  Processing ROIs in chunks through ``lax.map`` bounds
 # the temps to a chunk's share while XLA's scan-transpose accumulates
 # the feature gradient across chunks; outputs are bit-identical (each
-# ROI's computation is independent).  0 disables chunking.
-_ROI_CHUNK = int(os.environ.get("EKSML_ROI_CHUNK", "128"))
+# ROI's computation is independent).  (Tests set 0, the unchunked form,
+# to compare with.)
+_ROI_CHUNK = 128
 
 
 def _chunk_size(n: int) -> int | None:
@@ -70,7 +70,7 @@ def _chunk_size(n: int) -> int | None:
     best = max(d for d in range(1, c + 1) if n % d == 0)
     if best <= 1:
         logging.getLogger(__name__).warning(
-            "ROIAlign chunking requested (EKSML_ROI_CHUNK=%d) but %d "
+            "ROIAlign chunks of at most %d ROIs requested but %d "
             "ROIs has no divisor in (1, %d] — running UNCHUNKED; the "
             "full gather temps may OOM HBM at large canvases. Pick an "
             "ROI count with a divisor <= the bound (powers of two are "
@@ -326,9 +326,9 @@ def dispatch_roi_align(feats, rois, strides, out_size,
     Correctness guard: an ROI wider than the kernel's coverage at the
     COARSEST level — ``(TILE - margin) × strides[-1]`` px, ~1696 (f32)
     / ~1440 (bf16) with TILE=64 — would be silently truncated by the
-    tile while the XLA backward computes the full gradient.  ROI extent
-    is bounded by the (padded) image extent, so when the feature maps
-    imply images beyond that bound, dispatch takes the XLA path."""
+    tile.  ROI extent is bounded by the (padded) image extent, so when
+    the feature maps imply images beyond that bound, dispatch takes the
+    XLA path."""
     from eksml_tpu.ops.pallas import (TILE,
                                       pallas_batched_multilevel_roi_align,
                                       pallas_roi_align_supported,

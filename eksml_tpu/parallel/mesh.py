@@ -59,7 +59,7 @@ V5E_TOPOLOGY_GRIDS = {
 # v6e (Trillium) slice inventory: same 2D-torus slice shapes and
 # 4-chip hosts as v5e (machine type ct6e-standard-4t — the terraform
 # tpu_machine_type for a v6e pool), ~4.7x the bf16 peak per chip
-# (bench.py PEAK_FLOPS).  Topology names follow the same
+# (benchmark/peaks.json).  Topology names follow the same
 # ``cloud.google.com/gke-tpu-topology`` label scheme.
 V6E_TOPOLOGIES = {name.replace("v5e-", "v6e-"): ch
                   for name, ch in V5E_TOPOLOGIES.items()}

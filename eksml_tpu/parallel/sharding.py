@@ -22,7 +22,7 @@ layout a *config knob* instead of a code path:
 
 - **``ShardingPlan``** (SNIPPETS.md [3]'s compile-with-plan layer):
   one object that owns the strategy name, the rules, the batch spec,
-  and the jit wrapper, so train/bench/dryrun ask the *plan* for
+  and the jit wrapper, so train/predict/dryrun ask the *plan* for
   in/out shardings instead of hard-coding them.  Strategies:
 
   * ``replicated`` — today's behavior, the default.  Specs are all
@@ -504,7 +504,7 @@ class ShardingPlan:
     """Strategy + rules + mesh → shardings and compiled steps.
 
     The Titanax-style compile-with-plan layer (SNIPPETS.md [3]): the
-    trainer/bench never names a PartitionSpec — it asks the plan.
+    trainer never names a PartitionSpec — it asks the plan.
     """
 
     def __init__(self, strategy: str, mesh: Mesh, rules=(),
@@ -550,7 +550,7 @@ class ShardingPlan:
         #: the STORAGE layout, never the replica count), which is
         #: what keeps per-image compute — and therefore the loss
         #: stream — bit-identical to replicated; the spec
-        #: _globalize_batch and bench both use
+        #: _globalize_batch and profiling/predict.py both use
         self.batch_spec = (P(batch_axes[0]) if len(batch_axes) == 1
                            else P(batch_axes))
 
@@ -609,7 +609,7 @@ class ShardingPlan:
         memory plan exists to shed.
 
         ONE definition of the eval_shape→shardings→out_shardings
-        idiom for trainer, bench and dryrun (three hand-rolled copies
+        idiom for trainer, predict and dryrun (three hand-rolled copies
         could drift and measure different layouts under the same plan
         name)."""
         sh = self.shardings(jax.eval_shape(fn, *args))
@@ -746,7 +746,7 @@ class ShardingPlan:
         return "\n".join(out)
 
     def describe(self) -> str:
-        """One-line summary for logs and bench diagnostics."""
+        """One-line summary for logs and diagnostics."""
         # slices only show when the mesh actually carries the axis —
         # every single-slice plan keeps its historical string
         extra = (f", slices={self.slice_axis_size}, "

@@ -1,7 +1,7 @@
 """Profile attribution: compiled-HLO cost → named model components.
 
 See attribution.py for the engine; tools/trace_summary.py and
-bench.py --profile are the consumers.
+predict.py are the consumers.
 """
 
 from eksml_tpu.profiling.attribution import (FLOPS_PER_BYTE,  # noqa: F401

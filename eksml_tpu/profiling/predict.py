@@ -29,9 +29,8 @@ the factors agree; the spread IS the model error, and it is printed in
 every gate run (tools/perf_gate.py) and pinned in
 tests/test_perf_gate.py.
 
-Consumers: ``tools/perf_gate.py`` (the CI gate), ``bench.py`` (emits
-predicted next to measured so real rounds self-calibrate), and
-``Trainer.fit`` (the ``eksml_train_predicted_step_time_ms`` gauge).
+Consumers: ``tools/perf_gate.py`` (the CI gate) and ``Trainer.fit``
+(the ``eksml_train_predicted_step_time_ms`` gauge).
 """
 
 from __future__ import annotations
@@ -52,7 +51,7 @@ log = logging.getLogger(__name__)
 PREDICTED_GAUGE = "eksml_train_predicted_step_time_ms"
 
 # Chip spec table for the roofline terms.  Peak flops are the vendor
-# bf16 systolic numbers (bench.py PEAK_FLOPS uses the same); f32 runs
+# bf16 systolic numbers (benchmark/peaks.json holds the same); f32 runs
 # the MXU at half rate.  Link bandwidths are per-chip aggregate ICI
 # and the per-host DCN NIC share — the model only needs them to the
 # ~2× level (the calibration scale factor absorbs constant error; the
@@ -81,7 +80,7 @@ CHIP_SPECS: Dict[str, Dict[str, Any]] = {
     },
 }
 
-# jax device_kind → spec name (the strings bench.py's PEAK_FLOPS keys
+# jax device_kind → spec name (the strings benchmark/peaks.json keys
 # on).  A kind that is not here — "cpu" included — is an error: no
 # caller may price a program for a chip it did not run on.
 DEVICE_KIND_TO_TARGET = {
@@ -526,8 +525,8 @@ def predict_for_compiled(hlo_text: str,
     from the mesh, and the per-slice device count from ``num_slices``
     (collectives spanning slices price against DCN — as one flat ring
     or as the three-phase hierarchical exchange, per ``exchange``).
-    The trainer's gauge and bench's self-calibration point MUST price
-    through this one path — two hand-maintained invocation blocks
+    Whoever prices a compiled program (the trainer's gauge) prices
+    through this one path — a second hand-maintained invocation block
     would silently diverge on exactly the pricing inputs calibration
     depends on."""
     target = target_for_device_kind(device_kind)
@@ -558,7 +557,7 @@ def lower_train_step(cfg, batch_size: int, image_size=None,
                      ) -> Tuple[str, Dict[str, Any]]:
     """AOT-lower + compile the real train step; → (hlo_text, meta).
 
-    The same program construction bench.py measures: model from cfg,
+    The program ``make_synthetic_train_step`` builds: model from cfg,
     synthetic batch at the padded canvas, jitted init, optimizer, and
     — under a sharded strategy — the sharding plan's just-in-time
     gather / storage-grad constraints over a
@@ -630,7 +629,7 @@ def lower_train_step(cfg, batch_size: int, image_size=None,
             plan = ShardingPlan(strategy, mesh)
         mesh_shape = dict(mesh.shape)
 
-    # per-chip batch semantics under a plan (the trainer/bench
+    # per-chip batch semantics under a plan (the trainer's
     # contract): batch rows ride EVERY mesh axis (sharding.py
     # batch_spec — the strategies change the storage layout, never
     # the replica count); the replicated path is the historical
@@ -661,8 +660,7 @@ def lower_train_step(cfg, batch_size: int, image_size=None,
     else:
         opt_state = tx.init(params)
 
-    # ONE step construction with bench.py — the program priced here
-    # must be the program the hardware measures
+    # the model's real forward, backward and update (train.py)
     step = make_synthetic_train_step(
         model, tx, plan,
         param_sh if plan is not None else None,
@@ -866,9 +864,8 @@ def calibration_points(artifacts_dir: str,
     - the pinned r5 sources above, matched to
       ``perf_pred_<rung>_<strategy>_<precision>.json``;
     - any ``bench_rung_*.json`` that already CARRIES a
-      ``predicted_step_time_ms`` (bench.py emits predicted next to
-      measured since this gate landed) — fresh hardware rounds
-      self-calibrate with no pinned table.
+      ``predicted_step_time_ms`` (a record that holds predicted next
+      to measured) — such a round calibrates with no pinned table.
     """
     points: List[Dict] = []
     for fname, run_name, rung in R5_CALIBRATION_SOURCES:
@@ -927,7 +924,7 @@ def calibration_points(artifacts_dir: str,
                 "measured_source": os.path.basename(path),
                 "predicted_ms": float(predicted),
                 "predicted_source": "embedded",
-                # bench.py priced the measured-width compiled HLO
+                # the record priced the measured-width compiled HLO
                 "fit_group": "measured",
             })
     return points

@@ -19,7 +19,7 @@ the shared filesystem, same contract as the quarantine ledger), so:
 Publishing is decoupled from plumbing: subsystems call the module-level
 :func:`event`, which forwards to the installed per-process recorder
 (``Trainer`` installs one per host) and no-ops when none is installed —
-library consumers (bench, eval_ckpt, unit tests) pay nothing.
+library consumers (eval_ckpt, unit tests) pay nothing.
 
 Event kinds in use (grep anchors, not an enum — new subsystems add
 their own): ``sigterm``, ``preempt_exit``, ``nan_observed``,

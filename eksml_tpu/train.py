@@ -156,8 +156,8 @@ def _goodput_knobs(cfg) -> Dict[str, Any]:
 def cast_params_for_storage(params, param_dtype: str):
     """TRAIN.PARAM_DTYPE storage cast (the 1344/b8 memory plan): f32
     leaves → bf16; everything else keeps its dtype.  ONE definition
-    shared by Trainer.init_state and bench.py, so the bench A/B always
-    measures the same memory plan production training uses.  Cast
+    shared by Trainer.init_state and profiling/predict.py, so the
+    priced program has the memory plan production training uses.  Cast
     BEFORE tx.init so the momentum tree follows."""
     if param_dtype != "bfloat16":
         return params
@@ -172,10 +172,10 @@ def make_synthetic_train_step(model, tx, plan=None, param_sh=None,
     the plan's just-in-time gather / storage-grad constraints when one
     is active, optimizer update under the ``optimizer`` named scope.
 
-    ONE construction shared by bench.py (which measures it) and
-    profiling/predict.py (which AOT-prices it), so the predicted
-    program can never silently diverge from the measured one — the
-    calibration fit's honesty depends on them being the same program.
+    The construction profiling/predict.py AOT-prices
+    (``lower_train_step``): no loader, sentinel or telemetry around
+    it, so the hermetic perf gate prices the model's real forward,
+    backward and update on a synthetic batch.
     ``param_sh``/``opt_sh`` are the plan's state shardings
     (``init_sharded``); ignored without a plan."""
 
@@ -198,7 +198,7 @@ def make_synthetic_train_step(model, tx, plan=None, param_sh=None,
     # donate only on accelerators — the compiled_step rule: on
     # XLA:CPU device buffers can alias external host memory (zero-copy
     # device_put, jit outputs) and donating them is undefined behavior
-    # (the born-sharded 2d opt state turned bench's CPU smoke into a
+    # (the born-sharded 2d opt state turned a CPU smoke run into a
     # loss=nan + `buffer.IsAvailable()` abort).  Donation changes
     # buffer aliasing, not the instruction stream, so the CPU-lowered
     # priced program still matches the TPU-measured one.
@@ -1310,8 +1310,7 @@ class Trainer:
 
     def _price_compiled(self, compiled) -> None:
         """Price the compiled step's HLO against its chip's roofline
-        (ONE pricing path with bench.py's self-calibration point — see
-        ``predict_for_compiled``), publish the gauge and keep the
+        (``predict_for_compiled``), publish the gauge and keep the
         prediction for the predicted-vs-measured lines."""
         from eksml_tpu.profiling import predict as predict_mod
 

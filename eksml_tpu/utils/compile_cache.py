@@ -4,7 +4,7 @@ The reference pays zero compile cost (TF 1.x kernels are precompiled);
 on TPU the first jit of the full Mask-RCNN train step is minutes of
 XLA work, repeated on every process start.  jax's persistent cache
 makes that a one-time cost per (program, topology, cache directory):
-the trainer, the bench and ``chip_smoke.py`` all reuse the same
+the trainer, the benchmark and ``chip_smoke.py`` all reuse the same
 serialized executables.
 
 ONE location rule: ``JAX_COMPILATION_CACHE_DIR`` when the environment

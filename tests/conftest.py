@@ -39,6 +39,32 @@ def _seed():
     np.random.seed(0)
 
 
+@pytest.fixture(scope="session")
+def tracked_files():
+    """Repo-relative paths of the files git would commit (tracked, or
+    new and not ignored).  A checkout without ``.git`` holds exactly
+    those plus what building and testing leave behind, so there every
+    file outside dot-directories and caches counts."""
+    import subprocess
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if os.path.isdir(os.path.join(root, ".git")):
+        out = subprocess.run(
+            ["git", "ls-files", "--cached", "--others",
+             "--exclude-standard"], cwd=root, check=True,
+            capture_output=True, text=True).stdout
+        return frozenset(p for p in out.splitlines()
+                         if os.path.exists(os.path.join(root, p)))
+    found = set()
+    for d, dirs, files in os.walk(root):
+        dirs[:] = [x for x in dirs if not x.startswith(".")
+                   and x not in ("__pycache__", "chiprun_out",
+                                 "_archive")]
+        found.update(os.path.relpath(os.path.join(d, f), root)
+                     for f in files if not f.endswith((".pyc", ".so")))
+    return frozenset(found)
+
+
 @pytest.fixture()
 def fresh_config():
     """The global config at its import-time defaults; tests mutate

@@ -28,7 +28,7 @@ def _bank_file(path, line, noise_before=True):
         tail += "INFO compile done\n{\"not\": \"a metric line\"}\n"
     tail += json.dumps(line) + "\n"
     with open(path, "w") as f:
-        json.dump({"n": 1, "cmd": "python bench.py", "rc": 0,
+        json.dump({"n": 1, "cmd": "python measure.py", "rc": 0,
                    "tail": tail}, f)
 
 

@@ -2,7 +2,7 @@
 
 The reference pays no compile cost (precompiled TF kernels); on TPU the
 train-step compile is minutes of XLA work, so the cache is part of the
-operational surface (bench.py, train.py, __graft_entry__.py enable it).
+operational surface (train.py, __graft_entry__.py, benchmark/ enable it).
 """
 
 import os

@@ -188,3 +188,24 @@ def test_derived_images_layer_on_their_bases():
               "container-optimized-viz"):
         df = open(os.path.join(REPO, d, "Dockerfile")).read()
         assert "ARG BASE_IMAGE" in df, f"{d} missing BASE_IMAGE arg"
+
+
+def test_container_copies_only_tracked_files(tracked_files):
+    """The build context is the checkout: a ``COPY`` of a file the tree
+    no longer holds fails the image build, which no other test runs."""
+    copied = []
+    for df in DOCKERFILES:
+        for line in open(df).read().replace("\\\n", " ").splitlines():
+            if not line.startswith(("COPY ", "ADD ")):
+                continue
+            words = [w for w in line.split()[1:]
+                     if not w.startswith("--")]
+            copied += [(os.path.relpath(df, REPO), src)
+                       for src in words[:-1]]
+    assert len(copied) >= 6, copied
+    missing = [
+        (df, src) for df, src in copied
+        if src not in tracked_files
+        and not any(p.startswith(src.rstrip("/") + "/")
+                    for p in tracked_files)]
+    assert not missing, missing

@@ -134,7 +134,7 @@ def test_jit_purity_negative_host_code_and_env_reads(tmp_path):
             os.environ["A"] = "1"    # host side: fine
 
         def train_step(params):
-            backend = os.environ.get("EKSML_ROI_BACKEND")  # read: ok
+            precision = os.environ.get("EKSML_DEFAULT_PRECISION")  # ok
             key = jax.random.PRNGKey(0)                    # jax rng: ok
             return params
 
@@ -615,21 +615,23 @@ def test_self_check_real_repo_zero_findings():
 
 def test_injected_violation_fails_naming_rule_file_line(tmp_path):
     """Reverse direction: a synthetic post-override args.precision
-    read in (a copy of) bench.py exits 1 and names rule, file, line."""
-    target = tmp_path / "bench_injected.py"
-    src = open(os.path.join(REPO, "bench.py")).read()
-    needle = 'f"image={shape}, {cfg.TRAIN.PRECISION}, "'
-    assert needle in src, "bench.py banner changed; update this test"
-    target.write_text(src.replace(
-        needle, 'f"image={shape}, {args.precision}, "'))
+    read in a small entry-point module exits 1 and names rule, file,
+    line."""
+    target = tmp_path / "entry_injected.py"
+    target.write_text(textwrap.dedent("""
+        def run(args, cfg):
+            cfg.TRAIN.PRECISION = args.precision
+            cfg.update_args(args.config)
+            print(f"image={cfg.PREPROC.MAX_SIZE}, {args.precision}")
+        """))
     proc = _run_cli("--rules", "config-drift", str(target))
     assert proc.returncode == 1
     line = [ln for ln in proc.stdout.splitlines()
             if "config-drift" in ln][0]
     assert "args.precision" in line
-    assert "bench_injected.py" in line
+    assert "entry_injected.py" in line
     import re
-    assert re.search(r"bench_injected\.py:\d+: config-drift", line)
+    assert re.search(r"entry_injected\.py:5: config-drift", line)
 
 
 def test_cli_update_baseline_then_clean(tmp_path):

@@ -113,7 +113,7 @@ def test_thread_roots_every_spawn_idiom(tmp_path):
                 signal.signal(signal.SIGTERM, on_sig)
                 atexit.register(cleanup)
             """,
-        "bench.py": """
+        "eksml_tpu/train.py": """
             def main():
                 pass
             """,
@@ -301,7 +301,7 @@ def test_lock_order_propagates_held_locks_through_calls(tmp_path):
 def test_lock_order_single_main_root_is_not_a_deadlock(tmp_path):
     """Both orders on ONE main thread cannot interleave with
     themselves; only spawned/concurrent roots make a cycle fire."""
-    r = lint_tree(tmp_path, {"bench.py": """
+    r = lint_tree(tmp_path, {"eksml_tpu/train.py": """
         import threading
 
         A = threading.Lock()
@@ -887,8 +887,7 @@ def test_named_spawn_sites_cover_runtime_threads():
                 "eksml_tpu/telemetry/exporter.py",
                 "eksml_tpu/resilience/watchdog.py",
                 "eksml_tpu/evalcoco/runner.py",
-                "eksml_tpu/ops/pallas/roi_align_kernel.py",
-                "bench.py"):
+                "eksml_tpu/ops/pallas/roi_align_kernel.py"):
         src = open(os.path.join(REPO, rel)).read()
         for m in re.finditer(
                 r"threading\.Thread\((?:[^()]|\([^()]*\))*\)", src):

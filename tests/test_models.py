@@ -145,8 +145,8 @@ def test_predict_shapes_and_validity():
 
 @pytest.mark.slow
 def test_remat_bf16_train_grads_compile():
-    """TRAIN.REMAT is the bench's HBM-OOM escape hatch (bench.py reruns
-    an OOM'd operating point with remat on), so the nn.remat-wrapped
+    """TRAIN.REMAT is the HBM-OOM escape hatch (an operating point
+    that does not fit reruns with remat on), so the nn.remat-wrapped
     backbone/FPN must actually compile and differentiate — including
     under the bf16 policy threaded through their dtype attrs."""
     m = tiny_model(remat=True, compute_dtype=jnp.bfloat16)

@@ -209,19 +209,23 @@ def test_stacked_level_nms_equals_per_level_loop():
         assert not keep[lvl, k:].any()   # padding never kept
 
 
-def test_nms_tile_env_knob(monkeypatch):
-    """EKSML_NMS_TILE is read at trace time and validated."""
+def test_nms_tile_argument():
+    """``tile`` is validated, and a small one (several tiles, padding
+    in the last) gives the sequential recurrence's mask."""
     import pytest
 
-    boxes = jnp.asarray([[0, 0, 10, 10], [100, 100, 110, 110]],
-                        jnp.float32)
-    scores = jnp.asarray([0.9, 0.8])
-    monkeypatch.setenv("EKSML_NMS_TILE", "8")
-    keep = np.asarray(nms_mask(boxes, scores, 0.5))
-    assert keep.all()
-    monkeypatch.setenv("EKSML_NMS_TILE", "0")
-    with pytest.raises(ValueError, match="EKSML_NMS_TILE"):
-        nms_mask(boxes, scores, 0.5)
+    from eksml_tpu.ops.nms import nms_mask_sequential
+
+    rng = np.random.RandomState(11)
+    ctr = rng.rand(27, 2) * 40
+    boxes = jnp.asarray(np.concatenate(
+        [ctr, ctr + rng.rand(27, 2) * 30 + 5], 1).astype(np.float32))
+    scores = jnp.asarray(rng.rand(27).astype(np.float32))
+    np.testing.assert_array_equal(
+        np.asarray(nms_mask(boxes, scores, 0.5, tile=8)),
+        np.asarray(nms_mask_sequential(boxes, scores, 0.5)))
+    with pytest.raises(ValueError, match="tile size must be positive"):
+        nms_mask(boxes, scores, 0.5, tile=0)
 
 
 def test_microbench_vendored_old_nms_agrees():

@@ -171,48 +171,34 @@ def test_bwd_bf16_dtype_and_tolerance():
                                    np.asarray(b), atol=0.05, rtol=0.05)
 
 
-def test_bwd_env_override_forces_xla(monkeypatch):
-    """EKSML_ROI_BWD=xla must route interpret-mode grads through the
-    XLA formulation (and agree — both are the same linear map)."""
-    rng = np.random.RandomState(8)
-    feats = _feats(rng, c=8)
-    rois = _rois(rng, 1, 3)
-
-    monkeypatch.setenv("EKSML_ROI_BWD", "xla")
-    g_xla = jax.grad(lambda fs: pallas_batched_multilevel_roi_align(
-        fs, rois, STRIDES, 7, 2, 2, True).sum())(feats)
-    monkeypatch.setenv("EKSML_ROI_BWD", "auto")
-    g_pal = jax.grad(lambda fs: pallas_batched_multilevel_roi_align(
-        fs, rois, STRIDES, 7, 2, 2, True).sum())(feats)
-    for a, b in zip(g_xla, g_pal):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   atol=1e-4)
-
-
-def _dispatch_jaxpr(feats, rois):
+def _dispatch_jaxpr(feats, rois, grad=False):
     from eksml_tpu.ops.roi_align import dispatch_roi_align
 
-    return str(jax.make_jaxpr(
-        lambda fs, r: dispatch_roi_align(fs, r, STRIDES, 7))(feats, rois))
+    def fwd(fs, r):
+        return dispatch_roi_align(fs, r, STRIDES, 7)
+
+    fn = jax.grad(lambda fs, r: fwd(fs, r).sum()) if grad else fwd
+    return str(jax.make_jaxpr(fn)(feats, rois))
 
 
-@pytest.mark.parametrize("backend,mode,kernel", [
-    ("cpu", "auto", False),     # non-TPU platform -> XLA formulation
-    ("tpu", "auto", True),      # TPU: auto MEANS the kernel
-    ("tpu", "xla", False),      # the explicit setting wins
-    ("cpu", "pallas", True),    # ... in both directions
-])
-def test_gate_is_decided_by_platform_or_setting(monkeypatch, backend,
-                                                mode, kernel):
-    """The kernel-or-XLA choice is decidable BEFORE anything compiles:
-    platform, or the explicit EKSML_ROI_BACKEND setting.  No probe."""
+@pytest.mark.parametrize("grad", [False, True],
+                         ids=["forward", "grad"])
+@pytest.mark.parametrize("backend", ["cpu", "tpu"])
+def test_gate_follows_the_platform(monkeypatch, backend, grad):
+    """The kernel-or-XLA choice is decidable BEFORE anything compiles,
+    from the platform alone — no probe, no setting — and it is ONE
+    choice: the backward ``pallas_call`` is in the gradient's jaxpr
+    exactly when the forward's is (no Pallas forward over an XLA
+    backward, nor the reverse)."""
     from eksml_tpu.ops.pallas import roi_align_kernel as rk
 
     monkeypatch.setattr(rk.jax, "default_backend", lambda: backend)
-    monkeypatch.setenv("EKSML_ROI_BACKEND", mode)
     rng = np.random.RandomState(9)
-    jaxpr = _dispatch_jaxpr(_feats(rng), _rois(rng, 1, 4))
+    jaxpr = _dispatch_jaxpr(_feats(rng), _rois(rng, 1, 4), grad=grad)
+    kernel = backend == "tpu"
     assert ("pallas_call" in jaxpr) is kernel
+    assert ("roi_align_fwd" in jaxpr) is kernel
+    assert ("roi_align_bwd" in jaxpr) is (kernel and grad)
 
 
 def test_gate_kernel_failure_on_tpu_raises_not_falls_back(monkeypatch):
@@ -224,7 +210,6 @@ def test_gate_kernel_failure_on_tpu_raises_not_falls_back(monkeypatch):
     from eksml_tpu.ops.roi_align import dispatch_roi_align
 
     monkeypatch.setattr(rk.jax, "default_backend", lambda: "tpu")
-    monkeypatch.delenv("EKSML_ROI_BACKEND", raising=False)
     rng = np.random.RandomState(10)
     feats, rois = _feats(rng), _rois(rng, 1, 4)
     with pytest.raises(Exception) as err:
@@ -237,22 +222,16 @@ def test_gate_kernel_failure_on_tpu_raises_not_falls_back(monkeypatch):
 
 def test_gate_coverage_guard_selects_xla_before_compiling(monkeypatch):
     """Feature maps implying an image wider than the tile covers at the
-    coarsest level take the XLA path even with the kernel forced — a
-    static shape decision, not a fallback."""
-    monkeypatch.setenv("EKSML_ROI_BACKEND", "pallas")
+    coarsest level take the XLA path even where the gate says kernel
+    — a static shape decision, not a fallback."""
+    from eksml_tpu.ops.pallas import roi_align_kernel as rk
+
+    monkeypatch.setattr(rk.jax, "default_backend", lambda: "tpu")
     big = 2048  # > (TILE - margin) * 32
     feats = tuple(jnp.zeros((1, big // s, big // s, 8), jnp.float32)
                   for s in STRIDES)
     rois = jnp.asarray([[[8.0, 8.0, 200.0, 120.0]]], jnp.float32)
     assert "pallas_call" not in _dispatch_jaxpr(feats, rois)
-
-
-def test_gate_rejects_unknown_setting(monkeypatch):
-    from eksml_tpu.ops.pallas import roi_align_kernel as rk
-
-    monkeypatch.setenv("EKSML_ROI_BWD", "palas")
-    with pytest.raises(ValueError, match="EKSML_ROI_BWD"):
-        rk.pallas_roi_bwd_supported()
 
 
 def test_kernel_runs_once_per_batch_shard_on_a_mesh():
@@ -361,6 +340,48 @@ def test_backward_chunked_matches_unchunked(monkeypatch):
     for w, ch in zip(whole, chunked):
         np.testing.assert_allclose(np.asarray(w), np.asarray(ch),
                                    atol=1e-5)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("n,out", [(4 * 512, 7), (4 * 128, 14)],
+                         ids=["box", "mask"])
+def test_backward_chunk_fits_at_the_cells_shapes(n, out, dtype):
+    """The backward's chunk of the incoming gradient at the benchmark
+    cells' own shapes (batch 4, 512 box ROIs at 7x7 / 128 mask ROIs at
+    14x14, C 256): it divides the grid, and a chunk of the gradient
+    beside the kernel's own scratch stays under the stack budget, which
+    stays under the limit every kernel declares."""
+    from eksml_tpu.ops.pallas import roi_align_kernel as rk
+
+    c = 256
+    scratch = rk._bwd_scratch_bytes(out, c)
+    chunk = rk._roi_chunk(n, out, c, dtype, scratch)
+    assert n % chunk == 0
+    out_pad = out + (-out % 8)
+    held = chunk * out * out_pad * c * jnp.dtype(dtype).itemsize
+    assert held + scratch <= rk._VMEM_STACK_BUDGET
+    assert rk._VMEM_STACK_BUDGET < rk._SCOPED_VMEM_KIB * 1024
+
+
+def test_ops_and_models_read_no_environment(tracked_files):
+    """What the step's ops do is decided by what they can observe
+    (the platform, static shapes), never by a variable read while
+    tracing: the value would be baked into the jitted program, where
+    the persistent compile cache ignores a later change."""
+    import os
+    import re
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sources = [p for p in tracked_files if p.endswith(".py")
+               and p.startswith(("eksml_tpu/ops/", "eksml_tpu/models/"))]
+    assert len(sources) >= 10, sources
+    reads = []
+    for path in sources:
+        with open(os.path.join(root, path)) as f:
+            reads += [f"{path}:{i}" for i, line in enumerate(f, 1)
+                      if re.search(r"\benviron\b|\bgetenv\b", line)]
+    assert not reads, reads
 
 
 def _grads_vs_xla(feats, rois, strides, out_size, levels=None):
@@ -581,7 +602,7 @@ def _assert_vmem_limit(params_list, kib):
         assert mosaic.vmem_limit_bytes == kib * 1024, mosaic
 
 
-def test_vmem_limit_rides_in_the_kernel(monkeypatch):
+def test_vmem_limit_rides_in_the_kernel():
     """Nothing in the repo edits LIBTPU_INIT_ARGS (libtpu reads it
     once, at backend init, so a flag appended later never reaches the
     compiler).  The scoped-vmem limit therefore travels IN the compiled
@@ -607,10 +628,4 @@ def test_vmem_limit_rides_in_the_kernel(monkeypatch):
             f, r, gg, STRIDES, 7, 2, 2, True),
         feats, rois, g)
     _assert_vmem_limit(bwd, rk._SCOPED_VMEM_KIB)
-
-    # the env override must flow through to the emitted kernels
-    monkeypatch.setenv("EKSML_SCOPED_VMEM_KIB", "65536")
-    fwd = _pallas_eqn_compiler_params(
-        lambda f, r: rk._pallas_forward(f, r, STRIDES, 7, 2, 2, True),
-        feats, rois)
-    _assert_vmem_limit(fwd, 65536)
+    assert rk._SCOPED_VMEM_KIB == 32768

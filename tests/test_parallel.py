@@ -1,5 +1,7 @@
 """Parallel-layer tests on the 8-device virtual CPU mesh."""
 
+import os
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -9,6 +11,7 @@ from jax.sharding import PartitionSpec as P
 from eksml_tpu.parallel import (batch_sharding, build_mesh, cross_host_sum,
                                 param_fingerprint, replicated_sharding,
                                 validate_topology)
+from eksml_tpu.parallel import collectives
 from eksml_tpu.parallel.collectives import assert_replicas_in_sync
 from eksml_tpu.parallel.mesh import TOPOLOGIES
 
@@ -364,3 +367,62 @@ def test_topology_manifest_round_trip_carries_slice_count():
                              num_slices=1)
     assert not compatible(loaded, topo1)
     assert "num_slices" in diff(loaded, topo1)
+
+
+# ---- nothing edits LIBTPU_INIT_ARGS or starts a child that would
+# ---- need the chip
+
+
+def _repo_sources():
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    paths = [os.path.join(root, "__graft_entry__.py")]
+    for base in ("eksml_tpu", "tools"):
+        for d, _, files in os.walk(os.path.join(root, base)):
+            paths += [os.path.join(d, f) for f in files
+                      if f.endswith(".py")]
+    return paths
+
+
+def test_collective_flag_absent_from_libtpu_init_args():
+    """libtpu 0.0.34 has no ``xla_tpu_all_reduce_combine_threshold_
+    bytes`` and EXITS on the unknown flag, and it reads
+    ``LIBTPU_INIT_ARGS`` once, at backend init.  So the flag is not set
+    at all: no library or tool source writes that variable."""
+    for path in _repo_sources():
+        with open(path) as f:
+            src = f.read()
+        assert 'environ["LIBTPU_INIT_ARGS"] =' not in src, path
+    assert not hasattr(collectives, "set_xla_collective_flags")
+
+
+def test_collective_flag_layer_starts_no_child():
+    """A process that has initialised JAX owns the chip; a child that
+    needs it fails or hangs.  The collective layer (and the kernel
+    gate) therefore never start one."""
+    import inspect
+
+    from eksml_tpu.ops.pallas import roi_align_kernel
+
+    for mod in (collectives, roi_align_kernel):
+        src = inspect.getsource(mod)
+        assert "subprocess" not in src, mod.__name__
+        assert "threading" not in src, mod.__name__
+
+
+def test_collective_flag_operator_value_survives_trainer_init(
+        monkeypatch, fresh_config, tmp_path):
+    """An operator-set LIBTPU_INIT_ARGS (the charts' pod env — placed
+    before the first backend call, where it can take effect) passes
+    through ``Trainer.__init__`` untouched."""
+    from eksml_tpu.config import SMOKE_OVERRIDES, finalize_configs
+    from eksml_tpu.train import Trainer
+
+    monkeypatch.setenv("LIBTPU_INIT_ARGS", "--xla_keep_me=1")
+    fresh_config.update_args(list(SMOKE_OVERRIDES)
+                             + ["TPU.MESH_SHAPE=(1,1)"])
+    cfg = finalize_configs(is_training=True)
+    trainer = Trainer(cfg, str(tmp_path), write_metrics=False)
+    try:
+        assert os.environ["LIBTPU_INIT_ARGS"] == "--xla_keep_me=1"
+    finally:
+        trainer.ckpt.close()

@@ -423,7 +423,7 @@ def test_synthetic_regression_fails_component_attributed(tmp_path):
     assert "roi-bwd" in row["error"], row
 
 
-# ---- bench.py status field + bench_gate --predicted ------------------
+# ---- a bench line's status field + bench_gate --predicted ------------
 
 
 def test_usable_measurement_honors_status_field():
@@ -438,7 +438,7 @@ def test_usable_measurement_honors_status_field():
 
 def _bank_round_file(path, line):
     with open(path, "w") as f:
-        json.dump({"n": 1, "cmd": "python bench.py", "rc": 0,
+        json.dump({"n": 1, "cmd": "python measure.py", "rc": 0,
                    "tail": json.dumps(line) + "\n"}, f)
 
 

@@ -35,7 +35,11 @@ def test_watchdog_fires_on_stall_and_names_the_phase(tmp_path):
                       first_beat_factor=1.0).start()
     try:
         wd.beat("train_step", 7)
-        time.sleep(1.0)  # the "hang": no further beats
+        # the "hang": no further beats, until the second report or a
+        # generous cap (a loaded machine schedules the watchdog late)
+        give_up = time.monotonic() + 10.0
+        while wd.fires < 2 and time.monotonic() < give_up:
+            time.sleep(0.05)
     finally:
         wd.stop()
     assert wd.fires >= 2, "persistent hang must re-report every deadline"

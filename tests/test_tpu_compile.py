@@ -119,12 +119,10 @@ def test_forward_compiles_for_v5e(one_chip, head, dtype):
 
 @pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
 @pytest.mark.parametrize("head", ["box", "mask"])
-def test_grad_compiles_for_v5e(one_chip, monkeypatch, head, dtype):
+def test_grad_compiles_for_v5e(one_chip, head, dtype):
     """Forward + the transpose kernel (strips of the f32 accumulators,
-    asynchronous write-back).  The backward gate asks
-    ``jax.default_backend()``, which is the CPU here, so the test
-    steers it through the existing ``EKSML_ROI_BWD`` switch."""
-    monkeypatch.setenv("EKSML_ROI_BWD", "pallas")
+    asynchronous write-back): whoever calls the forward kernel gets
+    the backward kernel."""
     feats, rois, out_size = _shapes(head, dtype, one_chip)
 
     def loss(fs, r):
@@ -139,13 +137,11 @@ def test_grad_compiles_for_v5e(one_chip, monkeypatch, head, dtype):
     assert set(names) <= set(KERNEL_NAMES), names
 
 
-def test_every_custom_call_carries_its_kernels_name(one_chip,
-                                                    monkeypatch):
+def test_every_custom_call_carries_its_kernels_name(one_chip):
     """Forward kept alive beside the backward (``value_and_grad`` of a
     loss that returns the pooled features too): no ``tpu_custom_call``
     of the step is left unnamed, and the three kernels come out under
     three names, with the scope the attribution rules match."""
-    monkeypatch.setenv("EKSML_ROI_BWD", "pallas")
     feats, rois, out_size = _shapes("box", "bfloat16", one_chip)
 
     def loss(fs, r):
@@ -187,11 +183,13 @@ def test_dispatch_compiles_on_a_four_chip_mesh(four_chip_mesh,
     compiles, forward and backward."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
+    from eksml_tpu.ops.pallas import roi_align_kernel
     from eksml_tpu.ops.roi_align import (batch_partition,
                                          dispatch_roi_align)
 
-    monkeypatch.setenv("EKSML_ROI_BACKEND", "pallas")
-    monkeypatch.setenv("EKSML_ROI_BWD", "pallas")
+    # the gate asks jax.default_backend(), the CPU here
+    monkeypatch.setattr(roi_align_kernel.jax, "default_backend",
+                        lambda: "tpu")
     spec = P(("data", "model"))
     feats, rois, out_size = _shapes(
         head, "bfloat16", NamedSharding(four_chip_mesh, spec))

@@ -1,7 +1,9 @@
-"""Gate a fresh bench.py JSON line against the banked trajectory.
+"""Gate a fresh bench JSON line against the banked trajectory.
 
-The repo banks one ``BENCH_r<NN>.json`` per round (the driver wraps
-``bench.py`` stdout as ``{"n", "cmd", "rc", "tail"}``), but nothing
+A round is banked as one ``BENCH_r<NN>.json`` (a wrapper's record of
+a run's stdout: ``{"n", "cmd", "rc", "tail"}``; the tree holds none
+today, and the harness that printed such lines is gone: ROADMAP D1),
+but nothing
 ever COMPARED a new measurement against that trajectory — a step-time
 regression only surfaced when a human eyeballed the numbers.  This
 tool is the missing regression gate:
@@ -10,8 +12,8 @@ tool is the missing regression gate:
   each file's ``tail`` is scanned for its last ``{"metric": ...}``
   line.  Error lines (``value == 0``) fall back to the line's
   ``last_good`` snapshot where an older record carries one.
-- the **fresh** measurement is a bench JSON line (or raw bench.py
-  stdout) from a file or stdin.
+- the **fresh** measurement is a bench JSON line (or the raw stdout
+  that ends in one) from a file or stdin.
 - the gate FAILS (exit 1) when fresh ``step_time_ms`` exceeds the
   newest usable banked step time by more than ``--max-regress-pct``
   (or when throughput ``value`` drops by more than the same bound,
@@ -25,8 +27,7 @@ tool is the missing regression gate:
 
 Usage::
 
-    python bench.py ... | python tools/bench_gate.py --fresh - \
-        --max-regress-pct 10
+    ... | python tools/bench_gate.py --fresh - --max-regress-pct 10
     python tools/bench_gate.py --fresh bench_out.json \
         --bank 'BENCH_r*.json' --allow-missing-baseline
 
@@ -51,7 +52,7 @@ METRIC_LINE_RE = re.compile(r'^\s*\{"metric"')
 
 
 def extract_metric_line(text: str) -> Optional[Dict]:
-    """Last ``{"metric": ...}`` JSON object in ``text`` (bench.py
+    """Last ``{"metric": ...}`` JSON object in ``text`` (a run
     prints exactly one as its final line; banked files wrap whole
     stdout)."""
     last = None
@@ -67,14 +68,14 @@ def extract_metric_line(text: str) -> Optional[Dict]:
 def usable_measurement(line: Optional[Dict]) -> Optional[Dict]:
     """The comparable core of a bench line: the line itself when it
     carries a real measurement, else its ``last_good`` snapshot (the
-    stale-but-honest fallback bench.py emits when hardware was
-    unreachable), else None."""
+    stale-but-honest fallback of a run that could not reach
+    hardware), else None."""
     if not isinstance(line, dict):
         return None
 
     def _ok(d: Dict) -> bool:
         # an explicit error mark wins over whatever numbers rode
-        # along (bench.py stamps status on every line since ISSUE 7);
+        # along (every line carries a status since ISSUE 7);
         # both compared numbers must also be real: a step_time_ms of
         # 0 would divide the gate by zero as a baseline and trivially
         # PASS as a fresh line — "the bench crashed" must fail
@@ -279,7 +280,7 @@ def gate(fresh: Optional[Dict], bank: List[Tuple[str, Dict]],
 def main(argv=None) -> int:
     p = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     p.add_argument("--fresh", required=True,
-                   help="fresh bench JSON line / bench.py stdout "
+                   help="fresh bench JSON line / a run's stdout "
                         "(file path, or '-' for stdin)")
     p.add_argument("--bank", default=None,
                    help="glob of banked rounds (default: "

@@ -13,10 +13,8 @@ import sys
 import tempfile
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-# hermetic: none of the kernel/precision env switches may leak in
-for var in ("EKSML_ROI_BACKEND", "EKSML_ROI_BWD",
-            "EKSML_DEFAULT_PRECISION"):
-    os.environ.pop(var, None)
+# hermetic: the precision env switch may not leak in
+os.environ.pop("EKSML_DEFAULT_PRECISION", None)
 
 import numpy as np
 

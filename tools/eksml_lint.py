@@ -1,8 +1,7 @@
 """eksml-lint CLI: framework-invariant static analysis gating CI.
 
 Runs the thirteen rules in ``eksml_tpu/analysis/`` over the
-production tree (eksml_tpu/, tools/, bench.py — tests are excluded on
-purpose) and exits nonzero on any finding that is neither suppressed
+production tree (eksml_tpu/, tools/ — tests are excluded on purpose) and exits nonzero on any finding that is neither suppressed
 inline (``# eksml-lint: disable=<rule>``) nor grandfathered in the
 committed baseline: the six v1 module/project rules, the four v2
 SPMD-safety rules on the cross-module call graph, and the three v3
@@ -65,7 +64,7 @@ def main(argv=None) -> int:
     p = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     p.add_argument("targets", nargs="*", default=None,
                    help="files/dirs to lint (default: the production "
-                        "tree — eksml_tpu/, tools/, bench.py)")
+                        "tree — eksml_tpu/, tools/)")
     p.add_argument("--rules", default=None,
                    help=f"comma list of {list(ALL_RULES)} "
                         "(default: all)")

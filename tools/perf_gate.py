@@ -20,9 +20,8 @@ baseline.
 - **calibration**: every run reports the model's honesty — one scale
   factor per rung fitted against the banked r5 hardware artifacts
   (``artifacts/roi_ab_r5.json``, ``bench_rung_1344_b4.json``), with
-  the cross-rung spread printed as ``model_error_pct``.  When new
-  hardware numbers land (bench.py now emits predicted next to
-  measured), the fit tightens automatically.
+  the cross-rung spread printed as ``model_error_pct``.  A banked
+  rung that carries predicted next to measured joins the fit.
 
 The model is lowered at the SMOKE channel widths (config
 SMOKE_OVERRIDES) so a CI box compiles each geometry in tens of
@@ -61,8 +60,8 @@ sys.path.insert(0, REPO)
 from eksml_tpu.fsio import atomic_write_json, atomic_write_text  # noqa: E402
 
 # Rung geometries the predictor lowers (canvas × batch, plus the knobs
-# a rung pre-plans — mirrors bench.py RUNGS where the names overlap so
-# a measured rung pairs with its prediction by name).
+# a rung pre-plans — named as the banked ``bench_rung_<name>.json``
+# records are, so a measured rung pairs with its prediction by name).
 PRED_RUNGS: Dict[str, Dict[str, Any]] = {
     "128_b1": {"image_size": 128, "batch_size": 1},
     "256_b1": {"image_size": 256, "batch_size": 1},
@@ -184,8 +183,8 @@ def predict_rung(rung: str, strategy: str, precision: str,
     cfg = _rung_config(rung, precision, config_overrides)
     # cfg wins over the flag: a --config TRAIN.PRECISION override
     # changed the lowered program, and pricing/keying it as the flag
-    # precision would overwrite the wrong baseline (the bench.py
-    # re-derivation rule)
+    # precision would overwrite the wrong baseline (the lint's
+    # config-drift rule)
     precision = str(cfg.TRAIN.PRECISION)
     num_slices = int(spec.get("num_slices", 1))
     exchange = "hierarchical" if num_slices > 1 else "flat"
@@ -264,7 +263,7 @@ def predict_serve_rung(rung: str, precision: str, target: str,
 
     spec = SERVE_PRED_RUNGS[rung]
     cfg = _serve_rung_config(rung, precision, config_overrides)
-    # cfg wins over the flag (the bench.py re-derivation rule): a
+    # cfg wins over the flag (the lint's config-drift rule): a
     # --config TRAIN.PRECISION override changed the lowered program
     precision = str(cfg.TRAIN.PRECISION)
     t0 = time.time()

@@ -552,8 +552,9 @@ def _attribution_section(logdir: str,
                                        "attribution.json")
     lines = ["## Modeled cost by component (profile attribution)"]
     if not os.path.exists(path):
-        lines += ["", f"No attribution artifact at `{path}` — run "
-                      "`bench.py --profile` to bank one."]
+        lines += ["", f"No attribution artifact at `{path}` — "
+                      "`eksml_tpu.profiling.write_attribution_artifact` "
+                      "writes one from a compiled step's HLO text."]
         return lines
     try:
         with open(path) as f:
@@ -889,9 +890,8 @@ def _predicted_section(artifacts_dir: Optional[str]) -> List[str]:
         return lines
     if not cal["points"]:
         lines += ["", "No measured-vs-predicted calibration pairs "
-                      "yet — the fit tightens when a hardware round "
-                      "lands (bench.py emits predicted alongside "
-                      "measured)."]
+                      "yet — the fit takes any banked rung that "
+                      "carries predicted alongside measured."]
         return lines
     lines += ["",
               f"Calibration over {cal['n_points']} hardware "

@@ -10,7 +10,8 @@ durations by family (regex over XLA fusion/custom-call names).
 
 Component attribution (VERDICT r5 weak #3: fusion names like "5"/"23"
 put 86.78% of device time in "other"): pass ``--attribution`` (the
-``profile/attribution.json`` artifact ``bench.py --profile`` banks) or
+``profile/attribution.json`` artifact that
+``eksml_tpu.profiling.write_attribution_artifact`` writes) or
 ``--hlo`` (a raw ``Compiled.as_text()`` dump) and every event name is
 first resolved through the compiled module's instruction→component map
 (eksml_tpu/profiling), yielding a ``component_pct`` table — rpn-nms /
@@ -365,8 +366,9 @@ def main(argv=None):
     p.add_argument("--out", default=None)
     p.add_argument("--top", type=int, default=15)
     p.add_argument("--attribution", default=None,
-                   help="profile/attribution.json from bench.py "
-                        "--profile: resolve event names to model "
+                   help="profile/attribution.json (profiling."
+                        "write_attribution_artifact): resolve event "
+                        "names to model "
                         "components (eksml_tpu/profiling)")
     p.add_argument("--hlo", default=None,
                    help="raw Compiled.as_text() dump to build the "
