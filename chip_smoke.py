@@ -64,11 +64,13 @@ GLOBAL_BATCH = 4
 # kernel phase: max |kernel - reference| as a fraction of max
 # |reference|.  The reference is the XLA gather formulation evaluated
 # in float32 on the same (dtype-rounded) inputs: the kernel computes in
-# f32 at HIGHEST MXU precision and rounds only its output, so what is
-# left is output rounding (2^-8 in bf16) and, in the backward, the
-# order in which many ROIs accumulate into one tile.
+# f32 at HIGHEST MXU precision (or, forward over bf16 strips, its
+# three-term equivalent) and rounds only its output, so what is left is
+# output rounding (2^-8 in bf16) and, in the backward, the order in
+# which many ROIs accumulate into one strip.
 KERNEL_FWD_TOL = 1e-2
 KERNEL_BWD_TOL = 2e-2
+KERNEL_TIMED_CALLS = 10
 # four-chip phase: relative |loss_4 - loss_1| / |loss_1|.  Step 1 runs
 # identical params on identical data (reduction order differs); later
 # steps also carry discrete flips in proposal sampling / NMS.
@@ -137,11 +139,30 @@ def check_device(args, device: dict) -> None:
 # ---------------------------------------------------------------- kernel
 
 
+def median_call_ms(fn, *args) -> float:
+    """Median host-clock time of a call that ends in
+    ``block_until_ready``, compiled and warmed first."""
+    import statistics
+
+    import jax
+
+    jax.block_until_ready(fn(*args))
+    times = []
+    for _ in range(KERNEL_TIMED_CALLS):
+        t0 = time.perf_counter()
+        jax.block_until_ready(fn(*args))
+        times.append(1e3 * (time.perf_counter() - t0))
+    return round(statistics.median(times), 3)
+
+
 def phase_kernel(args) -> None:
     """Pallas ROIAlign forward and backward against the XLA gather
-    formulation (same tile-fit level assignment) at the production
-    shape class: C=256, four FPN levels, the mask head's 128 ROIs × 14²
-    and the box head's 512 ROIs × 7²."""
+    formulation (same tile-fit level assignment) at the benchmark
+    cells' shapes: batch 4, C=256, four FPN levels of a 1344² canvas,
+    the mask head's 128 ROIs × 14² and the box head's 512 ROIs × 7² an
+    image; then each kernel alone on the clock (``fwd_ms``, ``bwd_ms``:
+    median of ``KERNEL_TIMED_CALLS`` calls that end in
+    ``block_until_ready``; on a chip only)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -157,7 +178,7 @@ def phase_kernel(args) -> None:
         img, c, b = 128, 8, 1
         cases = [("mask", 6, 14, jnp.float32), ("box", 8, 7, jnp.float32)]
     else:
-        img, c, b = 1344, 256, 2
+        img, c, b = 1344, 256, 4
         cases = [("mask", 128, 14, jnp.bfloat16),
                  ("mask", 128, 14, jnp.float32),
                  ("box", 512, 7, jnp.bfloat16),
@@ -233,6 +254,17 @@ def phase_kernel(args) -> None:
                                                               out_r)
         fwd_err = rel_err(out_k, out_r)
         bwd_err = max(rel_err(a, r) for a, r in zip(grads_k, grads_r))
+        del out_r, grads_r, out_k, grads_k
+        if not interpret:
+            def bwd_alone(fs, r, gg):
+                # the forward is dead code under a vjp whose primal
+                # output nobody reads
+                return jax.vjp(lambda f: kernel(f, r), fs)[1](gg)[0]
+
+            observed["fwd_ms"] = median_call_ms(jax.jit(kernel), feats,
+                                                rois)
+            observed["bwd_ms"] = median_call_ms(jax.jit(bwd_alone), feats,
+                                                rois, g)
         used = sorted(set(np.asarray(levels).ravel().tolist()))
         emit({"phase": "kernel", "rois_set": roi_set, "head": head,
               "rois": n,

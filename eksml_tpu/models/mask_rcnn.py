@@ -36,12 +36,13 @@ from eksml_tpu.models.rpn import (RPNHead, generate_proposals, match_anchors,
 from eksml_tpu.ops.anchors import generate_fpn_anchors
 from eksml_tpu.ops.boxes import clip_boxes, decode_boxes
 from eksml_tpu.ops.nms import class_aware_nms
-from eksml_tpu.ops.pallas import bwd_tile_share
+from eksml_tpu.ops.pallas import bwd_tile_share, fwd_tile_share
 from eksml_tpu.ops.roi_align import dispatch_roi_align, resample_masks
 
 # what leaves the step beside the losses, and the host span that
 # carries it at log steps (train.Trainer.fit)
-COUNTER_SPANS = {"roi_bwd_strips": ("roi_bwd_tile_share",)}
+COUNTER_SPANS = {"roi_bwd_strips": ("roi_bwd_tile_share",
+                                    "roi_fwd_tile_share")}
 
 
 class MaskRCNN(nn.Module):
@@ -315,15 +316,17 @@ class MaskRCNN(nn.Module):
             losses["mrcnn_loss"] = mask_loss.mean()
 
         losses["total_loss"] = sum(losses.values())
-        # after the sum, which takes every entry of the dict: a counter,
-        # not a loss (COUNTER_SPANS hands it to a span at log steps)
+        # after the sum, which takes every entry of the dict: counters,
+        # not losses (COUNTER_SPANS hands them to a span at log steps)
         counted = [(rois, 7)]
         if "mrcnn_loss" in losses:
             counted.append((rois_m, ma))
-        losses["roi_bwd_tile_share"] = jax.lax.stop_gradient(sum(
-            bwd_tile_share(feats[:4], r, self.anchor_strides[:4], o)
-            * r.shape[1] for r, o in counted)
-            / sum(r.shape[1] for r, _ in counted))
+        for key, share in (("roi_bwd_tile_share", bwd_tile_share),
+                           ("roi_fwd_tile_share", fwd_tile_share)):
+            losses[key] = jax.lax.stop_gradient(sum(
+                share(feats[:4], r, self.anchor_strides[:4], o)
+                * r.shape[1] for r, o in counted)
+                / sum(r.shape[1] for r, _ in counted))
         return losses
 
     def _cascade_train(self, feats, rois, roi_labels, matched_gt, fg_mask,

@@ -8,5 +8,6 @@ backend.
 """
 
 from eksml_tpu.ops.pallas.roi_align_kernel import (  # noqa: F401
-    TILE, bwd_tile_share, pallas_batched_multilevel_roi_align,
+    TILE, bwd_tile_share, fwd_tile_share,
+    pallas_batched_multilevel_roi_align,
     pallas_roi_align_supported, sublane_align, tile_margin)

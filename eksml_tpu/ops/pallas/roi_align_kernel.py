@@ -1,53 +1,72 @@
-"""Pallas multilevel ROIAlign: per-ROI tile DMA + separable matmuls.
+"""Pallas multilevel ROIAlign: strips of the ROI's footprint, one MXU
+product a strip, channels in lanes — forward and backward.
 
 Why a kernel (SURVEY.md §7 hard part #2): the XLA formulation in
 ops/roi_align.py must align every ROI on every FPN level (one-hot
 select keeps shapes static) and sample via gathers — 4× redundant work
-on a gather path the TPU executes poorly.  This kernel:
+on a gather path the TPU executes poorly.  These kernels:
 
-- reads the per-ROI *assigned* level only (the 4× back);
-- replaces gathers with two MXU matmuls per ROI: bilinear
-  interpolation is separable, so sampling is
-  ``Ry @ tile @ Cx`` with ``Ry[s,t] = relu(1 - |y_s - t|)``
-  (row weights) and ``Cx`` likewise for columns — exactly the 2-tap
-  bilinear weights, built with iota arithmetic on the VPU;
-- DMAs one fixed ``T×T×C`` feature tile per ROI from HBM (grid is
-  sequential per core, so no write races), scalar-prefetching ALL
-  per-ROI metadata — level/batch/origin indices and the float
-  start/bin-size values — through SMEM.  (Putting the float info in a
-  VMEM block would need a (1, 8) block shape, which Mosaic rejects:
-  the second-to-last block dim must be a multiple of 8.)  Tile fetch
-  is DOUBLE-BUFFERED: ROI r+1's tile streams into the other slot while
-  ROI r's matmuls run, so the 2-4 MB/ROI DMA overlaps compute.
+- read (forward) or update (backward) the per-ROI *assigned* level
+  only (the 4× back);
+- move and multiply the ROI's FOOTPRINT, not a fixed tile: the rows
+  ``floor(y1 − 0.5) .. floor(y2 − 0.5) + 1`` and the columns likewise,
+  the ones that carry a non-zero bilinear weight, are covered by
+  ``ny × nx ≤ 4 × 4`` strips of one fixed shape
+  (``STRIP_H × STRIP_W × C``; DMA shapes are static), ``_strip_prep``'s
+  cover.  One cover serves both directions; only the W alignment of the
+  origin differs (``sublane_align(dtype)`` for the forward's strips,
+  read in the features' own dtype; 8 for the backward's float32
+  accumulators);
+- replace gathers with ONE MXU product a strip.  Bilinear sampling and
+  bin pooling are linear and separable, so a strip's contribution is
+  ``out[(i j), c] += Σ_(y x) RyP[i, y] · CxP[j, x] · strip[(y x), c]``
+  with the *pooled* two-tap weights
+  (``RyP[i, t] = mean_a relu(1 − |y_(i,a) − t|)``, ``_pooled_weights``);
+  the ``[out², STRIP_H·STRIP_W]`` weight matrix is the outer product of
+  the two pooled vectors, built with iota arithmetic on the VPU.
+  Channels stay in lanes on both sides: no transpose, no
+  ``[S, T, C]`` intermediate;
+- scalar-prefetch ALL per-ROI metadata — level/batch/strip origin and
+  counts, and the float start/bin-size values — through SMEM.  (Putting
+  the float info in a VMEM block would need a (1, 8) block shape, which
+  Mosaic rejects: the second-to-last block dim must be a multiple
+  of 8.)
+
+The forward (``_fwd_kernel``) accumulates a ROI's strips in a float32
+VMEM block and writes it to HBM once.  Its strips are read-only, so
+there are no hazards: the next strip — the next ROI's first one too —
+streams into the other slot while this one is multiplied, and a ROI's
+result leaves by DMA while the next ROI is formed.  bf16 features are
+exact in one bf16 pass, so only the float32 weights are split into
+their three bf16 terms, each multiplied by the strip once
+(``_strip_product``; what ``Precision.HIGHEST`` computes on the
+up-cast strip, less the passes over its zero low parts); float32
+features take ``Precision.HIGHEST`` itself.
 
 Semantics notes:
 - matches ``aligned=True`` ROIAlign with zero padding outside the
   image, PROVIDED each level's feature map is spatially padded to at
-  least ``T`` (the caller pads; padding is zeros, which is exactly the
-  zero-padding ROIAlign wants);
+  least ``TILE`` (the caller pads; padding is zeros, which is exactly
+  the zero-padding ROIAlign wants);
 - level assignment is the shared tile-fit variant
-  (``assign_fpn_levels_tile_fit``): ROIs whose extent would overflow
-  the tile at the heuristic level are bumped to a coarser level, so
-  the forward and backward kernels (both read ``_prep``'s levels)
-  compute the same linear map — no silent fwd/bwd divergence for
-  extreme aspect ratios.
+  (``assign_fpn_levels_tile_fit`` at ``TILE``): ROIs whose extent would
+  overflow a ``TILE``-wide window at the heuristic level are bumped to
+  a coarser level.  ``TILE`` is thereby the bound of the cover (4 × 4
+  strips always suffice) and part of the mathematics the benchmark's
+  reference repeats (``roi_tile_usable``); both kernels read
+  ``_prep``'s levels, so they compute the same linear map.
 
-The backward wrt features is the TRANSPOSE of the same separable
-linear map, accumulated into per-level f32 HBM buffers by sequential
+The backward wrt features is the TRANSPOSE of the same linear map,
+accumulated into per-level f32 HBM buffers by sequential
 read-modify-write DMA (the grid is sequential per core — no write
-races; buffers start zeroed through ``input_output_aliases``).  It
-moves and multiplies the ROI's FOOTPRINT, not the tile: the rows and
-columns that carry a non-zero weight are covered by strips of one
-fixed shape (``STRIP_H × STRIP_W``, ``_bwd_prep``; an ROI that fills
-the tile still gets all of it), and a strip's update is one MXU
-product with channels in lanes, ``d[(y x), c] = Σ_(i j) RyP[i,y] ·
-CxP[j,x] · g[(i j), c]`` with the *pooled* weights
-(``RyP[i,t] = mean_a Ry[i·s+a, t]``; pooling is linear so it folds
-into the weights) — no scatter, no transpose.  Write-back is
-asynchronous over two staging strips (``_bwd_kernel``).
-``bwd_tile_share`` is the share of the tile the strips cover, the
-step's ``roi_bwd_tile_share`` counter.  Whoever runs the forward
-kernel runs this one (``_bwd``): there is no mixed path.
+races; buffers start zeroed through ``input_output_aliases``):
+``d[(y x), c] = Σ_(i j) RyP[i,y] · CxP[j,x] · g[(i j), c]`` a strip,
+write-back asynchronous over two staging strips (``_bwd_kernel``).
+``fwd_tile_share`` / ``bwd_tile_share`` are the shares of a
+``TILE × TILE`` tile the strips of each direction cover, the step's
+``roi_fwd_tile_share`` / ``roi_bwd_tile_share`` counters.  Whoever runs
+the forward kernel runs the backward one (``_bwd``): there is no mixed
+path.
 """
 
 from __future__ import annotations
@@ -59,22 +78,26 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-TILE = 64  # T: per-ROI feature tile (covers √area/stride ≲ 56 + taps)
-# The backward moves its f32 accumulators in strips of this one shape
+# T: the window the tile-fit level assignment fits every ROI into
+# (√area/stride ≲ 56 + taps), hence the bound of the strips' cover
+TILE = 64
+# Both kernels move the level's map in strips of this one shape
 # (rows × columns; DMA shapes are static); 4 × 4 of them cover a tile.
 # Chosen on the chip (PERF.md §6, PR 29): the kernel's time is 0.9 µs a
 # ROI + 0.5 µs a strip + 3.1 ns a strip pixel (the MXU), and 16 × 16
 # gave the least on both ROI sets among 8×32, 16×16, 16×32, 32×32, 8×64.
 STRIP_H, STRIP_W = 16, 16
-# W origin of a strip: the f32 accumulators' sublane tile, whatever the
-# features' dtype (the forward's origin follows ``sublane_align``)
+# W origin of a backward strip: the f32 accumulators' sublane tile,
+# whatever the features' dtype (a forward strip is read in the
+# features' dtype, so its origin follows ``sublane_align``)
 _BWD_ALIGN = 8
 
-# Mosaic's default per-kernel scoped-vmem stack is 16 MiB, and the
-# production mask-head call (double-buffered 64×64×256 tile scratch +
-# vmem-resident output) needs ~16.16 MiB — 160 KiB over, a hard compile
-# reject.  v5e/v6e have 128 MiB of vmem per core; a 32 MiB stack is
-# comfortably safe.  The limit rides inside every Mosaic custom call
+# Mosaic's default per-kernel scoped-vmem stack is 16 MiB, and a
+# production call whose chunk of the output (forward) or of the
+# incoming gradient (backward) XLA keeps vmem-resident beside the
+# kernel's own scratch has overflowed it (round 5: 160 KiB over, a hard
+# compile reject).  v5e/v6e have 128 MiB of vmem per core; a 32 MiB
+# stack is comfortably safe.  The limit rides inside every Mosaic custom call
 # (``_compiler_params``), so no process-wide libtpu flag is needed.
 _SCOPED_VMEM_KIB = 32768
 
@@ -114,127 +137,186 @@ def _tap_weights(start, binsz, s_idx, t_idx, sampling: int):
     """Two-tap bilinear weight of feature position ``t_idx`` for sample
     ``s_idx`` (float arrays of one shape), sample coords
     ``start + (bin + (j+0.5)/sampling) * binsz`` — the ONE definition
-    of the sampling semantics; forward contracts it directly, backward
-    uses its bin-pooled mean.  Any change here keeps fwd/bwd transposed
-    by construction."""
+    of the sampling semantics; both kernels contract its bin-pooled
+    mean (``_pooled_weights``), so any change here keeps fwd/bwd
+    transposed by construction."""
     bins = jnp.floor(s_idx / sampling)
     off = (s_idx - bins * sampling + 0.5) / sampling
     coord = start + (bins + off) * binsz
     return jnp.maximum(0.0, 1.0 - jnp.abs(coord - t_idx))
 
 
-def _bilinear_weights(start, binsz, out_size: int, sampling: int):
-    """[S, T] weight matrix of the forward: samples down, tile
-    positions across."""
-    s_total = out_size * sampling
+def _pooled_weights(start, binsz, bin_idx, t_idx, sampling: int):
+    """Weight of feature position ``t_idx`` in output bin ``bin_idx``:
+    the mean of the bin's ``sampling`` tap weights (pooling is linear,
+    so it folds into the weights)."""
+    return sum(
+        _tap_weights(start, binsz, bin_idx * sampling + a, t_idx, sampling)
+        for a in range(sampling)) / sampling
+
+
+def _strip_product(weights, strip):
+    """``weights [M, K] (float32) · strip [K, C]`` accumulated in
+    float32, to float32 rounding.  A bf16 strip is exact in one bf16
+    pass, so only the weights are split into their three bf16 terms
+    (3 × 8 significand bits = float32's 24), stacked down ``M`` so the
+    strip is latched in the MXU once: ``Precision.HIGHEST`` on the
+    up-cast strip computes the same three products plus three more with
+    the strip's zero low parts.  Other dtypes take ``HIGHEST``."""
     f32 = jnp.float32
-    # Mosaic's iota is integer-only; build int32 and convert
-    s_idx = jax.lax.broadcasted_iota(
-        jnp.int32, (s_total, TILE), 0).astype(f32)
-    t_idx = jax.lax.broadcasted_iota(
-        jnp.int32, (s_total, TILE), 1).astype(f32)
-    return _tap_weights(start, binsz, s_idx, t_idx, sampling)
+    if strip.dtype != jnp.bfloat16:
+        return jnp.dot(weights, strip.astype(f32),
+                       preferred_element_type=f32,
+                       precision=jax.lax.Precision.HIGHEST)
+    terms, rest = [], weights
+    for _ in range(3):
+        terms.append(rest.astype(jnp.bfloat16))
+        rest = rest - terms[-1].astype(f32)
+    m = weights.shape[0]
+    prod = jnp.dot(jnp.concatenate(terms, axis=0), strip,
+                   preferred_element_type=f32)
+    # smallest first: the low terms' sum is rounded once into the high
+    return (prod[2 * m:] + prod[m:2 * m]) + prod[:m]
 
 
-def _kernel(out_size: int, sampling: int, num_levels: int, align: int,
-            # scalar prefetch (SMEM), one entry per ROI:
-            lvl_ref, b_ref, y0_ref, x0_ref,   # int32 level/batch/origin
-            ys_ref, xs_ref, bh_ref, bw_ref,   # f32 tile-local start/bin
-            *refs):
+def _block_rows(out_size: int) -> int:
+    """Rows of a ROI's ``[(i j), C]`` block: ``out²`` padded to the
+    bf16 sublane tile, so the forward's result, its three stacked
+    weight terms and its HBM image are tile-aligned in either dtype."""
+    return -(-out_size * out_size // 16) * 16
+
+
+def _fwd_kernel(out_size: int, sampling: int, num_levels: int, align: int,
+                # scalar prefetch (SMEM), one entry per ROI:
+                lvl_ref, b_ref, ya_ref, xa_ref,   # level/batch/strip origin
+                ny_ref, nx_ref,                   # strips down / across
+                ys_ref, xs_ref, bh_ref, bw_ref,   # f32 start/bin size
+                *refs):
+    """ROI r's ``ny × nx`` strips (``_strip_prep``, W origin aligned to
+    ``align``) are read from its level by DMA in the features' dtype
+    and each adds one MXU product to the ROI's float32 block:
+    ``acc[(i j), c] += Σ_(y x) RyP[i, y]·CxP[j, x] · strip[(y x), c]``
+    — the backward's product the other way round, with the weight
+    matrix built ``[(i j), (y x)]`` so that the contraction runs over
+    its lanes and channels stay in lanes on the other side.
+
+    Nothing is written to the maps, so reads never wait for anything:
+    while strip s is multiplied, strip s+1 — the next ROI's first strip
+    after this ROI's last — streams into the other slot (``state[0]``
+    counts strips issued and picks the slot).  A ROI's block is cast to
+    the features' dtype into the result block and leaves by DMA while
+    the next ROI's strips are multiplied; that write is waited before
+    the block is refilled, a whole ROI later, and the last grid step
+    waits for its own.  Every DMA of a kind moves one byte count, so
+    waits are issued against any descriptor of the kind (a wait is
+    semaphore + byte-count accounting, not an address match)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     feat_refs = refs[:num_levels]          # HBM [B, Hp, Wp, C] each
-    out_ref = refs[num_levels]             # HBM [N, out, out_pad, C]
-    tiles_ref = refs[num_levels + 1]       # VMEM scratch [2, T, T, C]
-    sems = refs[num_levels + 2]            # DMA semaphores (2,)
-    res_ref = refs[num_levels + 3]         # VMEM scratch [1, out, pad, C]
-    out_sem = refs[num_levels + 4]         # DMA semaphore
+    out_ref = refs[num_levels]             # HBM [N, rows, C]
+    strips, in_sems, acc_ref, res_ref, out_sem, state = \
+        refs[num_levels + 1:]
 
+    f32 = jnp.float32
+    rows, c = acc_ref.shape
+    px = STRIP_H * STRIP_W
     r = pl.program_id(0)
     n = pl.num_programs(0)
 
-    # Double-buffered tile fetch: while ROI r's matmuls run, ROI r+1's
-    # tile streams into the other slot — the per-ROI DMA (4 MB f32 /
-    # 2 MB bf16) stops serializing with compute.  Slot parity keeps the
-    # in-flight DMA and the live compute on different buffers; the grid
-    # is sequential per core, so step r's body starts only after step
-    # r-1's compute retired.
-    def _dma(slot, idx, op):
+    def window(feat, bb, y, x):
+        return feat.at[bb, pl.ds(y, STRIP_H), pl.ds(x, STRIP_W), :]
+
+    def start_read(idx, ry, cx, slot):
         lv = lvl_ref[idx]
         bb = b_ref[idx]
-        yy = y0_ref[idx]
-        # x0 arrives as a sublane-block count; multiplying by the
-        # dtype's sublane alignment (8 for f32 tiles (8,128), 16 for
-        # bf16 (16,128)) here lets Mosaic PROVE the W-dim slice origin
-        # is aligned (its HBM-slice tiling requirement — an SMEM value
-        # alone is unprovable)
-        xx = x0_ref[idx] * align
+        y = ya_ref[idx] + ry * STRIP_H
+        # the origin arrives as a block count: a product with the
+        # dtype's sublane alignment lets Mosaic PROVE the W-dim slice
+        # is aligned (an SMEM value alone is unprovable)
+        x = (xa_ref[idx] + cx * (STRIP_W // align)) * align
         for i in range(num_levels):
             @pl.when(lv == i)
             def _(i=i):
-                op(pltpu.make_async_copy(
-                    feat_refs[i].at[bb, pl.ds(yy, TILE),
-                                    pl.ds(xx, TILE), :],
-                    tiles_ref.at[slot], sems.at[slot]))
+                pltpu.make_async_copy(window(feat_refs[i], bb, y, x),
+                                      strips.at[slot],
+                                      in_sems.at[slot]).start()
+
+    def wait_read(slot):
+        pltpu.make_async_copy(window(feat_refs[0], 0, 0, 0),
+                              strips.at[slot], in_sems.at[slot]).wait()
 
     @pl.when(r == 0)
     def _():
-        _dma(0, 0, lambda d: d.start())
+        state[0] = 0
+        start_read(0, 0, 0, 0)
 
-    @pl.when(r + 1 < n)
-    def _():
-        _dma((r + 1) % 2, r + 1, lambda d: d.start())
+    ny = ny_ref[r]
+    nx = nx_ref[r]
+    nxt = jnp.minimum(r + 1, n - 1)
 
-    _dma(r % 2, r, lambda d: d.wait())
-    tile_ref = tiles_ref.at[r % 2]
-
+    # [(i j), (y x)] index grids (Mosaic's iota is integer-only)
+    q = jax.lax.broadcasted_iota(jnp.int32, (rows, px), 0).astype(f32)
+    p = jax.lax.broadcasted_iota(jnp.int32, (rows, px), 1).astype(f32)
+    i_idx = jnp.floor((q + 0.5) / out_size)
+    j_idx = q - i_idx * out_size
+    y_idx = jnp.floor((p + 0.5) / STRIP_W)
+    x_idx = p - y_idx * STRIP_W
     y_start = ys_ref[r]
     x_start = xs_ref[r]
     bin_h = bh_ref[r]
     bin_w = bw_ref[r]
 
-    ry = _bilinear_weights(y_start, bin_h, out_size, sampling)  # [S, T]
-    cx = _bilinear_weights(x_start, bin_w, out_size, sampling)  # [S, T]
-    f32 = jnp.float32
-    s_total = out_size * sampling
+    acc_ref[...] = jnp.zeros((rows, c), f32)
 
-    tile = tile_ref[:].astype(f32)                  # [T, T, C]
-    c = tile.shape[-1]
-    # rows: [S, T] @ [T, T*C] → [S, T, C].  HIGHEST precision: the MXU
-    # multiplies in bf16 passes; one-pass (default) loses ~2^-8 relative
-    # accuracy vs the XLA gather formulation.
-    rows = jnp.dot(ry, tile.reshape(TILE, TILE * c),
-                   preferred_element_type=f32,
-                   precision=jax.lax.Precision.HIGHEST
-                   ).reshape(s_total, TILE, c)
-    # cols: contract T with cx → [S, S, C]
-    sampled = jax.lax.dot_general(
-        rows, cx.T,
-        dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=f32,
-        precision=jax.lax.Precision.HIGHEST)        # [S, C, S]
-    sampled = sampled.transpose(0, 2, 1)            # [S, S, C]
-    pooled = sampled.reshape(out_size, sampling, out_size, sampling,
-                             c).mean(axis=(1, 3))
-    # The output buffer is pinned to HBM and written by explicit DMA
-    # (~100 KB/ROI, negligible next to the matmuls).  A windowed VMEM
-    # out_spec let XLA choose the buffer's home — and on hardware it
-    # greedily packed pallas outputs into scoped vmem until the
-    # kernel's own stack allocation failed, at ANY limit (16 MiB
-    # default and the raised 32 MiB both died with the same ~156 KiB
-    # overshoot, round 5).  Explicit HBM removes the choice.
-    # The DMA must move full tile-aligned extents: the buffer's W dim
-    # is padded to the sublane tile (7→8, 14→16) and the pad columns
-    # ride along (sliced off at the XLA level after the call).
-    pad_w = res_ref.shape[2] - out_size
-    if pad_w:
-        pooled = jnp.pad(pooled, ((0, 0), (0, pad_w), (0, 0)))
-    res_ref[0] = pooled.astype(res_ref.dtype)
-    copy = pltpu.make_async_copy(res_ref, out_ref.at[pl.ds(r, 1)],
-                                 out_sem)
-    copy.start()
-    copy.wait()
+    def column(cx, issued):
+        cxp = _pooled_weights(x_start, bin_w, j_idx,
+                              x_idx + (cx * STRIP_W).astype(f32), sampling)
+
+        def add_strip(ry, issued):
+            slot = issued % 2
+            more_down = ry + 1 < ny
+            more_across = cx + 1 < nx
+            mine = more_down | more_across
+
+            @pl.when(mine | (r + 1 < n))
+            def _():
+                start_read(jnp.where(mine, r, nxt),
+                           jnp.where(more_down, ry + 1, 0),
+                           jnp.where(more_down, cx,
+                                     jnp.where(more_across, cx + 1, 0)),
+                           1 - slot)
+
+            ryp = _pooled_weights(y_start, bin_h, i_idx,
+                                  y_idx + (ry * STRIP_H).astype(f32),
+                                  sampling)
+            wait_read(slot)
+            acc_ref[...] += _strip_product(
+                ryp * cxp, strips[slot].reshape(px, c))
+            return issued + 1
+
+        return jax.lax.fori_loop(0, ny, add_strip, issued)
+
+    state[0] = jax.lax.fori_loop(0, nx, column, state[0])
+
+    # The output buffer is pinned to HBM and written by explicit DMA.
+    # A windowed VMEM out_spec let XLA choose the buffer's home — and on
+    # hardware it greedily packed pallas outputs into scoped vmem until
+    # the kernel's own stack allocation failed, at ANY limit (round 5).
+    # Explicit HBM removes the choice.
+    def write(op):
+        op(pltpu.make_async_copy(res_ref, out_ref.at[r], out_sem))
+
+    @pl.when(r >= 1)
+    def _():
+        write(lambda d: d.wait())    # the previous ROI's, a ROI ago
+
+    res_ref[...] = acc_ref[...].astype(res_ref.dtype)
+    write(lambda d: d.start())
+
+    @pl.when(r == n - 1)
+    def _():
+        write(lambda d: d.wait())
 
 
 def _bwd_kernel(out_size: int, sampling: int, num_levels: int,
@@ -243,10 +325,10 @@ def _bwd_kernel(out_size: int, sampling: int, num_levels: int,
                 ny_ref, nx_ref,                   # strips down / across
                 ys_ref, xs_ref, bh_ref, bw_ref,   # f32 start/bin size
                 *refs):
-    """Transpose of ``_kernel``, over the ROI's footprint only: the
+    """Transpose of ``_fwd_kernel``, over the same footprint: the
     rows and columns of the level's map that carry a non-zero weight
     are covered by ``ny × nx`` strips of ONE shape
-    ``[STRIP_H, STRIP_W, C]`` (``_bwd_prep``), and each strip of the
+    ``[STRIP_H, STRIP_W, C]`` (``_strip_prep``), and each strip of the
     f32 accumulator is read, updated and written back by DMA.
 
     A strip's update is one MXU product with channels in lanes on both
@@ -290,7 +372,7 @@ def _bwd_kernel(out_size: int, sampling: int, num_levels: int,
     lvl = lvl_ref[r]
     b = b_ref[r]
     ya = ya_ref[r]
-    xa = xa_ref[r] * _BWD_ALIGN             # see _kernel: provable align
+    xa = xa_ref[r] * _BWD_ALIGN             # see _fwd_kernel: provable
     ny = ny_ref[r]
     nx = nx_ref[r]
 
@@ -342,10 +424,7 @@ def _bwd_kernel(out_size: int, sampling: int, num_levels: int,
         q = jax.lax.broadcasted_iota(jnp.int32, shape, 2).astype(f32)
         i_idx = jnp.floor((q + 0.5) / out_size)
         bin_idx = i_idx if t_axis == 0 else q - i_idx * out_size
-        return sum(
-            _tap_weights(start, binsz, bin_idx * sampling + a, t_idx,
-                         sampling)
-            for a in range(sampling)) / sampling
+        return _pooled_weights(start, binsz, bin_idx, t_idx, sampling)
 
     g = g_ref[0].astype(f32)                                # [oo, C]
     c = g.shape[-1]
@@ -393,7 +472,9 @@ def _bwd_kernel(out_size: int, sampling: int, num_levels: int,
 
 def _prep(feats, rois, strides, out_size, min_level, align):
     """Host-side (traced) index/weight prep: tile-fit level assignment,
-    clamped tile origins, tile-local sample-start coordinates."""
+    the clamped origin of the ``TILE``-wide window that bounds the ROI,
+    window-local sample-start coordinates — the source of
+    ``_strip_prep``'s cover."""
     from eksml_tpu.ops.roi_align import assign_fpn_levels_tile_fit
 
     b, n = rois.shape[0], rois.shape[1]
@@ -431,17 +512,22 @@ def _prep(feats, rois, strides, out_size, min_level, align):
             ys, xs, bin_h, bin_w)
 
 
-def _bwd_prep(feats, rois, strides, out_size, min_level, align):
-    """The backward's twin of ``_prep``: same levels, sample starts and
-    bin sizes, but instead of one tile origin the cover of the ROI's
-    FOOTPRINT by ``ny × nx`` strips of ``[STRIP_H, STRIP_W]``.  A row
-    carries a non-zero weight from ``floor(y1 − 0.5)`` (the first
-    sample's upper tap) to ``floor(y2 − 0.5) + 1`` (the last sample's
-    lower tap), columns likewise; the column origin is rounded down to
-    ``_BWD_ALIGN`` and shipped as a block count, and both origins are
+def _strip_prep(feats, rois, strides, out_size, min_level, align,
+                w_align):
+    """``_prep``'s levels, sample starts and bin sizes, and the cover of
+    the ROI's FOOTPRINT by ``ny × nx`` strips of ``[STRIP_H, STRIP_W]``
+    — the one cover both kernels walk.  A row carries a non-zero weight
+    from ``floor(y1 − 0.5)`` (the first sample's upper tap) to
+    ``floor(y2 − 0.5) + 1`` (the last sample's lower tap), columns
+    likewise; the column origin is rounded down to ``w_align`` (the
+    sublane tile of what the strips hold: ``sublane_align(dtype)`` for
+    the forward's features, ``_BWD_ALIGN`` for the backward's float32
+    accumulators) and shipped as a block count, and both origins are
     pulled in so the last strip ends inside the padded map.  The
-    tile-fit level assignment bounds the footprint by the tile, so at
-    most ``TILE/STRIP_H × TILE/STRIP_W`` strips are ever needed."""
+    tile-fit level assignment bounds the footprint by the tile less
+    ``tile_margin`` (46 usable pixels + 3 of taps + up to 15 of
+    round-down in bf16), so at most ``TILE/STRIP_H × TILE/STRIP_W``
+    strips are ever needed."""
     levels, batch_idx, y0, x0, ys, xs, bin_h, bin_w = _prep(
         feats, rois, strides, out_size, min_level, align)
     shapes = jnp.asarray([f.shape[1:3] for f in feats], jnp.int32)[levels]
@@ -459,29 +545,45 @@ def _bwd_prep(feats, rois, strides, out_size, min_level, align):
 
     ya, ny, ys = cover(y0, ys, bin_h, shapes[:, 0], STRIP_H, 1)
     xa, nx, xs = cover(x0 * align, xs, bin_w, shapes[:, 1], STRIP_W,
-                       _BWD_ALIGN)
-    return (levels, batch_idx, ya, xa // _BWD_ALIGN, ny, nx,
+                       w_align)
+    return (levels, batch_idx, ya, xa // w_align, ny, nx,
             ys, xs, bin_h, bin_w)
 
 
-def bwd_tile_share(feats, rois, strides, out_size: int = 7,
-                   min_level: int = 2):
-    """Mean over ROIs of the accumulator bytes the backward kernel
-    moves (its strips) over the bytes of a ``TILE × TILE`` tile, from
-    ``_bwd_prep``'s own strip counts.  A pure function of the ROIs and
-    the levels' shapes: 1.0 when every ROI fills its tile."""
+def _tile_share(feats, rois, strides, out_size, min_level, w_align):
+    """Mean over ROIs of the pixels a kernel moves (its strips, W origin
+    aligned to ``w_align``) over those of a ``TILE × TILE`` tile, from
+    ``_strip_prep``'s own strip counts.  A pure function of the ROIs
+    and the levels' shapes: 1.0 when every ROI fills its tile."""
     align = sublane_align(feats[0].dtype)
     padded = jax.eval_shape(lambda fs: _pad_levels(fs, align), list(feats))
-    prep = _bwd_prep(padded, rois, strides, out_size, min_level, align)
+    prep = _strip_prep(padded, rois, strides, out_size, min_level, align,
+                       w_align)
     strips = (prep[4] * prep[5]).astype(jnp.float32)
     return strips.mean() * (STRIP_H * STRIP_W / (TILE * TILE))
 
 
+def fwd_tile_share(feats, rois, strides, out_size: int = 7,
+                   min_level: int = 2):
+    """``_tile_share`` of the forward kernel's strips, read in the
+    features' dtype."""
+    return _tile_share(feats, rois, strides, out_size, min_level,
+                       sublane_align(feats[0].dtype))
+
+
+def bwd_tile_share(feats, rois, strides, out_size: int = 7,
+                   min_level: int = 2):
+    """``_tile_share`` of the backward kernel's strips of its float32
+    accumulators."""
+    return _tile_share(feats, rois, strides, out_size, min_level,
+                       _BWD_ALIGN)
+
+
 def _pad_levels(feats, align):
     """Zero-pad each level's spatial dims to ≥ TILE, and W additionally
-    to a multiple of ``align`` so the clamped tile x-origin stays
-    sublane-aligned (zero padding IS ROIAlign's out-of-image semantics,
-    so this is free correctness)."""
+    to a multiple of ``align`` so a strip origin pulled in from the
+    right edge stays sublane-aligned (zero padding IS ROIAlign's
+    out-of-image semantics, so this is free correctness)."""
     out = []
     for f in feats:
         _, h, w, _ = f.shape
@@ -497,8 +599,8 @@ def _pad_levels(feats, align):
 # keep a pallas output (or operand) resident in vmem, the WHOLE buffer
 # counts against the kernel's stack, not just the windowed block.  The
 # round-5 hardware compile proved it: the mask head's full
-# bf16[128,14,14,256] output (12.85 MiB) + the double-buffered tile
-# scratch overflowed the limit by 160 KiB and Mosaic rejected the
+# bf16[128,14,14,256] output (12.85 MiB) + the kernel's scratch of the
+# time overflowed the limit by 160 KiB and Mosaic rejected the
 # kernel.  The fix is static shape arithmetic, not a probe: chunk the
 # ROI grid so worst-case (full output vmem-resident + scratch +
 # headroom) provably fits.
@@ -511,11 +613,10 @@ def _roi_chunk(n_total: int, out_size: int, c: int, dtype,
     (chunk's output + kernel scratch) fits the scoped-vmem budget
     (module-level ``_VMEM_STACK_BUDGET``, read at call time so tests
     can monkeypatch it).
-    The per-ROI size uses the TILED output layout (W padded to the
-    sublane tile, 7→8 / 14→16) — the buffer XLA would actually pack."""
-    esize = jnp.dtype(dtype).itemsize
-    out_pad = out_size + (-out_size % 8)
-    per_roi = out_size * out_pad * c * esize
+    The per-ROI size is that of a ``[(i j), C]`` block in its TILED
+    layout (``_block_rows``: 49→64 / 196→208) — the forward's output and
+    the backward's incoming gradient as XLA would actually pack them."""
+    per_roi = _block_rows(out_size) * c * jnp.dtype(dtype).itemsize
     room = max(_VMEM_STACK_BUDGET - scratch_bytes, per_roi)
     bound = max(room // per_roi, 1)
     if n_total <= bound:
@@ -523,42 +624,54 @@ def _roi_chunk(n_total: int, out_size: int, c: int, dtype,
     return max(d for d in range(1, int(bound) + 1) if n_total % d == 0)
 
 
+def _fwd_scratch_bytes(out_size: int, c: int, dtype) -> int:
+    """The forward kernel's own vmem: two strips and the result block
+    in the features' dtype, the float32 block, and what one strip's
+    product holds at once (its weight terms, ``STRIP_H·STRIP_W`` lanes
+    wide, and the ``[3·rows, C]`` product of the stacked terms)."""
+    esize = jnp.dtype(dtype).itemsize
+    rows, px = _block_rows(out_size), STRIP_H * STRIP_W
+    return (2 * px * c * esize + rows * c * esize + rows * c * 4
+            + 3 * rows * (px + c) * 4)
+
+
 def _pallas_forward(feats, rois, strides, out_size, sampling, min_level,
                     interpret):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    align = sublane_align(feats[0].dtype)
+    dtype = feats[0].dtype
+    align = sublane_align(dtype)
     feats = _pad_levels(feats, align)
     b, n = rois.shape[0], rois.shape[1]
     c = feats[0].shape[-1]
-    scalars = _prep(feats, rois, strides, out_size, min_level, align)
+    scalars = _strip_prep(feats, rois, strides, out_size, min_level, align,
+                          align)
     num_levels = len(feats)
-    kern = functools.partial(_kernel, out_size, sampling, num_levels,
+    kern = functools.partial(_fwd_kernel, out_size, sampling, num_levels,
                              align)
-
-    esize = jnp.dtype(feats[0].dtype).itemsize
-    out_pad = out_size + (-out_size % 8)
-    # tile double-buffer + the per-ROI result staging block
-    scratch_bytes = (2 * TILE * TILE + out_size * out_pad) * c * esize
-    chunk = _roi_chunk(b * n, out_size, c, feats[0].dtype, scratch_bytes)
+    rows = _block_rows(out_size)
+    chunk = _roi_chunk(b * n, out_size, c, dtype,
+                       _fwd_scratch_bytes(out_size, c, dtype))
 
     def call(chunk_scalars, n_rois):
         grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=8,
+            num_scalar_prefetch=10,
             grid=(n_rois,),
             # unwindowed HBM refs: Mosaic DMAs explicitly, and the
             # buffers stay off the kernel's scoped-vmem stack UNLESS
             # XLA elects to place them there — chunking bounds each
             # call's output so that even a packed chunk fits the
-            # raised 32 MiB limit alongside the tile scratch
+            # raised 32 MiB limit alongside the kernel's scratch
             in_specs=[pl.BlockSpec(memory_space=pltpu.HBM)] * num_levels,
             out_specs=pl.BlockSpec(memory_space=pltpu.HBM),
             scratch_shapes=[
-                pltpu.VMEM((2, TILE, TILE, c), feats[0].dtype),
+                pltpu.VMEM((2, STRIP_H, STRIP_W, c), dtype),
                 pltpu.SemaphoreType.DMA((2,)),
-                pltpu.VMEM((1, out_size, out_pad, c), feats[0].dtype),
+                pltpu.VMEM((rows, c), jnp.float32),
+                pltpu.VMEM((rows, c), dtype),
                 pltpu.SemaphoreType.DMA(()),
+                pltpu.SMEM((1,), jnp.int32),
             ],
         )
         # no output coloring here: with ROI chunking bounding the
@@ -569,8 +682,7 @@ def _pallas_forward(feats, rois, strides, out_size, sampling, min_level,
         return pl.pallas_call(
             kern,
             grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct(
-                (n_rois, out_size, out_pad, c), feats[0].dtype),
+            out_shape=jax.ShapeDtypeStruct((n_rois, rows, c), dtype),
             compiler_params=_compiler_params(),
             interpret=interpret,
             name="roi_align_fwd",
@@ -582,7 +694,10 @@ def _pallas_forward(feats, rois, strides, out_size, sampling, min_level,
         out = jnp.concatenate([
             call(tuple(s[i:i + chunk] for s in scalars), chunk)
             for i in range(0, b * n, chunk)], axis=0)
-    return out[:, :, :out_size, :].reshape(b, n, out_size, out_size, c)
+    # the pad rows of the (i j) axis ride along to HBM (the DMA moves
+    # full tile-aligned extents) and are sliced off here
+    return out[:, :out_size * out_size, :].reshape(
+        b, n, out_size, out_size, c)
 
 
 def _hbm_out(shape, dtype):
@@ -657,7 +772,8 @@ def _pallas_backward(feats, rois, g, strides, out_size, sampling,
     padded = _pad_levels(feats, align)
     b, n = rois.shape[0], rois.shape[1]
     c = padded[0].shape[-1]
-    scalars = _bwd_prep(padded, rois, strides, out_size, min_level, align)
+    scalars = _strip_prep(padded, rois, strides, out_size, min_level,
+                          align, _BWD_ALIGN)
     num_levels = len(padded)
     kern = functools.partial(_bwd_kernel, out_size, sampling,
                              num_levels)

@@ -26,7 +26,8 @@ Three formulations of those semantics, and when each runs:
   cannot take the Pallas kernel (no TPU, or a canvas beyond the tile's
   coverage); the oracle of the kernel's and ``resample_masks``' tests.
 - the Pallas kernel (``dispatch_roi_align`` on a TPU): the same feature
-  maps, one tile DMA and two MXU matmuls per ROI.
+  maps, strips of the ROI's footprint by DMA and one MXU product a
+  strip.
 - ``resample_masks`` (two batched float32 matmuls, ``Ry · M · Cxᵀ``):
   one single-channel map per ROI, i.e. ``MaskRCNN._mask_targets``'
   ground-truth masks, on every backend.  With one channel a gather
@@ -319,16 +320,20 @@ def _per_shard(kernel, num_levels: int):
 @jax.named_scope("roi_align")
 def dispatch_roi_align(feats, rois, strides, out_size,
                        sampling_ratio: int = 2, min_level: int = 2):
-    """Backend dispatch: the Pallas kernel on real TPU (assigned-level
-    tile DMA + separable MXU matmuls, ops/pallas/roi_align_kernel.py),
-    the XLA gather formulation elsewhere.
+    """Backend dispatch: the Pallas kernel on real TPU (the ROI's
+    footprint on its assigned level read in 16×16 strips, one MXU
+    product of pooled bilinear weights a strip, forward and backward:
+    ops/pallas/roi_align_kernel.py), the XLA gather formulation
+    elsewhere.
 
-    Correctness guard: an ROI wider than the kernel's coverage at the
-    COARSEST level — ``(TILE - margin) × strides[-1]`` px, ~1696 (f32)
-    / ~1440 (bf16) with TILE=64 — would be silently truncated by the
-    tile.  ROI extent is bounded by the (padded) image extent, so when
-    the feature maps imply images beyond that bound, dispatch takes the
-    XLA path."""
+    Correctness guard: the strips cover at most a ``TILE``-wide window,
+    which the tile-fit level assignment keeps every ROI inside by
+    moving it to a coarser level; an ROI wider than that window's
+    coverage at the COARSEST level — ``(TILE - margin) × strides[-1]``
+    px, ~1696 (f32) / ~1440 (bf16) with TILE=64 — has no level left and
+    would be silently truncated.  ROI extent is bounded by the (padded)
+    image extent, so when the feature maps imply images beyond that
+    bound, dispatch takes the XLA path."""
     from eksml_tpu.ops.pallas import (TILE,
                                       pallas_batched_multilevel_roi_align,
                                       pallas_roi_align_supported,

@@ -28,7 +28,7 @@ def test_the_default_is_the_detector_built_as_before(fresh_config):
     assert cfg.MODEL.NAME == "maskrcnn" and cfg.TRAIN.OPTIMIZER == "sgd"
     assert models.build_model(cfg) == models.MaskRCNN.from_config(cfg)
     assert models.counter_spans(cfg) == {
-        "roi_bwd_strips": ("roi_bwd_tile_share",)}
+        "roi_bwd_strips": ("roi_bwd_tile_share", "roi_fwd_tile_share")}
     assert models.pretrained_loader(cfg) is None
     fresh_config.freeze(False)
     fresh_config.BACKBONE.WEIGHTS = "/no/such/file.npz"
@@ -189,9 +189,10 @@ def test_main_trains_the_sequence_model_as_it_trains_the_detector(tmp_path):
 
 def test_the_detectors_step_carries_its_counter_to_a_span(tmp_path):
     """The detector's side of the counter seam: the step's output holds
-    ``roi_bwd_tile_share`` (a share of the 64 x 64 tile, outside the
-    summed loss), the log rows carry it, and at log steps it rides a
-    zero-length ``roi_bwd_strips`` span, as ``moe_route`` does."""
+    ``roi_bwd_tile_share`` and ``roi_fwd_tile_share`` (shares of the
+    64 x 64 tile, outside the summed loss), the log rows carry them,
+    and at log steps they ride a zero-length ``roi_bwd_strips`` span,
+    as ``moe_route`` does."""
     logdir = str(tmp_path / "run")
     env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=ROOT)
     out = subprocess.run(
@@ -211,6 +212,9 @@ def test_the_detectors_step_carries_its_counter_to_a_span(tmp_path):
     for r in logged:
         # 16 x 16 strips: a sixteenth of the tile at the least
         assert 1 / 16 <= r["roi_bwd_tile_share"] <= 1.0
+        # the forward's W origin is no finer than the backward's (equal
+        # in float32, as here): it never covers less
+        assert r["roi_bwd_tile_share"] <= r["roi_fwd_tile_share"] <= 1.0
         assert r["total_loss"] == pytest.approx(sum(
             v for k, v in r.items()
             if k.endswith("_loss") and k != "total_loss"), rel=1e-5)
@@ -218,5 +222,6 @@ def test_the_detectors_step_carries_its_counter_to_a_span(tmp_path):
         events = json.load(f)["traceEvents"]
     strips = [e for e in events if e["name"] == "roi_bwd_strips"]
     assert [e["args"]["step"] for e in strips] == [2, 4]
-    assert [e["args"]["roi_bwd_tile_share"] for e in strips] == [
-        r["roi_bwd_tile_share"] for r in logged]
+    for key in ("roi_bwd_tile_share", "roi_fwd_tile_share"):
+        assert [e["args"][key] for e in strips] == [
+            r[key] for r in logged]

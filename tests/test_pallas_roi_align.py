@@ -1,9 +1,10 @@
-"""Pallas ROIAlign kernel vs the XLA reference formulation.
+"""Pallas ROIAlign kernels vs the XLA reference formulation.
 
 Runs in interpret mode (no TPU in the test environment, SURVEY.md §4);
-the kernel's math — assigned-level tile DMA + separable two-tap
-bilinear matmuls — must agree with ops.roi_align's gather formulation
-everywhere the tile covers the ROI.
+the kernels' math — strips of the assigned level's map over the ROI's
+footprint, one product of pooled two-tap bilinear weights a strip —
+must agree with ops.roi_align's gather formulation everywhere the
+tile-fit level assignment puts the ROI.
 """
 
 import numpy as np
@@ -279,31 +280,32 @@ def test_kernel_runs_once_per_batch_shard_on_a_mesh():
 
 def test_vmem_chunk_math_covers_observed_hardware_oom():
     """The round-5 hardware compile failure: mask head, 128 ROIs x
-    14x14 x 256ch bf16 — full output 12.85 MiB + 4 MiB scratch
-    overflowed Mosaic's 16 MiB scoped-vmem stack by 160 KiB.  The
-    static chunk bound must split exactly this case (and the box
-    head's equivalent) under budget."""
+    14x14 x 256ch bf16 — the full output (12.85 MiB) beside the
+    kernel's scratch overflowed Mosaic's 16 MiB scoped-vmem stack by
+    160 KiB.  The static chunk bound must split exactly this case (and
+    the box head's equivalent) under budget."""
     from eksml_tpu.ops.pallas.roi_align_kernel import (
-        TILE, _VMEM_STACK_BUDGET, _roi_chunk)
+        _VMEM_STACK_BUDGET, _block_rows, _fwd_scratch_bytes, _roi_chunk)
 
     for n, out in ((128, 14), (512, 7)):  # mask head / box head
         c, esize = 256, 2  # bf16
-        scratch = 2 * TILE * TILE * c * esize
+        scratch = _fwd_scratch_bytes(out, c, jnp.bfloat16)
         chunk = _roi_chunk(n, out, c, jnp.bfloat16, scratch)
         assert n % chunk == 0
         assert chunk < n  # the failing case MUST be split
-        out_pad = out + (-out % 8)
-        assert (chunk * out * out_pad * c * esize + scratch
+        assert (chunk * _block_rows(out) * c * esize + scratch
                 <= _VMEM_STACK_BUDGET)
     # small calls stay single-shot (no perf regression on probes)
     assert _roi_chunk(6, 7, 32, jnp.float32,
-                      2 * TILE * TILE * 32 * 4) == 6
+                      _fwd_scratch_bytes(7, 32, jnp.float32)) == 6
 
 
 def test_forward_chunked_matches_unchunked(monkeypatch):
     """Force the chunked forward path (budget shrunk so n=12 splits)
     and assert bit-identical output vs the single-call path — each
-    ROI's computation is independent, so chunking must be invisible."""
+    ROI's computation is independent, so chunking must be invisible
+    (the strip pipeline and the result slots start afresh in each
+    call)."""
     from eksml_tpu.ops.pallas import roi_align_kernel as rk
 
     rng = np.random.RandomState(7)
@@ -311,10 +313,11 @@ def test_forward_chunked_matches_unchunked(monkeypatch):
     rois = _rois(rng, 2, 6)
     whole = rk._pallas_forward(feats, rois, STRIDES, 7, 2, 2, True)
     esize = 4
-    scratch = 2 * rk.TILE * rk.TILE * 32 * esize
+    scratch = rk._fwd_scratch_bytes(7, 32, jnp.float32)
+    # per-ROI size uses the TILED layout ((i j) 49→64)
+    assert rk._block_rows(7) == 64 and rk._block_rows(14) == 208
     monkeypatch.setattr(rk, "_VMEM_STACK_BUDGET",
-                        scratch + 4 * 7 * 8 * 32 * esize)
-    # per-ROI size uses the TILED layout (W 7→8)
+                        scratch + 4 * 64 * 32 * esize)
     assert rk._roi_chunk(12, 7, 32, jnp.float32, scratch) == 4
     chunked = rk._pallas_forward(feats, rois, STRIDES, 7, 2, 2, True)
     np.testing.assert_array_equal(np.asarray(whole), np.asarray(chunked))
@@ -333,8 +336,7 @@ def test_backward_chunked_matches_unchunked(monkeypatch):
     esize = 4
     scratch = rk._bwd_scratch_bytes(7, 32)
     monkeypatch.setattr(rk, "_VMEM_STACK_BUDGET",
-                        scratch + 2 * 7 * 8 * 32 * esize)
-    # per-ROI size uses the TILED layout (W 7→8)
+                        scratch + 2 * rk._block_rows(7) * 32 * esize)
     assert rk._roi_chunk(6, 7, 32, jnp.float32, scratch) == 2
     chunked = rk._pallas_backward(feats, rois, g, STRIDES, 7, 2, 2, True)
     for w, ch in zip(whole, chunked):
@@ -342,24 +344,29 @@ def test_backward_chunked_matches_unchunked(monkeypatch):
                                    atol=1e-5)
 
 
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
                          ids=["bf16", "f32"])
 @pytest.mark.parametrize("n,out", [(4 * 512, 7), (4 * 128, 14)],
                          ids=["box", "mask"])
-def test_backward_chunk_fits_at_the_cells_shapes(n, out, dtype):
-    """The backward's chunk of the incoming gradient at the benchmark
-    cells' own shapes (batch 4, 512 box ROIs at 7x7 / 128 mask ROIs at
-    14x14, C 256): it divides the grid, and a chunk of the gradient
-    beside the kernel's own scratch stays under the stack budget, which
-    stays under the limit every kernel declares."""
+def test_chunk_fits_at_the_cells_shapes(n, out, dtype, direction):
+    """The forward's chunk of its output and the backward's chunk of the
+    incoming gradient at the benchmark cells' own shapes (batch 4, 512
+    box ROIs at 7x7 / 128 mask ROIs at 14x14, C 256): it divides the
+    grid, and a chunk beside the kernel's own scratch stays under the
+    stack budget, which stays under the limit every kernel declares."""
     from eksml_tpu.ops.pallas import roi_align_kernel as rk
 
     c = 256
-    scratch = rk._bwd_scratch_bytes(out, c)
+    scratch = (rk._fwd_scratch_bytes(out, c, dtype) if direction == "fwd"
+               else rk._bwd_scratch_bytes(out, c))
     chunk = rk._roi_chunk(n, out, c, dtype, scratch)
     assert n % chunk == 0
-    out_pad = out + (-out % 8)
-    held = chunk * out * out_pad * c * jnp.dtype(dtype).itemsize
+    # eight box-head calls and eight mask-head calls a step in bf16, as
+    # the cells' traces have had them since PR 25
+    if dtype == jnp.bfloat16:
+        assert chunk == {7: 256, 14: 64}[out]
+    held = chunk * rk._block_rows(out) * c * jnp.dtype(dtype).itemsize
     assert held + scratch <= rk._VMEM_STACK_BUDGET
     assert rk._VMEM_STACK_BUDGET < rk._SCOPED_VMEM_KIB * 1024
 
@@ -419,14 +426,39 @@ def _assert_grads_close(gp, gr, dtype):
                                        atol=2e-4)
 
 
-def _strip_counts(feats, rois, strides):
+def _w_align(feats, direction):
+    """W alignment of a direction's strips: the features' sublane tile
+    for the forward's reads, 8 for the backward's f32 accumulators."""
+    from eksml_tpu.ops.pallas import roi_align_kernel as rk
+
+    return (rk.sublane_align(feats[0].dtype) if direction == "fwd"
+            else rk._BWD_ALIGN)
+
+
+def _strip_counts(feats, rois, strides, direction="bwd"):
     from eksml_tpu.ops.pallas import roi_align_kernel as rk
 
     align = rk.sublane_align(feats[0].dtype)
-    prep = rk._bwd_prep(rk._pad_levels(feats, align), rois, strides, 7, 2,
-                        align)
+    prep = rk._strip_prep(rk._pad_levels(feats, align), rois, strides, 7,
+                          2, align, _w_align(feats, direction))
     return set(zip(np.asarray(prep[4]).tolist(),
                    np.asarray(prep[5]).tolist()))
+
+
+def _every_extent_rois(rng, img, sizes, origin_step, last_off):
+    """One ROI per (rows, columns) pair of ``sizes`` (feature pixels at
+    stride 8).  The first column sits just past a multiple of
+    ``origin_step`` — or ``last_off`` past one for the widest extent,
+    where only the origin's round-down makes the fourth strip."""
+    boxes = [[20.3, 30.6, 20.3 + 40, 30.6 + 40]]      # P2, one strip
+    for hf in sizes:
+        for wf in sizes:
+            off = last_off if wf == sizes[-1] else 0.6
+            cells = (img // 8 - wf - int(off) - 1) // origin_step
+            x1 = 8 * (origin_step * rng.randint(0, max(cells, 1)) + off)
+            y1 = 8 * (rng.randint(0, img // 8 - hf - 1) + 0.6)
+            boxes.append([x1, y1, x1 + 8 * wf, y1 + 8 * hf])
+    return jnp.asarray([boxes], jnp.float32)
 
 
 @pytest.mark.parametrize("out_size", [7, 14])
@@ -443,24 +475,33 @@ def test_bwd_every_strip_count_matches_xla_vjp(dtype, out_size):
     rng = np.random.RandomState(12)
     feats = tuple(jnp.asarray(rng.randn(1, img // s, img // s, 8), dtype)
                   for s in strides)
-    top = 50 if dtype == jnp.float32 else 44
-    sizes = [10, 20, 36, top]
-    boxes = [[20.3, 30.6, 20.3 + 40, 30.6 + 40]]      # P2, one strip
-    for hf in sizes:
-        for wf in sizes:
-            # the first column on a multiple of 8 (the strips' origin),
-            # or 7 past one where only the round-down makes 4 strips
-            off = 7.6 if wf == 44 else 0.6
-            x1 = 8 * (8 * rng.randint(0, (img // 8 - wf) // 8) + off)
-            y1 = 8 * (rng.randint(0, img // 8 - hf - 1) + 0.6)
-            boxes.append([x1, y1, x1 + 8 * wf, y1 + 8 * hf])
-    rois = jnp.asarray([boxes], jnp.float32)
+    # the first column on a multiple of 8 (the strips' origin), or 7
+    # past one where only the round-down makes 4 strips
+    if dtype == jnp.float32:
+        rois = _every_extent_rois(rng, img, [10, 20, 36, 50], 8, 0.6)
+    else:
+        rois = _every_extent_rois(rng, img, [10, 20, 36, 44], 8, 7.6)
     counts = _strip_counts(feats, rois, strides)
     downs = (1, 2, 3, 4) if dtype == jnp.float32 else (1, 2, 3)
     assert counts >= {(ny, nx) for ny in downs for nx in (1, 2, 3, 4)}
     assert max(counts) <= (TILE // rk.STRIP_H, TILE // rk.STRIP_W)
     _assert_grads_close(*_grads_vs_xla(feats, rois, strides, out_size),
                         dtype)
+
+
+_BORDER_ROIS = [
+    [100.2, 200.7, 140.9, 236.1],     # P2
+    [60.5, 300.1, 220.3, 420.8],      # P3
+    [101.0, 90.0, 400.0, 390.0],      # P4
+    [10.0, 12.0, 500.0, 505.0],       # P5
+    [0.0, 0.0, 30.0, 22.0],           # top-left corner
+    [470.0, 0.0, 511.0, 41.0],        # top-right
+    [0.0, 480.0, 40.0, 511.9],        # bottom-left
+    [300.0, 330.0, 511.5, 511.5],     # bottom-right, P3
+    [-14.0, -9.0, 31.0, 28.0],        # past the top-left
+    [490.0, 495.0, 540.0, 530.0],     # past the bottom-right
+    [0.0, 0.0, 0.0, 0.0],             # a padded (empty) proposal
+]
 
 
 @pytest.mark.parametrize("out_size", [7, 14])
@@ -471,19 +512,7 @@ def test_bwd_every_level_and_border_matches_xla_vjp(out_size):
     padded map; rows outside it get nothing, as zero padding wants)."""
     rng = np.random.RandomState(13)
     feats = _feats(rng, img=512, c=8)
-    rois = jnp.asarray([[
-        [100.2, 200.7, 140.9, 236.1],     # P2
-        [60.5, 300.1, 220.3, 420.8],      # P3
-        [101.0, 90.0, 400.0, 390.0],      # P4
-        [10.0, 12.0, 500.0, 505.0],       # P5
-        [0.0, 0.0, 30.0, 22.0],           # top-left corner
-        [470.0, 0.0, 511.0, 41.0],        # top-right
-        [0.0, 480.0, 40.0, 511.9],        # bottom-left
-        [300.0, 330.0, 511.5, 511.5],     # bottom-right, P3
-        [-14.0, -9.0, 31.0, 28.0],        # past the top-left
-        [490.0, 495.0, 540.0, 530.0],     # past the bottom-right
-        [0.0, 0.0, 0.0, 0.0],             # a padded (empty) proposal
-    ]], jnp.float32)
+    rois = jnp.asarray([_BORDER_ROIS], jnp.float32)
     gp, gr = _grads_vs_xla(feats, rois, STRIDES, out_size)
     assert all(float(jnp.abs(g).max()) > 0 for g in gr)  # every level
     _assert_grads_close(gp, gr, jnp.float32)
@@ -507,10 +536,11 @@ def test_bwd_overlapping_rois_accumulate(kind):
                         jnp.float32)
 
 
-def _share_in_numpy(feats, rois, strides):
-    """bwd_tile_share recomputed from the ROIs: rows floor(y1 − 0.5) ..
+def _share_in_numpy(feats, rois, strides, w_align=8):
+    """A tile share recomputed from the ROIs: rows floor(y1 − 0.5) ..
     floor(y2 − 0.5) + 1 on the ROI's level, columns likewise from an
-    origin rounded down to 8, in 16 × 16 strips, over the tile."""
+    origin rounded down to ``w_align``, in 16 × 16 strips, over the
+    tile."""
     from eksml_tpu.ops.pallas import roi_align_kernel as rk
     from eksml_tpu.ops.roi_align import assign_fpn_levels_tile_fit
 
@@ -523,7 +553,7 @@ def _share_in_numpy(feats, rois, strides):
         per_axis = []
         for lo, hi, size, align in (
                 (y1, y2, feats[lv].shape[1], 1),
-                (x1, x2, feats[lv].shape[2], 8)):
+                (x1, x2, feats[lv].shape[2], w_align)):
             size = max(size, TILE)
             first = int(np.clip(np.floor(lo / strides[lv] - 0.5), 0,
                                 size - 1))
@@ -535,8 +565,13 @@ def _share_in_numpy(feats, rois, strides):
     return total / len(flat) * 16 * 16 / (TILE * TILE)
 
 
+@pytest.mark.parametrize("direction", ["bwd", "fwd"])
 @pytest.mark.parametrize("case", ["random", "tile_filling", "p2_32px"])
-def test_bwd_tile_share(case):
+def test_tile_share(case, direction):
+    """``bwd_tile_share`` / ``fwd_tile_share`` (the step's
+    ``roi_bwd_tile_share`` / ``roi_fwd_tile_share`` counters) against
+    numpy.  The bf16 cases differ by the W origin: 16 for the forward's
+    strips, 8 for the backward's."""
     from eksml_tpu.ops.pallas import roi_align_kernel as rk
 
     assert (rk.STRIP_H, rk.STRIP_W) == (16, 16)   # _share_in_numpy's
@@ -560,15 +595,216 @@ def test_bwd_tile_share(case):
         xy = rng.uniform(0, 1, (2, 64, 2)) * (1343 - side)
         rois = jnp.asarray(np.concatenate([xy, xy + side], -1),
                            jnp.float32)
-    share = float(rk.bwd_tile_share(feats, rois, strides))
-    assert share == pytest.approx(_share_in_numpy(feats, rois, strides))
+    fn = rk.fwd_tile_share if direction == "fwd" else rk.bwd_tile_share
+    share = float(fn(feats, rois, strides))
+    w_align = _w_align(feats, direction)
+    assert w_align == (8 if direction == "bwd" or case == "tile_filling"
+                       else 16)
+    assert share == pytest.approx(
+        _share_in_numpy(feats, rois, strides, w_align))
     if case == "tile_filling":
         assert share == 1.0
     elif case == "p2_32px":
-        # 8 px + taps: one strip down, one or two across (8-aligned)
+        # 8 px + taps: one strip down, one or two across
         assert 1 / 16 <= share < 0.2
     else:
         assert 0.1 < share < 0.9
+    if direction == "fwd" and case != "tile_filling":
+        # a coarser origin never covers less
+        assert share >= float(rk.bwd_tile_share(feats, rois, strides))
+
+
+# ------------------------------------------------- the forward's strips
+
+
+def _fwd_vs_xla(feats, rois, strides, out_size):
+    """(kernel output, XLA formulation in float32 at the kernel's
+    levels), both float32 arrays."""
+    from eksml_tpu.ops.pallas import roi_align_kernel as rk
+    from eksml_tpu.ops.roi_align import assign_fpn_levels_tile_fit
+
+    b, n = rois.shape[:2]
+    levels = assign_fpn_levels_tile_fit(
+        rois.reshape(b * n, 4), strides, len(feats), TILE,
+        align=rk.sublane_align(feats[0].dtype)).reshape(b, n)
+    out = pallas_batched_multilevel_roi_align(feats, rois, strides,
+                                              out_size, 2, 2, True)
+    assert out.dtype == feats[0].dtype
+    assert out.shape == (b, n, out_size, out_size, feats[0].shape[-1])
+    ref = batched_multilevel_roi_align(
+        tuple(f.astype(jnp.float32) for f in feats), rois, strides,
+        out_size, 2, 2, levels=levels)
+    return np.asarray(out, np.float32), np.asarray(ref)
+
+
+def _assert_fwd_close(out, ref, dtype):
+    if dtype == jnp.bfloat16:   # output rounding, 2^-9 relative
+        np.testing.assert_allclose(out, ref, atol=0.02, rtol=0.01)
+    else:
+        np.testing.assert_allclose(out, ref, atol=1e-4)
+
+
+@pytest.mark.parametrize("out_size", [7, 14])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_fwd_every_strip_count_matches_xla(dtype, out_size):
+    """The forward over every strip count from 1 × 1 to the
+    tile-filling 4 × 4 (bf16: the tile fit stops an ROI at 46 pixels,
+    so 3 strips down; across, the origin of 16 makes the fourth), on a
+    two-level pyramid whose coarsest level takes every large ROI."""
+    from eksml_tpu.ops.pallas import roi_align_kernel as rk
+
+    dtype = jnp.dtype(dtype)
+    strides, img = (4, 8), 512
+    rng = np.random.RandomState(16)
+    feats = tuple(jnp.asarray(rng.randn(1, img // s, img // s, 8), dtype)
+                  for s in strides)
+    if dtype == jnp.float32:
+        rois = _every_extent_rois(rng, img, [10, 20, 36, 50], 8, 0.6)
+        downs = (1, 2, 3, 4)
+    else:
+        rois = _every_extent_rois(rng, img, [10, 20, 36, 44], 16, 15.6)
+        downs = (1, 2, 3)
+    counts = _strip_counts(feats, rois, strides, "fwd")
+    assert counts >= {(ny, nx) for ny in downs for nx in (1, 2, 3, 4)}
+    assert max(counts) <= (TILE // rk.STRIP_H, TILE // rk.STRIP_W)
+    _assert_fwd_close(*_fwd_vs_xla(feats, rois, strides, out_size), dtype)
+
+
+
+
+@pytest.mark.parametrize("out_size", [7, 14])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_fwd_every_level_and_border_matches_xla(dtype, out_size):
+    """ROIs on each of the four levels (P4 and P5 of a 512 px canvas
+    are 32 and 16 wide: a level smaller than the tile, P5 no wider than
+    one strip; both zero-extended), hugging each border and reaching
+    past it: the strips are pulled inside the padded map, and what lies
+    outside the map reads as zeros."""
+    dtype = jnp.dtype(dtype)
+    rng = np.random.RandomState(17)
+    feats = tuple(f.astype(dtype) for f in _feats(rng, img=512, c=8))
+    assert feats[-1].shape[2] <= 16
+    rois = jnp.asarray([_BORDER_ROIS], jnp.float32)
+    out, ref = _fwd_vs_xla(feats, rois, STRIDES, out_size)
+    assert all(np.abs(ref[0, k]).max() > 0 for k in range(10))
+    _assert_fwd_close(out, ref, dtype)
+
+
+def test_fwd_bf16_cover_is_aligned_and_never_needs_a_fifth_strip():
+    """The bf16 forward's strips start on a multiple of 16 (Mosaic
+    wants a provably aligned W origin for a packed dtype) and 4 × 16
+    columns always hold the footprint: an ROI at the tile fit's bound
+    (46 usable pixels on its level) whose first column sits 15 past a
+    multiple of 16 spans 15 + 46 + 3 = 64.  Checked against the
+    footprint recomputed in numpy, so the cover's own clip to four
+    strips is shown never to cut."""
+    from eksml_tpu.ops.pallas import roi_align_kernel as rk
+    from eksml_tpu.ops.roi_align import assign_fpn_levels_tile_fit
+
+    rng = np.random.RandomState(18)
+    img = 1344
+    feats = tuple(jnp.zeros((1, img // s, img // s, 8), jnp.bfloat16)
+                  for s in STRIDES)
+    align = rk.sublane_align(jnp.bfloat16)
+    assert align == 16 and TILE - rk.tile_margin(jnp.bfloat16) == 46
+    # random ROIs of every size, and for each level the widest and the
+    # tallest ROI the fit leaves there, first column 15 past a multiple
+    # of 16 at that level's stride
+    side = np.exp(rng.uniform(np.log(8), np.log(1300), (400, 2)))
+    xy = rng.uniform(0, 1, (400, 2)) * (img - 1 - side)
+    boxes = np.concatenate([xy, xy + side], -1).tolist()
+    for stride in STRIDES:
+        long = 46 * stride * 0.999
+        for k in range(1, 4):
+            x1 = (16 * k + 15.5) * stride
+            if x1 + long < img:
+                boxes.append([x1, 40.0, x1 + long, 40.0 + long / 3])
+                boxes.append([x1, 40.0, x1 + long / 3, 40.0 + long])
+    rois = jnp.asarray([boxes], jnp.float32)
+    padded = rk._pad_levels(feats, align)
+    levels, _, ya, xa, ny, nx, ys, xs, bh, bw = (
+        np.asarray(v) for v in rk._strip_prep(
+            padded, rois, STRIDES, 7, 2, align, align))
+    fit = np.asarray(assign_fpn_levels_tile_fit(
+        rois[0], STRIDES, 4, TILE, align=align))
+    np.testing.assert_array_equal(levels, fit)
+    assert set(levels.tolist()) == {0, 1, 2, 3}
+    assert nx.max() == 4 and ny.max() <= 4
+    flat = np.asarray(boxes, np.float64)
+    for k, lv in enumerate(levels):
+        h, w = padded[lv].shape[1:3]
+        assert w % 16 == 0
+        x0 = xa[k] * 16                       # shipped as a block count
+        for lo, hi, origin, count, size in (
+                (flat[k, 1], flat[k, 3], ya[k], ny[k], h),
+                (flat[k, 0], flat[k, 2], x0, nx[k], w)):
+            first = int(np.clip(np.floor(lo / STRIDES[lv] - 0.5), 0,
+                                size - 1))
+            last = int(np.clip(np.floor(hi / STRIDES[lv] - 0.5) + 1,
+                               first, size - 1))
+            assert 0 <= origin <= first, (k, boxes[k])
+            assert last < origin + 16 * count <= size, (k, boxes[k])
+        # strip-local sample starts are relative to the strips' origin
+        assert ys[k] == pytest.approx(
+            flat[k, 1] / STRIDES[lv] - 0.5 - ya[k], abs=1e-3)
+        assert xs[k] == pytest.approx(
+            flat[k, 0] / STRIDES[lv] - 0.5 - x0, abs=1e-3)
+
+
+@pytest.mark.parametrize("out_size", [7, 14])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_fwd_and_bwd_are_transposes(dtype, out_size):
+    """``⟨fwd(x), g⟩ = ⟨x, bwd(g)⟩`` on shared ROIs: the two kernels
+    contract the same pooled weights over covers that differ only by
+    the W origin.  Float32 features: to float32 rounding.  bf16
+    features: the kernels' float32 blocks agree the same way, but each
+    side then rounds its OUTPUT to bf16, so the bound is 2^-9 of the
+    terms' mass."""
+    from eksml_tpu.ops.pallas import roi_align_kernel as rk
+
+    dtype = jnp.dtype(dtype)
+    rng = np.random.RandomState(19)
+    feats = tuple(f.astype(dtype) for f in _feats(rng, b=2, img=512, c=8))
+    rois = jnp.concatenate(
+        [_rois(rng, 2, 10, img=512),
+         jnp.asarray([_BORDER_ROIS[:4]] * 2, jnp.float32)], 1)
+    g = jnp.asarray(rng.randn(2, 14, out_size, out_size, 8), dtype)
+    out = rk._pallas_forward(feats, rois, STRIDES, out_size, 2, 2, True)
+    grads = rk._pallas_backward(feats, rois, g, STRIDES, out_size, 2, 2,
+                                True)
+    f64 = np.float64
+    lhs = float((np.asarray(out, f64) * np.asarray(g, f64)).sum())
+    rhs = sum(float((np.asarray(x, f64) * np.asarray(d, f64)).sum())
+              for x, d in zip(feats, grads))
+    mass = float(np.abs(np.asarray(out, f64) * np.asarray(g, f64)).sum())
+    tol = 2.0 ** -9 if dtype == jnp.bfloat16 else 1e-5
+    assert abs(lhs - rhs) <= tol * mass, (lhs, rhs, mass)
+
+
+def test_strip_product_bf16_equals_highest_on_the_upcast_strip():
+    """The three-term split of the weights against what it replaces:
+    ``Precision.HIGHEST`` on the strip cast to float32 (here the CPU's
+    float32 product, which is at least that).  Equal to float32
+    rounding of the sum — far inside bf16's 2^-9, which a single bf16
+    pass over the weights would show."""
+    from eksml_tpu.ops.pallas import roi_align_kernel as rk
+
+    rng = np.random.RandomState(20)
+    w = jnp.asarray(rng.rand(64, 256), jnp.float32)
+    strip = jnp.asarray(rng.randn(256, 32), jnp.bfloat16)
+    got = np.asarray(rk._strip_product(w, strip), np.float64)
+    want = (np.asarray(w, np.float64)
+            @ np.asarray(strip.astype(jnp.float32), np.float64))
+    scale = np.abs(np.asarray(w, np.float64)) @ np.abs(
+        np.asarray(strip.astype(jnp.float32), np.float64))
+    assert (np.abs(got - want) <= 2e-6 * scale).all()
+    one_pass = np.asarray(jnp.dot(
+        w.astype(jnp.bfloat16), strip,
+        preferred_element_type=jnp.float32), np.float64)
+    assert np.abs(one_pass - want).max() > 50 * np.abs(got - want).max()
+    # float32 strips: HIGHEST itself
+    got32 = np.asarray(rk._strip_product(w, strip.astype(jnp.float32)))
+    np.testing.assert_allclose(got32, want, rtol=1e-5, atol=1e-5)
 
 
 def _pallas_eqn_compiler_params(fn, *args):
