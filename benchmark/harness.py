@@ -56,6 +56,10 @@ class Cell:
         return self.config["model"]
 
     @property
+    def feature_itemsize(self):
+        return 2 if self.config["precision"] == "bfloat16" else 4
+
+    @property
     def hyper(self):
         return dict(self.config["optimizer"],
                     global_batch=self.config["batch_per_chip"] * self.chips)
@@ -208,11 +212,24 @@ class Capture:
     leaves the step loop alone: with the Python tracer on (the
     default) the first traced step waited 0.75 s for the host, and
     ``start_trace`` itself holds the host for 2.6 s (my chip runs,
-    PR 24).  So the profiler starts (host tracer only) while the
-    device is drained and nothing is timed, a fit of
-    ``trace_steps`` steps runs, and the profiler stops once the device
-    has drained again; the reduction's window is first whole step to
-    last, which leaves the fit's ramp-up out."""
+    PR 24).  So the profiler starts while the device is drained and
+    nothing is timed, a fit of ``trace_steps`` steps runs, and the
+    profiler stops once the device has drained again; the reduction's
+    window is first whole step to last, which leaves the fit's ramp-up
+    out.
+
+    The device's planes alone: the host tracer is off too (PR 32).
+    XLA's host-side re-tiling of a batch on its way to the chip
+    (``pjrt-tpu-tasks``: ``XlaLinearize`` of the uint8
+    ``[4, 1344, 1344, 3]`` pixels) records one host event for every
+    inner block it moves, at any host tracer level above 0: ~17 MB of
+    events a batch (a 366 MB trace for 20 steps), and some traced
+    stretches had two such transfers in flight take 3.0-3.4 s each, the
+    chip idle for 2.7-3.0 s behind them, where the plain window's
+    never starves (my chip runs, PR 32).  What is read here (busy time, the
+    window, seconds by instruction) is the device's; with the host
+    untraced an idle gap is labelled by where it lies among the step's
+    executions (``trace_reduce.summarize``), not by what the host did."""
 
     def __init__(self, logdir: str):
         import jax
@@ -220,13 +237,8 @@ class Capture:
         self.dir = os.path.join(logdir, "bench_profile")
         options = jax.profiler.ProfileOptions()
         options.python_tracer_level = 0
+        options.host_tracer_level = 0
         jax.profiler.start_trace(self.dir, profiler_options=options)
-
-    @staticmethod
-    def annotate():
-        import jax
-
-        return jax.profiler.TraceAnnotation(trace_reduce.FEED_ANNOTATION)
 
     def stop(self):
         """Path of the ``.xplane.pb``, or None."""
@@ -416,8 +428,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
         if trace:
             capture = Capture(logdir)
             state = trainer.fit(
-                traffic.feed(gen, count=int(cell.workload["trace_steps"]),
-                             annotate=capture.annotate),
+                traffic.feed(gen, count=int(cell.workload["trace_steps"])),
                 BIG_STEPS, start_step=step0 + steps, state=state,
                 data_health=loader.health)
             jax.block_until_ready(state)
@@ -447,8 +458,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
                 images_per_sec_per_chip=ips_chip, window_s=window_s,
                 window_steps=steps,
                 traced_steps=summary.steps if summary else 0,
-                feature_itemsize=(2 if cell.config["precision"]
-                                  == "bfloat16" else 4),
+                feature_itemsize=cell.feature_itemsize,
                 peak=peaks, spans=spans, trace=summary, memory_stats=mem)
             metrics = read_per_layer(cell, ctx)
             if summary is not None and summary.window_s:

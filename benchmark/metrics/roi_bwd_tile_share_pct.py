@@ -4,7 +4,9 @@ percentage (100 = every ROI takes every strip of its tile; the kernel before
 PR 29 moved the whole tile by construction).  Mean over the window's
 ``roi_bwd_strips`` spans, which carry the step's
 ``roi_bwd_tile_share`` (box and mask ROIs, weighted by count) as
-``args`` at log steps."""
+``args`` at log steps.  Reported in both detector cells since PR 32
+(``frcnn-r50-train-1344-b4`` alone before: an accepted test's hand-made
+context lacked the span the mask cell's program writes too)."""
 
 
 def read(ctx):

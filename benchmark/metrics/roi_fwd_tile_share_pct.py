@@ -5,7 +5,9 @@ PR 31 read the whole tile by construction).  Mean over the window's
 ``roi_bwd_strips`` spans, which carry the step's
 ``roi_fwd_tile_share`` (box and mask ROIs, weighted by count) as
 ``args`` at log steps, beside the backward's ``roi_bwd_tile_share``.  A
-program without the counter (PR 30's and older) gives nothing."""
+program without the counter (PR 30's and older) gives nothing.
+Registered by PR 32, in both detector cells (the mask cell's ROIs are
+the box head's 7 x 7 and the mask head's 14 x 14 together)."""
 
 
 def read(ctx):

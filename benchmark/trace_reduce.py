@@ -123,9 +123,21 @@ def host_label(gap, host_events):
     return min(covering)[1] if covering else None
 
 
+def place_label(gap, runs):
+    """Where the idle ``gap`` (start_ns, end_ns) lies among ``runs``,
+    the (start_ns, end_ns) of the step program's executions: all a
+    trace of the device alone can say of it.  Between two executions
+    the chip waits for the host (a dispatch, a batch, the log step's
+    sync); inside one, the program itself leaves it idle."""
+    s, e = gap
+    if any(rs <= s and e <= re for rs, re in runs):
+        return "inside a step's execution"
+    return "between two steps' executions"
+
+
 def summarize(device_events: dict, host_feed=(), custom_calls=frozenset(),
               modules=None, top_gaps=10, step_module=None,
-              host_events=()) -> TraceSummary:
+              host_events=(), host_traced=True) -> TraceSummary:
     """``device_events``: {device: [(instruction, start_ns, dur_ns)]}
     of the ops line; ``modules``: {device: [(name, start_ns, dur_ns)]}
     of whole program executions; ``host_feed``: [(start_ns, end_ns)]
@@ -134,7 +146,10 @@ def summarize(device_events: dict, host_feed=(), custom_calls=frozenset(),
     other program (a log step's small jits) are no steps and do not
     set the window.  ``host_events``: [(thread:event, start_ns,
     end_ns)] of the host's longer events, which label the idle gaps
-    further.  The window runs from the first whole execution's
+    further.  ``host_traced`` False: the trace holds the device's
+    planes alone (``harness.Capture`` since PR 32), so nothing is said
+    of the host and a gap is labelled by ``place_label``.  The window
+    runs from the first whole execution's
     start to the last one's end (first op to last op where no
     execution was recorded), and only operations inside it count."""
     out = TraceSummary(devices=len(device_events))
@@ -168,12 +183,17 @@ def summarize(device_events: dict, host_feed=(), custom_calls=frozenset(),
         all_gaps += gaps(ivals, lo, hi)
     labelled = {}
     for s, e in sorted(all_gaps, key=lambda g: g[0] - g[1])[:top_gaps]:
-        fed = sum(max(0, min(e, fe) - max(s, fs)) for fs, fe in host_feed)
-        label = ("feed waits in the loader's next"
-                 if fed >= 0.5 * (e - s) else "host not in the loader's next")
-        doing = host_label((s, e), host_events)
-        if doing:
-            label += f", host in {doing}"
+        if host_traced:
+            fed = sum(max(0, min(e, fe) - max(s, fs))
+                      for fs, fe in host_feed)
+            label = ("feed waits in the loader's next" if fed >= 0.5 * (e - s)
+                     else "host not in the loader's next")
+            doing = host_label((s, e), host_events)
+            if doing:
+                label += f", host in {doing}"
+        else:
+            label = (place_label((s, e), runs) if runs
+                     else "idle, host not traced")
         labelled.setdefault(label, []).append((e - s) / 1e9)
     out.idle_gaps = sorted(
         ([f"{k} (longest of {len(v)})", max(v)] for k, v in labelled.items()),
@@ -215,6 +235,9 @@ def read_xplane(path):
 
 def summarize_file(path, custom_calls=frozenset(),
                    step_module=None) -> TraceSummary:
+    """A trace with no host event at all was taken with the host
+    tracer off."""
     ops, modules, feed, host = read_xplane(path)
     return summarize(ops, feed, custom_calls, modules,
-                     step_module=step_module, host_events=host)
+                     step_module=step_module, host_events=host,
+                     host_traced=bool(feed or host))

@@ -94,23 +94,17 @@ def generate(params: dict, seed: int) -> list:
     return records
 
 
-def feed(gen, count=None, deadline=None, clock=None, on_batch=None,
-         annotate=None):
+def feed(gen, count=None, deadline=None, clock=None, on_batch=None):
     """Batches from the loader's generator ``gen``: ``count`` of them,
     or until ``clock() >= deadline``.  ``on_batch`` sees every host
-    batch handed on; ``annotate`` (a context-manager factory) wraps
-    each wait in the loader's ``next``."""
+    batch handed on."""
     n = 0
     while True:
         if count is not None and n >= count:
             return
         if deadline is not None and clock() >= deadline:
             return
-        if annotate is not None:
-            with annotate():
-                batch = next(gen)
-        else:
-            batch = next(gen)
+        batch = next(gen)
         if on_batch is not None:
             on_batch(batch)
         n += 1

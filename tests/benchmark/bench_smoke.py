@@ -48,7 +48,8 @@ SMOKE_TRAFFIC = {
 # what decides `correct` at smoke widths in float32 on the CPU: both
 # sides compute in float32, so the gaps are reduction order and the
 # rare discrete flip of a threshold; see test_reference_parity.py
-SMOKE_LIMITS = {"rpn_loss_step1": 1e-5, "loss_step1": 1e-4,
+SMOKE_LIMITS = {"rpn_loss_step1": 1e-5, "rpn_box_loss_step1": 1e-5,
+                "loss_step1": 1e-4,
                 "loss_step2": 1e-3, "loss_step3": 1e-3,
                 "first_grad_worst_leaf": 1e-3, "first_grad_median_leaf": 1e-4,
                 "delta3_worst_leaf": 1e-2, "delta3_median_leaf": 1e-3,
@@ -85,3 +86,63 @@ def smoke_cell(mask: bool, chips: int = 1, limits=None,
                         workload=workload, task=real.task,
                         end_to_end=real.end_to_end,
                         per_layer=real.per_layer)
+
+
+# ------------------------------------------------- examples of the readers
+
+# what a context holds when no example says otherwise: nothing to read
+EMPTY_CONTEXT = {"images_per_sec_per_chip": 0.0, "window_s": 0.0,
+                 "window_steps": 0, "traced_steps": 0}
+
+
+def load_example(name, root=ROOT):
+    """``benchmark/metrics/examples/<name>.json``: what the reader of
+    the per-layer metric ``name`` reads (``spans``, ``trace`` with its
+    ``op_seconds``, ``memory_stats``, fields of the ``context``, a
+    ``peak`` row) and the ``value`` it must then return."""
+    import json
+
+    with open(os.path.join(root, "benchmark", "metrics", "examples",
+                           f"{name}.json")) as f:
+        return json.load(f)
+
+
+def example_context(cell, names=None, root=ROOT, peak=None):
+    """A ``harness.TraceContext`` for ``cell`` that holds what the
+    examples of ``names`` hold (default: every per-layer metric the
+    cell reports), so a manifest that has grown brings its own data.
+    Spans and memory rows are put together (one that two examples share
+    stays once); of a context field, a trace field or an instruction's
+    seconds that two examples give, the first in ``names``' order
+    stands.  ``peak`` takes the place of the examples' own rows.  With
+    no names the context is empty: every reader returns None from it."""
+    from benchmark import harness, trace_reduce
+
+    if names is None:
+        names = [m["name"] for m in cell.per_layer]
+    fields = {"images_per_step": cell.hyper["global_batch"],
+              "feature_itemsize": cell.feature_itemsize}
+    trace, op_seconds, spans, memory = {}, {}, [], []
+    peaks = {} if peak is None else dict(peak)
+    for name in names:
+        example = load_example(name, root)
+        for key, value in example.get("context", {}).items():
+            fields.setdefault(key, value)
+        found = dict(example.get("trace", {}))
+        for instruction, seconds in found.pop("op_seconds", {}).items():
+            op_seconds.setdefault(instruction, seconds)
+        for key, value in found.items():
+            trace.setdefault(key, value)
+        spans += [s for s in example.get("spans", []) if s not in spans]
+        memory += [m for m in example.get("memory_stats", [])
+                   if m not in memory]
+        if peak is None:
+            for key, value in example.get("peak", {}).items():
+                peaks.setdefault(key, value)
+    summary = None
+    if trace or op_seconds:
+        summary = trace_reduce.TraceSummary(**trace, op_seconds=op_seconds)
+    return harness.TraceContext(
+        **dict(EMPTY_CONTEXT, **fields), spec=cell.spec, task=cell.task,
+        chips=cell.chips, peak=peaks, spans=spans, trace=summary,
+        memory_stats=memory)

@@ -21,7 +21,7 @@ def test_int8_control_and_half_batch_fail_the_comparison():
         got, exact, cell.task.extra_numbers)
     exact = run()
     again, _ = numbers(run())
-    assert "rpn_loss_step1" in again
+    assert {"rpn_loss_step1", "rpn_box_loss_step1"} <= set(again)
     assert all(v <= 1e-12 for v in again.values()), again  # deterministic
     limits = cell.workload["limits"]
 
@@ -35,6 +35,8 @@ def test_int8_control_and_half_batch_fail_the_comparison():
     ok, rows = compare.judge(half, limits)
     assert not ok, rows
     assert half["loss_step1"] > 10 * limits["loss_step1"], half
+    # the number that holds this fault at the cells' own size
+    assert half["rpn_box_loss_step1"] > 0.02, half
 
 
 def test_judge_needs_every_limited_number():

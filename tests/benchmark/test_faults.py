@@ -35,7 +35,8 @@ def half_batch_left_out(trainer):
 
 @pytest.mark.parametrize("fault,fails", [
     (state_unchanged, {"first_grad_worst_leaf", "delta3_worst_leaf"}),
-    (half_batch_left_out, {"loss_step1", "first_grad_worst_leaf"}),
+    (half_batch_left_out, {"loss_step1", "first_grad_worst_leaf",
+                           "rpn_box_loss_step1"}),
 ], ids=["state_unchanged", "half_batch"])
 def test_a_broken_step_reads_not_correct(fault, fails):
     import jax

@@ -185,12 +185,15 @@ def test_the_cells_readers():
     names = {m["name"] for m in cell.per_layer}
     general = {"lm_input_wait_ms", "lm_batch_build_ms", "lm_h2d_prefetch_ms",
                "lm_step_ms_p50", "lm_step_ms_p75", "lm_device_idle_pct"}
-    assert names == general | {
+    # PR 28's fourteen; what later PRs append for this cell reads its
+    # own example (test_examples.py), not this hand-made context
+    fourteen = general | {
         "lm_step_mfu_pct", "lm_tokens_per_sec_per_chip",
         "lm_device_peak_hbm_gb",
         "moe_load_max_over_mean", "moe_pairs_per_held_expert",
         "splash_mha_fwd_roofline_pct", "splash_mha_bwd_roofline_pct",
         "moe_ragged_dot_roofline_pct"}
+    assert names >= fourteen
     # the general readers' aliases are the readers themselves, under
     # the layer names they carry in the detector cells
     with open(os.path.join(bench_smoke.ROOT, "BENCHMARK.json")) as f:
@@ -230,8 +233,8 @@ def test_the_cells_readers():
     ctx.memory_stats = [{"peak_bytes_reserved": 3_217_801_216,
                          "peak_bytes_in_use": 8_468_550_144}]
     got = harness.read_per_layer(cell, ctx)
-    value = {k: v["value"] for k, v in got.items()}
-    assert set(value) == names
+    value = {k: v["value"] for k, v in got.items() if k in fourteen}
+    assert set(value) == fourteen
     assert value["lm_input_wait_ms"] == pytest.approx(5.0)
     assert value["lm_batch_build_ms"] == pytest.approx(1.5)
     assert value["lm_h2d_prefetch_ms"] == pytest.approx(0.7)
@@ -264,8 +267,8 @@ def test_the_cells_readers():
         cell, [], trace_reduce.TraceSummary(
             devices=1, steps=2, window_s=1.0, busy_s=0.9,
             op_seconds={"fusion.7": 1.0}), traced_steps=2))
-    assert set(bare) == {"lm_step_mfu_pct", "lm_tokens_per_sec_per_chip",
-                         "lm_device_idle_pct"}
+    assert set(bare) & fourteen == {
+        "lm_step_mfu_pct", "lm_tokens_per_sec_per_chip", "lm_device_idle_pct"}
 
 
 def test_extra_numbers_are_the_two_terms_at_step_one():

@@ -4,8 +4,9 @@ the chip run uses (``harness.run_cell`` -> ``compare.numbers``).
 
 Tolerances (``bench_smoke.SMOKE_LIMITS``), each with its reason:
 
-* ``rpn_loss_step1`` 1e-5: the same anchors on both sides, so only
-  float32 summation order is left (seen: under 1e-6).
+* ``rpn_loss_step1``, ``rpn_box_loss_step1`` 1e-5: the same anchors on
+  both sides, so only float32 summation order is left (seen: under
+  1e-6).
 * ``loss_step1`` 1e-4: both sides start from bit-equal weights (checked
   below) and compute in float32; what is left is summation order
   (seen: 1e-7 to 3e-7).

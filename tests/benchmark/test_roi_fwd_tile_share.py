@@ -1,13 +1,8 @@
 """The reader PR 31 brings: ``roi_fwd_tile_share_pct``, the mean of the
 ``roi_bwd_strips`` spans' forward counter, on contexts made by hand,
-and the entry that waits for it in
-``benchmark/metrics/waiting_per_layer.json``.
-
-The entry is not in ``BENCHMARK.json`` yet:
-``test_roi_bwd_tile_share.py::test_the_entry_in_the_manifest`` holds
-``roi_bwd_tile_share_pct`` to the LAST place of ``per_layer``, a new
-entry may only be appended, and that file is not this PR's to edit
-(PERF.md §7 R3 names the edit)."""
+and its entry in ``BENCHMARK.json`` (registered by PR 32; it waited in
+a data file while an accepted test held ``roi_bwd_tile_share_pct`` to
+the last place of ``per_layer``)."""
 
 import dataclasses
 import json
@@ -64,36 +59,32 @@ def test_nothing_without_the_counter(spans):
     the harness leaves the metric out of the line; it never raises."""
     cell, ctx = _context(spans)
     assert roi_fwd_tile_share_pct.read(ctx) is None
-    cell = dataclasses.replace(cell, per_layer=_waiting())
+    cell = dataclasses.replace(cell, per_layer=_entry())
     assert harness.read_per_layer(cell, ctx) == {}
 
 
-def _waiting():
-    with open(os.path.join(ROOT, "benchmark", "metrics",
-                           "waiting_per_layer.json")) as f:
-        return json.load(f)
-
-
-def test_the_waiting_entry_is_one_the_manifest_can_take():
-    """What a ``benchmark`` PR appends to ``per_layer`` as it stands:
-    the keys and the layer's name of the backward's twin, a cell that
-    exists and reports the end-to-end metric it moves, names within the
-    manifest's rules, and a reader the harness finds by that name."""
+def _entry():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         manifest = json.load(f)
-    waiting = _waiting()
-    assert [m["name"] for m in waiting] == ["roi_fwd_tile_share_pct"]
-    entry = waiting[0]
+    return [m for m in manifest["per_layer"]
+            if m["name"] == "roi_fwd_tile_share_pct"]
+
+
+def test_the_entry_in_the_manifest():
+    """Found by its name: the keys, the layer's name and the cells of
+    the backward's twin, a name within the manifest's rules, and a
+    reader the harness finds by that name."""
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        manifest = json.load(f)
+    (entry,) = _entry()
     twin = next(m for m in manifest["per_layer"]
                 if m["name"] == "roi_bwd_tile_share_pct")
     assert entry == dict(twin, name="roi_fwd_tile_share_pct")
     assert re.fullmatch(r"[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}", entry["name"])
-    assert entry["name"] not in {
-        m["name"] for m in manifest["per_layer"] + manifest["end_to_end"]}
-    assert set(entry["workloads"]) <= {
-        w["name"] for w in manifest["workloads"]}
+    assert set(entry["workloads"]) == {
+        "frcnn-r50-train-1344-b4", "mask-r50-train-1344-b4"}
     cell, ctx = _context([_strips(20, 0.24)])
-    cell = dataclasses.replace(cell, per_layer=waiting)
+    cell = dataclasses.replace(cell, per_layer=[entry])
     assert harness.read_per_layer(cell, ctx) == {
         "roi_fwd_tile_share_pct": {"value": pytest.approx(24.0),
                                    "unit": "%"}}

@@ -170,26 +170,44 @@ def test_the_six_entries_are_registered(manifest):
                                        "frcnn-r50-train-1344-b4"}
         assert m["moves"] == "images_per_sec_per_chip"
     assert len(json.dumps(manifest, indent=1)) < 64 * 1024
-    assert not os.path.exists(os.path.join(
-        bench_smoke.ROOT, "benchmark", "metrics", "proposed_per_layer.json"))
+
+
+def check_entries_are_registered_or_not_in_the_tree(root, manifest):
+    """``benchmark/metrics/`` holds readers and, under ``examples/``,
+    one example for each registered entry: no data file in which
+    entries wait beside the manifest (PRs 25 and 31 each grew one)."""
+    metrics = os.path.join(root, "benchmark", "metrics")
+    parked = [os.path.relpath(os.path.join(d, f), metrics)
+              for d, _, files in os.walk(metrics) for f in files
+              if f.endswith(".json")
+              and os.path.relpath(d, metrics) != "examples"]
+    assert not parked
+    assert sorted(os.listdir(os.path.join(metrics, "examples"))) == sorted(
+        m["name"] + ".json" for m in manifest["per_layer"])
+
+
+def test_entries_are_registered_or_not_in_the_tree(manifest):
+    check_entries_are_registered_or_not_in_the_tree(bench_smoke.ROOT,
+                                                    manifest)
 
 
 def test_every_reader_old_and_new_on_one_context():
-    """What ``test_trace_reduce`` asserts, for all the mask cell's
-    eleven, on a context that holds what each of them reads."""
-    spans = _stamps([250.0] * 44) + [
-        {"name": "data_wait", "dur": 2000.0},
-        {"name": "data_wait", "dur": 4000.0},
-        {"name": "data_wait", "dur": 99000.0},
-        {"name": "batch_build", "dur": 30000.0},
-        {"name": "h2d_prefetch", "dur": 6000.0}]
-    cell, ctx = _context(spans=spans, op_seconds=OP_SECONDS)
+    """What ``test_trace_reduce`` asserts, for every metric the mask
+    cell reports, however many later PRs append, on a context that
+    holds what each of their examples holds
+    (``benchmark/metrics/examples/``)."""
+    cell = bench_smoke.smoke_cell(mask=True)
+    ctx = bench_smoke.example_context(cell, peak=bench_smoke.CPU_PEAK)
     out = harness.read_per_layer(cell, ctx)
     assert set(out) == {m["name"] for m in cell.per_layer} >= set(NEW)
-    assert out["step_ms_p50"] == {"value": 250.0, "unit": "ms"}
     assert out["batch_build_ms"]["unit"] == "ms/batch"
+    # the six, on their own examples alone: whatever spans a later
+    # reader's example brings, these read theirs
+    six = bench_smoke.example_context(cell, NEW, peak=bench_smoke.CPU_PEAK)
+    out = harness.read_per_layer(cell, six)
+    assert set(out) >= set(NEW)
+    assert out["step_ms_p50"] == {"value": 250.0, "unit": "ms"}
+    assert out["batch_build_ms"] == {"value": 30.0, "unit": "ms/batch"}
     # and on a context with nothing in it, none of them
-    _, nothing = _context()
-    nothing.images_per_sec_per_chip = 0.0
-    nothing.memory_stats = []
+    nothing = bench_smoke.example_context(cell, ())
     assert harness.read_per_layer(cell, nothing) == {}

@@ -372,7 +372,8 @@ def test_detections_own_number():
     terms = [{"rpn_cls_loss": 0.5, "rpn_box_loss": 0.25}]
     off = [{"rpn_cls_loss": 0.5, "rpn_box_loss": 0.28}]
     got = detection.extra_numbers({"terms": off}, {"terms": terms})
-    assert got == {"rpn_loss_step1": pytest.approx(0.04)}
+    assert got == {"rpn_loss_step1": pytest.approx(0.04),
+                   "rpn_box_loss_step1": pytest.approx(0.12)}
     assert detection.extra_numbers({"terms": []}, {"terms": terms}) == {}
     values, _ = compare.numbers(
         {"loss": [1.0], "terms": off, "first_trace_norm": {"a": 1.0},
@@ -380,7 +381,37 @@ def test_detections_own_number():
         {"loss": [1.0], "terms": terms, "grad_norm": {"a": 1.0},
          "first_trace_norm": {"a": 1.0}, "delta_norm": {"a": 1.0}},
         detection.extra_numbers)
-    assert list(values)[:2] == ["loss_step1", "rpn_loss_step1"]
+    assert list(values)[:3] == ["loss_step1", "rpn_loss_step1",
+                                "rpn_box_loss_step1"]
+
+
+# ``rpn_box_loss_step1`` at the cells' own size (chip runs of PR 32
+# after the check's refusal; PERF.md section 4): the largest a sound
+# run read over the 43 seeds of both cells (the RPN's first step is the
+# same in both), and the least that half a batch left out read on 11.
+BOX_SOUND_MAX = 0.00483
+BOX_HALF_BATCH_LEAST = 0.0417
+# ``rpn_loss_step1`` read 0.00809 (seed 1126389669, both cells) and
+# 0.00711 (seed 32304) in sound runs, where half a batch reads 0.0080 at
+# the least: no limit lies between, so none is set.
+RPN_SOUND_MAX, RPN_HALF_BATCH_LEAST = 0.00809, 0.0080
+
+
+@pytest.mark.parametrize("name", ["mask-r50-train-1344-b4",
+                                  "frcnn-r50-train-1344-b4"])
+def test_the_detector_cells_hold_the_box_term_between_its_readings(name):
+    limits = harness.load_cell(bench_smoke.ROOT, name).workload["limits"]
+    limit = limits["rpn_box_loss_step1"]
+    assert 3 * BOX_SOUND_MAX < limit < BOX_HALF_BATCH_LEAST / 2
+    # the more of the room above the lower reading
+    assert limit / BOX_SOUND_MAX > BOX_HALF_BATCH_LEAST / limit
+    assert "rpn_loss_step1" not in limits
+    assert RPN_SOUND_MAX > RPN_HALF_BATCH_LEAST
+    sound = {"rpn_box_loss_step1": BOX_SOUND_MAX}
+    half = {"rpn_box_loss_step1": BOX_HALF_BATCH_LEAST}
+    only = {"rpn_box_loss_step1": limit}
+    assert compare.judge(sound, only)[0]
+    assert not compare.judge(half, only)[0]
 
 
 def test_first_moment_is_the_one_trace_of_sgd_momentum():

@@ -83,6 +83,38 @@ def test_idle_gaps_are_labelled_by_what_the_host_was_doing():
     assert trace_reduce.host_label((0, 10), []) is None
 
 
+def test_with_the_host_untraced_a_gap_is_labelled_by_its_place():
+    """``harness.Capture`` traces the device alone (PR 32): nothing is
+    said of the loader or the host; a gap lies between two executions
+    of the step (the host's to fill) or inside one (the program's)."""
+    us = 1000
+    ops = {"d": [("a", 0, 100 * us), ("b", 130 * us, 70 * us),    # step 1
+                 ("a", 3100 * us, 100 * us), ("b", 3200 * us, 100 * us),
+                 ("a", 3310 * us, 90 * us)]}
+    mods = {"d": [("jit_step(1)", 0, 200 * us),
+                  ("jit_step(1)", 3100 * us, 200 * us),
+                  ("jit_step(1)", 3310 * us, 90 * us)]}
+    s = trace_reduce.summarize(ops, (), modules=mods, step_module="jit_step",
+                               host_traced=False)
+    assert s.steps == 3 and s.window_s == pytest.approx(3400e-6)
+    assert s.idle_gaps == [
+        ["between two steps' executions (longest of 2)",
+         pytest.approx(2900e-6)],
+        ["inside a step's execution (longest of 1)", pytest.approx(30e-6)]]
+    assert not any("loader" in label for label, _ in s.idle_gaps)
+    # no executions recorded: a gap is idle time and no more is said
+    bare = trace_reduce.summarize(ops, (), host_traced=False)
+    assert [label for label, _ in bare.idle_gaps] == [
+        "idle, host not traced (longest of 3)"]
+    # the same events with the host traced read as before
+    s = trace_reduce.summarize(ops, [(250 * us, 3050 * us)], modules=mods,
+                               step_module="jit_step")
+    assert s.idle_gaps[0][0].startswith("feed waits in the loader's next")
+    assert trace_reduce.place_label((5, 6), [(0, 10)]).startswith("inside")
+    assert trace_reduce.place_label((5, 12), [(0, 10), (12, 20)]).startswith(
+        "between")
+
+
 def test_window_is_the_whole_executions_where_they_are_recorded():
     us = 1000
     ops = {"d": [("a", 0, 10 * us), ("b", 20 * us, 10 * us),
@@ -146,29 +178,23 @@ def test_fixture_recorded_on_the_chip():
         "fusion.37"
 
 
+FIVE = ("input_wait_ms", "step_mfu_pct", "roi_align_kernels_roofline_pct",
+        "device_idle_pct", "device_peak_hbm_gb")      # PR 24's readers
+
+
 def test_readers_on_hand_made_context():
+    """The context is made from the examples that ship with the readers
+    (``benchmark/metrics/examples/<name>.json``, put together by
+    ``bench_smoke.example_context``), so a manifest that has grown
+    brings what its new readers read."""
     cell = bench_smoke.smoke_cell(mask=True)
-    summary = trace_reduce.TraceSummary(
-        devices=1, steps=5, window_s=2.0, busy_s=1.5, custom_call_s=0.02,
-        custom_call_events=10,
-        op_seconds={"roi_align_fwd.8": 0.004, "roi_align_bwd.3": 0.015,
-                    "roi_align_seed_copy.1": 0.001, "fusion.37": 0.5})
-    spans = [{"name": "data_wait", "dur": 2000.0},
-             {"name": "train_step", "dur": 9.0},
-             {"name": "data_wait", "dur": 4000.0},
-             {"name": "data_wait", "dur": 99000.0},   # ends the iterator
-             {"name": "batch_build", "dur": 30000.0},
-             {"name": "h2d_prefetch", "dur": 6000.0}]
-    # 44 step completions 250 ms apart: enough for both percentiles
-    spans += [{"name": "device_step", "ts": 1e9 + 250e3 * i - 100.0,
-               "dur": 100.0, "args": {"step": 6 + i}} for i in range(45)]
-    ctx = harness.TraceContext(
-        spec=cell.spec, task=cell.task, chips=1, images_per_step=2,
-        images_per_sec_per_chip=10.0, window_s=4.0, window_steps=20,
-        traced_steps=5, feature_itemsize=4, peak=bench_smoke.CPU_PEAK,
-        spans=spans, trace=summary,
-        memory_stats=[{"peak_bytes_in_use": 1.7e9,
-                       "peak_bytes_reserved": 8.1e9}])
+    # PR 24's five and the two of PR 25 this test names, on their own
+    # examples: 3 data_wait spans (the last ends the iterator), busy
+    # 1.5 of 2.0 s, 8.1 GB reserved over 1.7 in use, 10 rows/s, custom
+    # calls 0.02 s in 5 traced steps, 44 completions 250 ms apart
+    ctx = bench_smoke.example_context(
+        cell, FIVE + ("step_ms_p50", "batch_build_ms"),
+        peak=bench_smoke.CPU_PEAK)
     assert device_idle_pct.read(ctx) == pytest.approx(25.0)
     assert input_wait_ms.read(ctx) == pytest.approx(3.0)
     assert device_peak_hbm_gb.read(ctx) == pytest.approx(8.1)
@@ -181,10 +207,13 @@ def test_readers_on_hand_made_context():
     assert roi_align_kernels_roofline_pct.read(ctx) == pytest.approx(
         100 * need * 5 / 0.02)
     out = harness.read_per_layer(cell, ctx)
-    assert set(out) == {m["name"] for m in cell.per_layer}
-    assert len(out) >= 11
     assert out["step_ms_p50"]["value"] == pytest.approx(250.0)
     assert out["batch_build_ms"]["value"] == pytest.approx(30.0)
+    # every metric the cell reports, on the examples of all of them
+    every = bench_smoke.example_context(cell, peak=bench_smoke.CPU_PEAK)
+    out = harness.read_per_layer(cell, every)
+    assert set(out) == {m["name"] for m in cell.per_layer}
+    assert len(out) >= 11
     # nothing traced: the trace's readers return nothing, never 0
     ctx.trace = None
     assert device_idle_pct.read(ctx) is None
