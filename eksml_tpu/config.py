@@ -468,11 +468,12 @@ def _define_defaults() -> None:
 
     # ---- model selection (eksml_tpu/models/__init__.py build_model) --
     # "maskrcnn" = the detector the MODE_*/BACKBONE/FPN/RPN/FRCNN/MRCNN
-    # blocks describe; "joyai_llm_flash" = the sequence model the LM
-    # block describes (models/lm/, imported only when selected)
+    # blocks describe; "joyai_llm_flash" and "ouro" = the two sequence
+    # models the LM block describes (models/lm/, imported only when
+    # selected)
     _C.MODEL.NAME = "maskrcnn"
 
-    # ---- sequence model (models/lm/): JoyAI-LLM-Flash's config.json
+    # ---- sequence models (models/lm/): JoyAI-LLM-Flash's config.json
     # (DeepSeek-V3 family, arXiv:2412.19437), every width as published;
     # NUM_LAYERS, EXPERTS_HELD and VOCAB_ROWS are this chip's share of
     # an expert-parallel deployment (ARCHITECTURE.md "Second engine")
@@ -497,6 +498,16 @@ def _define_defaults() -> None:
     _C.LM.NUM_LAYERS = 5
     _C.LM.NUM_MTP = 1                   # multi-token-prediction modules
     _C.LM.MTP_LOSS_WEIGHT = 0.3         # lambda, DeepSeek-V3 section 4.2
+    # MODEL.NAME=ouro (Ouro-2.6B's config.json, LoopLM arXiv:2510.25741):
+    # plain multi-head attention with q, k and v heads of one width, the
+    # NUM_LAYERS blocks applied UT_STEPS times with the same weights, a
+    # loss and a halting gate per pass, and beta on the entropy of the
+    # gate's exit distribution.  The keys above that name MLA, the
+    # experts or the MTP module are JoyAI's; these three are Ouro's
+    # (finalize_configs holds each set to its model: LM_KEYS_OF)
+    _C.LM.HEAD_DIM = 128
+    _C.LM.UT_STEPS = 4
+    _C.LM.EXIT_ENTROPY_WEIGHT = 0.1
     # (first, count): the contiguous routed experts THIS chip holds; the
     # router still scores all N_ROUTED_EXPERTS and picks 8 a token
     _C.LM.EXPERTS_HELD = (0, 16)
@@ -767,6 +778,30 @@ def _define_defaults() -> None:
 
 _define_defaults()
 
+# the LM keys only one of the two sequence models reads; what is not
+# listed (hidden size, heads, layers, vocabulary rows, ...) both read
+LM_KEYS_OF = {
+    "joyai_llm_flash": (
+        "Q_LORA_RANK", "KV_LORA_RANK", "QK_NOPE_HEAD_DIM",
+        "QK_ROPE_HEAD_DIM", "V_HEAD_DIM", "MOE_INTERMEDIATE_SIZE",
+        "FIRST_K_DENSE", "N_ROUTED_EXPERTS", "NUM_EXPERTS_PER_TOK",
+        "N_SHARED_EXPERTS", "ROUTED_SCALING_FACTOR", "NUM_MTP",
+        "MTP_LOSS_WEIGHT", "EXPERTS_HELD"),
+    "ouro": ("HEAD_DIM", "UT_STEPS", "EXIT_ENTROPY_WEIGHT"),
+}
+_LM_DEFAULTS = _C.LM.to_dict()
+
+
+def _lm_keys_of_the_other_model() -> list:
+    """The ``LM`` keys this run moved from their defaults although the
+    model it builds never reads them."""
+    def plain(value):       # (0, 16) and [0, 16] are one setting
+        return list(value) if isinstance(value, (tuple, list)) else value
+
+    return [f"LM.{key}" for name, keys in LM_KEYS_OF.items()
+            if name != _C.MODEL.NAME for key in keys
+            if plain(getattr(_C.LM, key)) != plain(_LM_DEFAULTS[key])]
+
 
 def finalize_configs(is_training: bool) -> AttrDict:
     """Validate + derive dependent values; returns the frozen config.
@@ -779,6 +814,12 @@ def finalize_configs(is_training: bool) -> AttrDict:
     assert _C.BACKBONE.NORM in ("FreezeBN", "GN"), _C.BACKBONE.NORM
     assert _C.TRAIN.PRECISION in ("float32", "bfloat16"), _C.TRAIN.PRECISION
     assert _C.TRAIN.OPTIMIZER in ("sgd", "adamw"), _C.TRAIN.OPTIMIZER
+    if _C.MODEL.NAME in LM_KEYS_OF:
+        stray = _lm_keys_of_the_other_model()
+        assert not stray, (
+            f"{stray}: set, but MODEL.NAME={_C.MODEL.NAME} does not read "
+            "them (keys of the other sequence model)")
+        assert _C.LM.UT_STEPS >= 1, _C.LM.UT_STEPS
     assert _C.TRAIN.PARAM_DTYPE in ("float32", "bfloat16"), (
         _C.TRAIN.PARAM_DTYPE)
     assert _C.RESILIENCE.DATA.VALIDATE in ("off", "warn", "strict"), (
@@ -900,7 +941,7 @@ SMOKE_OVERRIDES = (
 )
 
 
-# The sequence model at a size the CPU tests compile in seconds (2
+# JoyAI-LLM-Flash at a size the CPU tests compile in seconds (2
 # expert layers after the dense one, 8 experts of which 4 held, hidden
 # 64, S 64, float32); widths are cut HERE only, never in a chip cell.
 LM_TINY_OVERRIDES = (
@@ -912,6 +953,19 @@ LM_TINY_OVERRIDES = (
     "LM.INTERMEDIATE_SIZE=160", "LM.MOE_INTERMEDIATE_SIZE=32",
     "LM.N_ROUTED_EXPERTS=8", "LM.NUM_EXPERTS_PER_TOK=2",
     "LM.NUM_LAYERS=3", "LM.EXPERTS_HELD=(0,4)", "LM.VOCAB_ROWS=96",
+    "LM.SEQ_LEN=64", "LM.ATTENTION_BLOCK=16", "LM.LOSS_CHUNK=32",
+    "LM.DATA.DOC_LEN_MEDIAN=24.0", "LM.DATA.DOC_LEN_CLIP=(4,256)",
+)
+
+
+# Ouro's looped stack at the same tiny size (2 blocks applied 3 times,
+# 4 heads of 16, hidden 64, S 64, float32), for the CPU tests alone.
+OURO_TINY_OVERRIDES = (
+    "MODEL.NAME=ouro", "TRAIN.OPTIMIZER=adamw",
+    "TRAIN.PRECISION=float32", "TRAIN.REMAT=True",
+    "LM.HIDDEN_SIZE=64", "LM.NUM_HEADS=4", "LM.HEAD_DIM=16",
+    "LM.INTERMEDIATE_SIZE=160", "LM.NUM_LAYERS=2", "LM.UT_STEPS=3",
+    "LM.ROPE_THETA=1000000", "LM.INIT_STD=0.02", "LM.VOCAB_ROWS=96",
     "LM.SEQ_LEN=64", "LM.ATTENTION_BLOCK=16", "LM.LOSS_CHUNK=32",
     "LM.DATA.DOC_LEN_MEDIAN=24.0", "LM.DATA.DOC_LEN_CLIP=(4,256)",
 )

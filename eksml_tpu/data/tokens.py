@@ -1,4 +1,4 @@
-"""Packed-token batches for the sequence model, with the detection
+"""Packed-token batches for the sequence models, with the detection
 loader's contract: ``batches(n)`` yields host batches from a producer
 thread through a bounded queue, ``health`` is a ``LoaderHealth``, every
 build is a ``batch_build`` span (``seq``, ``rows``), and the batches go

@@ -18,47 +18,63 @@ from eksml_tpu.models.backbone_loader import load_r50_npz  # noqa: F401
 
 
 # ---- the seam: MODEL.NAME chooses what the Trainer builds ------------
-# The detector's modules are this package's eager imports (above); the
-# sequence model lives in ``models/lm``, imported only when selected, so
-# a detector run imports nothing of it.
+# A lookup by name of three things: the module class (``from_config``),
+# the rule of what decays, and the counters' spans.  The detector's
+# modules are this package's eager imports (above); the sequence models
+# live in ``models/lm``, imported only when selected, so a detector run
+# imports nothing of them.
 
-MODEL_NAMES = ("maskrcnn", "joyai_llm_flash")
+
+def _maskrcnn(cfg):
+    from eksml_tpu.models import mask_rcnn
+
+    return (MaskRCNN, mask_rcnn.decay_mask(cfg.BACKBONE.FREEZE_AT),
+            mask_rcnn.COUNTER_SPANS)
 
 
-def _is_detector(cfg) -> bool:
-    if cfg.MODEL.NAME not in MODEL_NAMES:
+def _joyai_llm_flash(cfg):
+    from eksml_tpu.models.lm import model
+
+    return model.JoyAIFlash, model.decay_mask, model.COUNTER_SPANS
+
+
+def _ouro(cfg):
+    from eksml_tpu.models.lm import ouro
+
+    return (ouro.Ouro, ouro.decay_mask,
+            ouro.counter_spans(cfg.LM.UT_STEPS))
+
+
+_SEAM = {"maskrcnn": _maskrcnn, "joyai_llm_flash": _joyai_llm_flash,
+         "ouro": _ouro}
+MODEL_NAMES = tuple(_SEAM)
+
+
+def _lookup(cfg):
+    """(module class, decay mask, counter spans) of ``MODEL.NAME``."""
+    if cfg.MODEL.NAME not in _SEAM:
         raise ValueError(f"MODEL.NAME={cfg.MODEL.NAME!r}: expected one "
                          f"of {MODEL_NAMES}")
-    return cfg.MODEL.NAME == "maskrcnn"
-
-
-def _lm():
-    from eksml_tpu.models import lm
-
-    return lm
+    return _SEAM[cfg.MODEL.NAME](cfg)
 
 
 def build_model(cfg):
     """The flax module ``Trainer`` trains: ``apply({"params": p}, batch,
     rng)`` -> dict with ``total_loss`` and ``*_loss`` terms."""
-    if _is_detector(cfg):
-        return MaskRCNN.from_config(cfg)
-    return _lm().JoyAIFlash.from_config(cfg)
+    return _lookup(cfg)[0].from_config(cfg)
 
 
 def decay_mask(cfg):
     """``params -> tree of bool``: the leaves weight decay applies to."""
-    if _is_detector(cfg):
-        from eksml_tpu.models import mask_rcnn
-
-        return mask_rcnn.decay_mask(cfg.BACKBONE.FREEZE_AT)
-    return _lm().decay_mask
+    return _lookup(cfg)[1]
 
 
 def pretrained_loader(cfg):
     """``(params, param_sh, replicated) -> params`` that fills in the
-    model's pretrained weights at init, or None where there are none."""
-    if _is_detector(cfg) and cfg.BACKBONE.WEIGHTS:
+    model's pretrained weights at init, or None where there are none
+    (the detector's backbone is the one model that has them)."""
+    _lookup(cfg)
+    if cfg.MODEL.NAME == "maskrcnn" and cfg.BACKBONE.WEIGHTS:
         from functools import partial
 
         from eksml_tpu.models.backbone_loader import load_backbone_into
@@ -71,8 +87,4 @@ def counter_spans(cfg) -> dict:
     """{host span name: keys of the step's metrics it carries at log
     steps}: the model's counters, where the span ring's readers see
     them."""
-    if _is_detector(cfg):
-        from eksml_tpu.models import mask_rcnn
-
-        return dict(mask_rcnn.COUNTER_SPANS)
-    return dict(_lm().COUNTER_SPANS)
+    return dict(_lookup(cfg)[2])

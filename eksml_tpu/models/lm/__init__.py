@@ -1,5 +1,6 @@
-"""The sequence model (JoyAI-LLM-Flash, DeepSeek-V3 family): imported
-only when ``MODEL.NAME`` selects it (``eksml_tpu.models.build_model``).
+"""The two sequence models: JoyAI-LLM-Flash (``model.py``, DeepSeek-V3
+family) and Ouro (``ouro.py``, LoopLM), each imported only when
+``MODEL.NAME`` selects it (``eksml_tpu.models.build_model``).
 """
 
 from eksml_tpu.models.lm.model import (  # noqa: F401

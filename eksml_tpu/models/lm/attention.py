@@ -1,4 +1,4 @@
-"""The causal attention core of the sequence model: softmax(q kT) v
+"""The causal attention core of both sequence models: softmax(q kT) v
 without an S x S array of scores.
 
 Two formulations of the same arithmetic, chosen by the platform

@@ -1,10 +1,12 @@
-"""JoyAI-LLM-Flash (DeepSeek-V3 family, arXiv:2412.19437) as a flax
-module with the training losses: multi-head latent attention in every
-block, a dense SwiGLU layer first, then expert layers (256-way sigmoid
-routing, 8 a token, one shared expert, the chip's held experts), one
-multi-token-prediction module of depth 1, and next-token +
-0.3 x next-next-token cross-entropy over the held slice of the
-vocabulary.
+"""JoyAI-LLM-Flash (DeepSeek-V3 family, arXiv:2412.19437), one of the
+two sequence models of ``models/lm`` (the other: ``ouro.py``, which
+shares this file's ``Matrix``, ``linear``, ``RMSNorm``, ``SwiGLU`` and
+chunked cross-entropy), as a flax module with the training losses:
+multi-head latent attention in every block, a dense SwiGLU layer first,
+then expert layers (256-way sigmoid routing, 8 a token, one shared
+expert, the chip's held experts), one multi-token-prediction module of
+depth 1, and next-token + 0.3 x next-next-token cross-entropy over the
+held slice of the vocabulary.
 
 ``model.apply({"params": p}, batch, rng)`` returns the dict the trainer
 expects (``total_loss`` and ``*_loss`` terms) plus the step's routing
@@ -262,6 +264,24 @@ class JoyAIFlash(nn.Module):
         return losses
 
 
+def _chunk_losses(hc, head_kernel, tc):
+    """-log softmax(hc . head)[tc] of one chunk of positions, float32:
+    the one place a ``[chunk, vocabulary]`` array of logits exists."""
+    logits = jnp.dot(hc, head_kernel, preferred_element_type=jnp.float32)
+    lse = jax.nn.logsumexp(logits, axis=-1)
+    picked = jnp.take_along_axis(logits, tc[:, None], axis=-1)[:, 0]
+    return lse - picked
+
+
+def _chunk_size(n: int, chunk: int) -> int:
+    """``chunk``, cut to ``n`` positions; ``n`` must be a multiple."""
+    chunk = min(chunk, n)
+    if n % chunk:
+        raise ValueError(f"{n} positions are no multiple of the "
+                         f"loss chunk {chunk}")
+    return chunk
+
+
 def chunked_cross_entropy(h, head_kernel, targets, weights, chunk: int):
     """Weighted mean of -log softmax(h . head)[target] over positions,
     ``chunk`` positions at a time: no ``[positions, vocabulary]`` array
@@ -270,18 +290,11 @@ def chunked_cross_entropy(h, head_kernel, targets, weights, chunk: int):
     h, targets, weights = (h.reshape(-1, d), targets.reshape(-1),
                            weights.reshape(-1))
     n = h.shape[0]
-    chunk = min(chunk, n)
-    if n % chunk:
-        raise ValueError(f"{n} positions are no multiple of the "
-                         f"loss chunk {chunk}")
+    chunk = _chunk_size(n, chunk)
 
     @jax.checkpoint
     def one(hc, tc, wc):
-        logits = jnp.dot(hc, head_kernel,
-                         preferred_element_type=jnp.float32)
-        lse = jax.nn.logsumexp(logits, axis=-1)
-        picked = jnp.take_along_axis(logits, tc[:, None], axis=-1)[:, 0]
-        return jnp.sum((lse - picked) * wc)
+        return jnp.sum(_chunk_losses(hc, head_kernel, tc) * wc)
 
     def step(total, xs):
         return total + one(*xs), None
@@ -292,6 +305,23 @@ def chunked_cross_entropy(h, head_kernel, targets, weights, chunk: int):
         (h.reshape(k, chunk, d), targets.reshape(k, chunk),
          weights.reshape(k, chunk)))
     return total / jnp.sum(weights)
+
+
+def chunked_position_losses(h, head_kernel, targets, chunk: int):
+    """-log softmax(h . head)[target] of every position, float32 in
+    ``targets``' shape, ``chunk`` positions at a time under the same
+    rule: what a caller weights position by position (Ouro's exit
+    distribution) without paying for the logits twice."""
+    d = h.shape[-1]
+    n = targets.size
+    chunk = _chunk_size(n, chunk)
+    one = jax.checkpoint(
+        lambda hc, tc: _chunk_losses(hc, head_kernel, tc))
+    _, out = jax.lax.scan(
+        lambda carry, xs: (carry, one(*xs)), None,
+        (h.reshape(n // chunk, chunk, d),
+         targets.reshape(n // chunk, chunk)))
+    return out.reshape(targets.shape)
 
 
 def decay_mask(params):

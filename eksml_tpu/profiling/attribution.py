@@ -182,7 +182,7 @@ SCOPE_RULES: Tuple[Tuple[str, str, bool], ...] = (
     ("box-head", r"(^|[/(])(fastrcnn|cascade\d*)($|[/)])", True),
     ("mask-head", r"(^|[/(])maskrcnn($|[/)])", True),
     ("mask-targets", r"(^|[/(])mask_targets($|[/)])", False),
-    # the sequence model's scopes (models/lm/)
+    # JoyAI-LLM-Flash's scopes (models/lm/model.py)
     ("mla-proj", r"(^|[/(])mla($|[/)])", True),
     ("mla-core", r"(^|[/(])mla_core($|[/)])", True),
     ("moe-route", r"(^|[/(])moe_route($|[/)])", False),
@@ -193,6 +193,12 @@ SCOPE_RULES: Tuple[Tuple[str, str, bool], ...] = (
     ("dense-mlp", r"(^|[/(])dense_mlp($|[/)])", True),
     ("mtp", r"(^|[/(])mtp($|[/)])", True),
     ("lm-loss", r"(^|[/(])lm_loss($|[/)])", False),
+    # the looped model's scopes (models/lm/ouro.py)
+    ("loop-attn", r"(^|[/(])loop_attn($|[/)])", True),
+    ("loop-attn-core", r"(^|[/(])loop_attn_core($|[/)])", True),
+    ("loop-mlp", r"(^|[/(])loop_mlp($|[/)])", True),
+    ("loop-head", r"(^|[/(])loop_head($|[/)])", True),
+    ("loop-exit", r"(^|[/(])loop_exit($|[/)])", False),
 )
 _SCOPE_RULES_C = tuple((comp, re.compile(pat), bwd)
                        for comp, pat, bwd in SCOPE_RULES)

@@ -243,6 +243,33 @@ def test_lm_attention_core_compiles_for_v5e(one_chip, monkeypatch):
                      "splash_mha_dkv_no_residuals"}, names
 
 
+def test_looped_attention_core_compiles_for_v5e(one_chip, monkeypatch):
+    """The same kernel and the same block sizes at the looped model's
+    widths: 2 rows x 4096 positions, 16 heads, q, k and v all 128 wide,
+    bf16 (``_splash_kernel``'s one choice serves both lm models: PERF.md
+    section 6, PR 33).  The kernels keep the names the ``loop_`` roofline
+    readers look for."""
+    from eksml_tpu.models.lm import attention
+
+    monkeypatch.setattr(attention.jax, "default_backend", lambda: "tpu")
+    attention._splash_kernel.cache_clear()
+    s = jax.ShapeDtypeStruct((2, 4096, 16, 128), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def loss(q, k, v):
+        with jax.named_scope("loop_attn_core"):
+            o = attention.causal_attention(q, k, v, 512)
+        return o.astype(jnp.float32).sum()
+
+    try:
+        compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            s, s, s).compile()
+    finally:
+        attention._splash_kernel.cache_clear()
+    assert set(_kernel_names(compiled)) == {
+        "splash_mha_fwd_residuals", "splash_mha_dkv_no_residuals"}
+
+
 def test_lm_grouped_product_compiles_for_v5e(one_chip):
     """The expert layer's routed part at the cell's size: 8192 tokens x
     8 pairs through 16 held experts of 2048 x 768.  XLA lowers
