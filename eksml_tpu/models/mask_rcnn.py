@@ -42,7 +42,8 @@ from eksml_tpu.ops.roi_align import dispatch_roi_align, resample_masks
 # what leaves the step beside the losses, and the host span that
 # carries it at log steps (train.Trainer.fit)
 COUNTER_SPANS = {"roi_bwd_strips": ("roi_bwd_tile_share",
-                                    "roi_fwd_tile_share")}
+                                    "roi_fwd_tile_share"),
+                 "rpn_targets": ("rpn_fg_rows",)}
 
 
 class MaskRCNN(nn.Module):
@@ -229,12 +230,13 @@ class MaskRCNN(nn.Module):
             labels, matched = match_anchors(
                 anchors_cat, gt_boxes, gt_valid,
                 self.rpn_pos_thresh, self.rpn_neg_thresh, gt_crowd=crowd)
-            fg, bg = sample_anchors(labels, r, self.rpn_batch_per_im,
-                                    self.rpn_fg_ratio)
-            return rpn_losses(logits, deltas, anchors_cat, labels, matched,
-                              gt_boxes, fg, bg)
+            fg, bg, fg_idx, fg_take = sample_anchors(
+                labels, r, self.rpn_batch_per_im, self.rpn_fg_ratio)
+            return (*rpn_losses(logits, deltas, anchors_cat, labels, matched,
+                                gt_boxes, fg, bg, fg_idx, fg_take),
+                    fg_take.sum())
 
-        rpn_cls, rpn_box = jax.vmap(rpn_one)(
+        rpn_cls, rpn_box, rpn_fg_rows = jax.vmap(rpn_one)(
             logits_cat, deltas_cat, batch["gt_boxes"], batch["gt_valid"],
             gt_crowd, rngs[:, 0])
 
@@ -327,6 +329,9 @@ class MaskRCNN(nn.Module):
                 share(feats[:4], r, self.anchor_strides[:4], o)
                 * r.shape[1] for r, o in counted)
                 / sum(r.shape[1] for r, _ in counted))
+        # how full the box term's int(batch_per_im * fg_ratio) slots an
+        # image are (its time does not depend on it)
+        losses["rpn_fg_rows"] = rpn_fg_rows.astype(jnp.float32).mean()
         return losses
 
     def _cascade_train(self, feats, rois, roi_labels, matched_gt, fg_mask,

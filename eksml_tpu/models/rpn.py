@@ -71,7 +71,7 @@ def match_anchors(anchors: jnp.ndarray, gt_boxes: jnp.ndarray,
     crowd = jnp.zeros_like(gt_valid) if gt_crowd is None else gt_crowd
     target_ok = (gt_valid > 0) & (crowd == 0)
     # [G, A], NOT [A, G]: A is ~450k at 1344 px while G ≤ MAX_GT_BOXES
-    # (8) — the anchor axis must own the 128-wide lane dim.  The [A, G]
+    # (100) — the anchor axis must own the 128-wide lane dim.  The [A, G]
     # orientation ran at ~6% lane utilization and 6.7 GB/s (profiled
     # fusion.35, 10.8 ms/step at 1344/b4).  argmax tie-breaking (first
     # max wins) is orientation-independent here: per-anchor reductions
@@ -102,19 +102,28 @@ def match_anchors(anchors: jnp.ndarray, gt_boxes: jnp.ndarray,
 
 @jax.named_scope("sampling")
 def sample_anchors(labels: jnp.ndarray, rng: jax.Array, batch_per_im: int,
-                   fg_ratio: float) -> Tuple[jnp.ndarray, jnp.ndarray]:
+                   fg_ratio: float
+                   ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray,
+                              jnp.ndarray]:
     """Fixed-size fg/bg anchor subsample for the loss; see
     ops.sampling for the choice-without-replacement construction.
-    Returns (fg_mask, bg_mask) with at most batch_per_im total bits."""
-    from eksml_tpu.ops.sampling import sample_mask_by_priority
+    Returns (fg_mask, bg_mask, fg_idx, fg_take): the two masks over
+    [A] with at most batch_per_im total bits, and the foreground draw
+    itself, ``k = int(batch_per_im * fg_ratio)`` anchor indices and
+    which of the k slots are real picks (``fg_idx[fg_take]`` are the
+    set bits of ``fg_mask``; the other slots point at arbitrary
+    anchors).  The box term reads those k rows, not [A]."""
+    from eksml_tpu.ops.sampling import (picks_to_mask, sample_by_priority,
+                                        sample_mask_by_priority)
 
     rng_fg, rng_bg = jax.random.split(rng)
     max_fg = int(batch_per_im * fg_ratio)
-    fg_mask = sample_mask_by_priority(labels == 1, rng_fg, max_fg)
-    num_bg = batch_per_im - fg_mask.sum()
+    fg_idx, fg_take = sample_by_priority(labels == 1, rng_fg, max_fg)
+    fg_mask = picks_to_mask(fg_idx, fg_take, labels.shape[0])
+    num_bg = batch_per_im - fg_take.sum()
     bg_mask = sample_mask_by_priority(labels == 0, rng_bg, batch_per_im,
                                       limit=num_bg)
-    return fg_mask, bg_mask
+    return fg_mask, bg_mask, fg_idx, fg_take
 
 
 @jax.named_scope("rpn_nms")
@@ -168,9 +177,20 @@ def generate_proposals(
 def rpn_losses(logits: jnp.ndarray, deltas: jnp.ndarray,
                anchors: jnp.ndarray, labels: jnp.ndarray,
                matched_gt: jnp.ndarray, gt_boxes: jnp.ndarray,
-               fg_mask: jnp.ndarray, bg_mask: jnp.ndarray):
+               fg_mask: jnp.ndarray, bg_mask: jnp.ndarray,
+               fg_idx: jnp.ndarray, fg_take: jnp.ndarray):
     """RPN objectness BCE + box smooth-L1, normalized by sample count
-    (matching the standard Faster-RCNN / TensorPack normalization)."""
+    (matching the standard Faster-RCNN / TensorPack normalization).
+
+    The objectness term is a masked sum over [A] (lane-dense).  The
+    box term is formed on ``sample_anchors``' k foreground rows alone
+    (``fg_idx`` [k], ``fg_take`` [k]): three picks of k rows, never an
+    [A, 4] array (4 of 128 lanes; encoding the ~450k anchors of a
+    1344 px image to keep at most 128 costs ~11 ms a step at b4 on a
+    v5e).  Slots with ``fg_take`` false read an arbitrary anchor, whose
+    target stays finite through ``encode_boxes`` (padded gt rows are
+    zeros: ``log(EPS / aw)``), and add exactly 0 to the value and to
+    the gradient."""
     from eksml_tpu.ops.boxes import encode_boxes
 
     sel = fg_mask | bg_mask
@@ -179,10 +199,10 @@ def rpn_losses(logits: jnp.ndarray, deltas: jnp.ndarray,
     n_sel = jnp.maximum(sel.sum(), 1)
     cls_loss = jnp.where(sel, cls_loss_all, 0.0).sum() / n_sel
 
-    gt_for_anchor = gt_boxes[matched_gt]
-    box_targets = encode_boxes(gt_for_anchor, anchors)
-    box_loss_all = smooth_l1(deltas - box_targets, beta=1.0 / 9).sum(-1)
-    box_loss = jnp.where(fg_mask, box_loss_all, 0.0).sum() / n_sel
+    box_targets = encode_boxes(gt_boxes[matched_gt[fg_idx]], anchors[fg_idx])
+    box_loss_rows = smooth_l1(deltas[fg_idx] - box_targets,
+                              beta=1.0 / 9).sum(-1)
+    box_loss = jnp.where(fg_take, box_loss_rows, 0.0).sum() / n_sel
     return cls_loss, box_loss
 
 

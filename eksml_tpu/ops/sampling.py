@@ -34,8 +34,13 @@ def sample_by_priority(candidates: jnp.ndarray, rng: jax.Array, k: int,
     return idx, take
 
 
+def picks_to_mask(idx: jnp.ndarray, take: jnp.ndarray, n: int) -> jnp.ndarray:
+    """``sample_by_priority``'s picks as a boolean mask over ``n``."""
+    return jnp.zeros(n, bool).at[idx].set(take)
+
+
 def sample_mask_by_priority(candidates: jnp.ndarray, rng: jax.Array, k: int,
                             limit: jnp.ndarray = None) -> jnp.ndarray:
     """Same, as a boolean mask over the input."""
     idx, take = sample_by_priority(candidates, rng, k, limit)
-    return jnp.zeros(candidates.shape[0], bool).at[idx].set(take)
+    return picks_to_mask(idx, take, candidates.shape[0])

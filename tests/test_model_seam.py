@@ -28,7 +28,8 @@ def test_the_default_is_the_detector_built_as_before(fresh_config):
     assert cfg.MODEL.NAME == "maskrcnn" and cfg.TRAIN.OPTIMIZER == "sgd"
     assert models.build_model(cfg) == models.MaskRCNN.from_config(cfg)
     assert models.counter_spans(cfg) == {
-        "roi_bwd_strips": ("roi_bwd_tile_share", "roi_fwd_tile_share")}
+        "roi_bwd_strips": ("roi_bwd_tile_share", "roi_fwd_tile_share"),
+        "rpn_targets": ("rpn_fg_rows",)}
     assert models.pretrained_loader(cfg) is None
     fresh_config.freeze(False)
     fresh_config.BACKBONE.WEIGHTS = "/no/such/file.npz"
@@ -192,7 +193,9 @@ def test_the_detectors_step_carries_its_counter_to_a_span(tmp_path):
     ``roi_bwd_tile_share`` and ``roi_fwd_tile_share`` (shares of the
     64 x 64 tile, outside the summed loss), the log rows carry them,
     and at log steps they ride a zero-length ``roi_bwd_strips`` span,
-    as ``moe_route`` does."""
+    as ``moe_route`` does.  Likewise ``rpn_fg_rows`` (the filled slots
+    of the RPN box term's ``int(BATCH_PER_IM * FG_RATIO)`` an image) on
+    an ``rpn_targets`` span."""
     logdir = str(tmp_path / "run")
     env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=ROOT)
     out = subprocess.run(
@@ -215,6 +218,9 @@ def test_the_detectors_step_carries_its_counter_to_a_span(tmp_path):
         # the forward's W origin is no finer than the backward's (equal
         # in float32, as here): it never covers less
         assert r["roi_bwd_tile_share"] <= r["roi_fwd_tile_share"] <= 1.0
+        # RPN.BATCH_PER_IM 256 x RPN.FG_RATIO 0.5 slots; the synthetic
+        # records have boxes, so some are filled
+        assert 0 < r["rpn_fg_rows"] <= 128
         assert r["total_loss"] == pytest.approx(sum(
             v for k, v in r.items()
             if k.endswith("_loss") and k != "total_loss"), rel=1e-5)
@@ -225,3 +231,7 @@ def test_the_detectors_step_carries_its_counter_to_a_span(tmp_path):
     for key in ("roi_bwd_tile_share", "roi_fwd_tile_share"):
         assert [e["args"][key] for e in strips] == [
             r[key] for r in logged]
+    targets = [e for e in events if e["name"] == "rpn_targets"]
+    assert [(e["args"]["step"], e["args"]["rpn_fg_rows"])
+            for e in targets] == [(r["step"], r["rpn_fg_rows"])
+                                  for r in logged]

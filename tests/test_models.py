@@ -13,6 +13,7 @@ import pytest
 
 from eksml_tpu.models import (FPN, MaskRCNN, ResNetBackbone, load_r50_npz)
 from eksml_tpu.models.backbone_loader import save_r50_npz
+from eksml_tpu.models.mask_rcnn import COUNTER_SPANS
 from eksml_tpu.models.resnet import FrozenBN
 
 
@@ -395,3 +396,42 @@ def test_mask_targets_is_two_highest_precision_matmuls(under_vmap):
     gathers = [e for e in eqns if e.primitive.name == "gather"]
     picked = sorted(e.invars[0].aval.shape[-2:] for e in gathers)
     assert picked == [(3, 4), (56, 56)], picked   # gt_boxes, gt_masks rows
+
+
+@pytest.mark.parametrize("kind", ["mask", "frcnn", "cascade"])
+def test_no_per_anchor_row_pick_under_rpn_loss(kind):
+    """The RPN's box term reads the sampled foreground rows
+    (``rpn.rpn_losses``): in the training loss and its gradient no
+    ``gather`` under the ``rpn_loss`` scope yields an array with the
+    anchor count among its dimensions (the dense ``gt_boxes[matched_gt]``
+    over every anchor cannot come back unnoticed), the picks that are
+    there yield k rows, and ``rpn_fg_rows`` leaves the step beside the
+    losses.  Traced, not compiled."""
+    model = tiny_model(with_masks=kind == "mask", cascade=kind == "cascade")
+    batch = tiny_batch()
+    rng = jax.random.PRNGKey(0)
+    params = jax.eval_shape(lambda: model.init(rng, batch, rng)["params"])
+
+    def loss_fn(p):
+        losses = model.apply({"params": p}, batch, rng)
+        return losses["total_loss"], losses
+
+    closed, out = jax.make_jaxpr(
+        jax.value_and_grad(loss_fn, has_aux=True), return_shape=True)(params)
+    (_, losses), _ = out
+    assert losses["rpn_fg_rows"].shape == ()
+    assert COUNTER_SPANS["rpn_targets"] == ("rpn_fg_rows",)
+    n_anchors = sum(3 * (128 // s) ** 2 for s in (4, 8, 16, 32, 64))
+    k = int(model.rpn_batch_per_im * model.rpn_fg_ratio)
+    scoped = [eqn for eqn in _eqns(closed.jaxpr)
+              if "rpn_loss" in str(eqn.source_info.name_stack)]
+    shapes = sorted({tuple(v.aval.shape) for eqn in scoped
+                     if eqn.primitive.name == "gather"
+                     for v in eqn.outvars})
+    assert shapes, "the sampled rows are picked under rpn_loss"
+    assert not [s for s in shapes if n_anchors in s], shapes
+    assert all(k in s for s in shapes), shapes
+    # the scope's name is the one the equations carry, so a dense pick
+    # would be seen: the objectness term's per-anchor arrays are
+    assert any(n_anchors in v.aval.shape
+               for eqn in scoped for v in eqn.outvars)
