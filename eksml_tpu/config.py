@@ -468,9 +468,9 @@ def _define_defaults() -> None:
 
     # ---- model selection (eksml_tpu/models/__init__.py build_model) --
     # "maskrcnn" = the detector the MODE_*/BACKBONE/FPN/RPN/FRCNN/MRCNN
-    # blocks describe; "joyai_llm_flash" and "ouro" = the two sequence
-    # models the LM block describes (models/lm/, imported only when
-    # selected)
+    # blocks describe; "joyai_llm_flash", "ouro" and "laguna" = the
+    # three sequence models the LM block describes (models/lm/, imported
+    # only when selected)
     _C.MODEL.NAME = "maskrcnn"
 
     # ---- sequence models (models/lm/): JoyAI-LLM-Flash's config.json
@@ -508,6 +508,37 @@ def _define_defaults() -> None:
     _C.LM.HEAD_DIM = 128
     _C.LM.UT_STEPS = 4
     _C.LM.EXIT_ENTROPY_WEIGHT = 0.1
+    # MODEL.NAME=laguna (poolside/Laguna-XS.2's config.json): window and
+    # full attention mixed layer by layer over NUM_KV_HEADS grouped
+    # key-value heads of HEAD_DIM, the query heads and the rotary table
+    # set by the layer's type, one sigmoid gate a head on the attention
+    # output, then JoyAI's expert layer with no selection bias
+    # (FIRST_K_DENSE, the expert keys and EXPERTS_HELD above).  One
+    # entry a held layer in the two tuples below
+    _C.LM.NUM_KV_HEADS = 8
+    _C.LM.HEADS_PER_LAYER = (48, 64, 64, 64, 48)
+    _C.LM.LAYER_TYPES = ("full_attention", "sliding_attention",
+                         "sliding_attention", "sliding_attention",
+                         "full_attention")
+    # a sliding layer's query sees itself and the SLIDING_WINDOW - 1
+    # positions before it
+    _C.LM.SLIDING_WINDOW = 512
+    # rotary by layer type, over the first PARTIAL_ROTARY_FACTOR x
+    # HEAD_DIM dimensions; TYPE "yarn" (arXiv:2309.00071) blends the
+    # plain table with its FACTOR-fold interpolation between the
+    # dimensions that turn BETA_FAST and BETA_SLOW times over
+    # ORIGINAL_MAX_POSITION and scales cos and sin by ATTENTION_FACTOR
+    _C.LM.ROPE_FULL.TYPE = "yarn"
+    _C.LM.ROPE_FULL.THETA = 500000
+    _C.LM.ROPE_FULL.PARTIAL_ROTARY_FACTOR = 0.5
+    _C.LM.ROPE_FULL.FACTOR = 64
+    _C.LM.ROPE_FULL.ORIGINAL_MAX_POSITION = 4096
+    _C.LM.ROPE_FULL.BETA_FAST = 64
+    _C.LM.ROPE_FULL.BETA_SLOW = 1
+    _C.LM.ROPE_FULL.ATTENTION_FACTOR = 1.4158883083359672
+    _C.LM.ROPE_WINDOW.TYPE = "default"
+    _C.LM.ROPE_WINDOW.THETA = 10000
+    _C.LM.ROPE_WINDOW.PARTIAL_ROTARY_FACTOR = 1.0
     # (first, count): the contiguous routed experts THIS chip holds; the
     # router still scores all N_ROUTED_EXPERTS and picks 8 a token
     _C.LM.EXPERTS_HELD = (0, 16)
@@ -778,16 +809,23 @@ def _define_defaults() -> None:
 
 _define_defaults()
 
-# the LM keys only one of the two sequence models reads; what is not
-# listed (hidden size, heads, layers, vocabulary rows, ...) both read
+# the LM keys not every sequence model reads, by the models that do;
+# what is not listed (hidden size, layers, vocabulary rows, ...) all
+# three read
+_EXPERT_LAYER_KEYS = (
+    "MOE_INTERMEDIATE_SIZE", "FIRST_K_DENSE", "N_ROUTED_EXPERTS",
+    "NUM_EXPERTS_PER_TOK", "N_SHARED_EXPERTS", "ROUTED_SCALING_FACTOR",
+    "EXPERTS_HELD")
 LM_KEYS_OF = {
     "joyai_llm_flash": (
         "Q_LORA_RANK", "KV_LORA_RANK", "QK_NOPE_HEAD_DIM",
-        "QK_ROPE_HEAD_DIM", "V_HEAD_DIM", "MOE_INTERMEDIATE_SIZE",
-        "FIRST_K_DENSE", "N_ROUTED_EXPERTS", "NUM_EXPERTS_PER_TOK",
-        "N_SHARED_EXPERTS", "ROUTED_SCALING_FACTOR", "NUM_MTP",
-        "MTP_LOSS_WEIGHT", "EXPERTS_HELD"),
-    "ouro": ("HEAD_DIM", "UT_STEPS", "EXIT_ENTROPY_WEIGHT"),
+        "QK_ROPE_HEAD_DIM", "V_HEAD_DIM", "NUM_MTP", "MTP_LOSS_WEIGHT",
+        "NUM_HEADS", "ROPE_THETA") + _EXPERT_LAYER_KEYS,
+    "ouro": ("HEAD_DIM", "UT_STEPS", "EXIT_ENTROPY_WEIGHT", "NUM_HEADS",
+             "ROPE_THETA"),
+    "laguna": ("HEAD_DIM", "NUM_KV_HEADS", "HEADS_PER_LAYER",
+               "LAYER_TYPES", "SLIDING_WINDOW", "ROPE_FULL",
+               "ROPE_WINDOW") + _EXPERT_LAYER_KEYS,
 }
 _LM_DEFAULTS = _C.LM.to_dict()
 
@@ -796,10 +834,14 @@ def _lm_keys_of_the_other_model() -> list:
     """The ``LM`` keys this run moved from their defaults although the
     model it builds never reads them."""
     def plain(value):       # (0, 16) and [0, 16] are one setting
+        if isinstance(value, AttrDict):
+            return {k: plain(v) for k, v in value.to_dict().items()}
         return list(value) if isinstance(value, (tuple, list)) else value
 
-    return [f"LM.{key}" for name, keys in LM_KEYS_OF.items()
-            if name != _C.MODEL.NAME for key in keys
+    read = LM_KEYS_OF[_C.MODEL.NAME]
+    others = {key for keys in LM_KEYS_OF.values() for key in keys
+              if key not in read}
+    return [f"LM.{key}" for key in sorted(others)
             if plain(getattr(_C.LM, key)) != plain(_LM_DEFAULTS[key])]
 
 
@@ -818,8 +860,22 @@ def finalize_configs(is_training: bool) -> AttrDict:
         stray = _lm_keys_of_the_other_model()
         assert not stray, (
             f"{stray}: set, but MODEL.NAME={_C.MODEL.NAME} does not read "
-            "them (keys of the other sequence model)")
+            "them (keys of another sequence model)")
         assert _C.LM.UT_STEPS >= 1, _C.LM.UT_STEPS
+    if _C.MODEL.NAME == "laguna":
+        lm = _C.LM
+        assert (len(lm.HEADS_PER_LAYER) == len(lm.LAYER_TYPES)
+                == lm.NUM_LAYERS), (
+            f"LM.HEADS_PER_LAYER {lm.HEADS_PER_LAYER} and LM.LAYER_TYPES "
+            f"{lm.LAYER_TYPES} want one entry for each of the "
+            f"LM.NUM_LAYERS={lm.NUM_LAYERS} held layers")
+        assert set(lm.LAYER_TYPES) <= {"full_attention",
+                                       "sliding_attention"}, lm.LAYER_TYPES
+        assert all(h % lm.NUM_KV_HEADS == 0 for h in lm.HEADS_PER_LAYER), (
+            lm.HEADS_PER_LAYER, lm.NUM_KV_HEADS)
+        assert lm.SLIDING_WINDOW >= 1, lm.SLIDING_WINDOW
+        for rope in (lm.ROPE_FULL, lm.ROPE_WINDOW):
+            assert rope.TYPE in ("default", "yarn"), rope.TYPE
     assert _C.TRAIN.PARAM_DTYPE in ("float32", "bfloat16"), (
         _C.TRAIN.PARAM_DTYPE)
     assert _C.RESILIENCE.DATA.VALIDATE in ("off", "warn", "strict"), (
@@ -968,6 +1024,30 @@ OURO_TINY_OVERRIDES = (
     "LM.ROPE_THETA=1000000", "LM.INIT_STD=0.02", "LM.VOCAB_ROWS=96",
     "LM.SEQ_LEN=64", "LM.ATTENTION_BLOCK=16", "LM.LOSS_CHUNK=32",
     "LM.DATA.DOC_LEN_MEDIAN=24.0", "LM.DATA.DOC_LEN_CLIP=(4,256)",
+)
+
+
+# Laguna's mixed stack at the same tiny size (a dense layer with full
+# attention, an expert layer with a window of 24 that is no multiple
+# of the 16-block, an expert layer with full attention; 4 and 6 query
+# heads over 2 key-value heads of 16, YaRN over half the head), for the
+# CPU tests alone.
+LAGUNA_TINY_OVERRIDES = (
+    "MODEL.NAME=laguna", "TRAIN.OPTIMIZER=adamw",
+    "TRAIN.PRECISION=float32", "TRAIN.REMAT=True",
+    "LM.HIDDEN_SIZE=64", "LM.HEAD_DIM=16", "LM.NUM_KV_HEADS=2",
+    "LM.HEADS_PER_LAYER=(4,6,4)",
+    "LM.LAYER_TYPES=('full_attention','sliding_attention',"
+    "'full_attention')",
+    "LM.SLIDING_WINDOW=24", "LM.ROPE_FULL.THETA=100",
+    "LM.ROPE_FULL.FACTOR=4", "LM.ROPE_FULL.ORIGINAL_MAX_POSITION=32",
+    "LM.ROPE_FULL.BETA_FAST=4", "LM.ROPE_FULL.ATTENTION_FACTOR=1.1386",
+    "LM.INTERMEDIATE_SIZE=160", "LM.MOE_INTERMEDIATE_SIZE=32",
+    "LM.N_ROUTED_EXPERTS=8", "LM.NUM_EXPERTS_PER_TOK=2",
+    "LM.NUM_LAYERS=3", "LM.EXPERTS_HELD=(0,4)", "LM.INIT_STD=0.02",
+    "LM.VOCAB_ROWS=96", "LM.SEQ_LEN=64", "LM.ATTENTION_BLOCK=16",
+    "LM.LOSS_CHUNK=32", "LM.DATA.DOC_LEN_MEDIAN=24.0",
+    "LM.DATA.DOC_LEN_CLIP=(4,256)",
 )
 
 

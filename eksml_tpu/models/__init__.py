@@ -45,8 +45,14 @@ def _ouro(cfg):
             ouro.counter_spans(cfg.LM.UT_STEPS))
 
 
+def _laguna(cfg):
+    from eksml_tpu.models.lm import laguna
+
+    return laguna.Laguna, laguna.decay_mask, laguna.COUNTER_SPANS
+
+
 _SEAM = {"maskrcnn": _maskrcnn, "joyai_llm_flash": _joyai_llm_flash,
-         "ouro": _ouro}
+         "ouro": _ouro, "laguna": _laguna}
 MODEL_NAMES = tuple(_SEAM)
 
 

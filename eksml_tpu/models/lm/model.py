@@ -1,7 +1,8 @@
 """JoyAI-LLM-Flash (DeepSeek-V3 family, arXiv:2412.19437), one of the
-two sequence models of ``models/lm`` (the other: ``ouro.py``, which
-shares this file's ``Matrix``, ``linear``, ``RMSNorm``, ``SwiGLU`` and
-chunked cross-entropy), as a flax module with the training losses:
+three sequence models of ``models/lm`` (the others: ``ouro.py`` and
+``laguna.py``, which share this file's ``Matrix``, ``linear``,
+``RMSNorm``, ``SwiGLU`` and chunked cross-entropy; Laguna its expert
+layer too), as a flax module with the training losses:
 multi-head latent attention in every block, a dense SwiGLU layer first,
 then expert layers (256-way sigmoid routing, 8 a token, one shared
 expert, the chip's held experts), one multi-token-prediction module of
@@ -145,8 +146,11 @@ class MLA(nn.Module):
 
 
 class MoE(nn.Module):
+    """Shared expert + the held routed experts.  ``selection_bias``
+    False: a router with no selection-only bias (Laguna's)."""
     cfg: Any
     dtype: Any
+    selection_bias: bool = True
 
     @nn.compact
     def __call__(self, h):
@@ -157,8 +161,9 @@ class MoE(nn.Module):
         flat = h.reshape(b * s, d)
         router = Matrix((d, c.N_ROUTED_EXPERTS), c.INIT_STD,
                         name="router")()
-        bias = RouterBias(c.N_ROUTED_EXPERTS, ROUTER_BIAS_STD,
-                          name="router_bias")()
+        bias = (RouterBias(c.N_ROUTED_EXPERTS, ROUTER_BIAS_STD,
+                           name="router_bias")()
+                if self.selection_bias else None)
         ids, gates = moe.route(flat, router, bias, c.NUM_EXPERTS_PER_TOK,
                                c.ROUTED_SCALING_FACTOR)
         self.sow("intermediates", "routing", ids)
@@ -255,13 +260,21 @@ class JoyAIFlash(nn.Module):
             losses["mtp_loss"] = mtp
             total = ce + c.MTP_LOSS_WEIGHT * mtp
         losses["total_loss"] = total
-        if counters:
-            losses["moe_pairs_held"] = sum(k["pairs_held"] for k in counters)
-            losses["moe_pairs_dropped"] = sum(
-                k["pairs_dropped"] for k in counters)
-            losses["moe_load_max_over_mean"] = jnp.max(jnp.stack(
-                [k["load_max_over_mean"] for k in counters]))
+        losses.update(routing_counters(counters))
         return losses
+
+
+def routing_counters(counters) -> dict:
+    """The step's ``moe_*`` counters from its expert layers' own
+    (``COUNTER_SPANS``' keys): pairs summed, the load of the worst
+    layer."""
+    if not counters:
+        return {}
+    return {
+        "moe_pairs_held": sum(k["pairs_held"] for k in counters),
+        "moe_pairs_dropped": sum(k["pairs_dropped"] for k in counters),
+        "moe_load_max_over_mean": jnp.max(jnp.stack(
+            [k["load_max_over_mean"] for k in counters]))}
 
 
 def _chunk_losses(hc, head_kernel, tc):

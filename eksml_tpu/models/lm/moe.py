@@ -1,8 +1,9 @@
 """The expert layer's routed part, for the chip's share of the experts.
 
 The router scores ALL ``n_routed`` experts (sigmoid, float32), selects
-``top_k`` a token by score + selection-only bias and gates by the
-scores alone (normalised over the selected, times the scaling factor).
+``top_k`` a token by score + selection-only bias (JoyAI's; Laguna's
+router has none and selects by score) and gates by the scores alone
+(normalised over the selected, times the scaling factor).
 This chip holds the contiguous experts ``[first, first + count)``: the
 token-expert pairs that name a held expert are sorted by expert and
 multiplied group by group (``jax.lax.ragged_dot``: XLA's grouped
@@ -21,14 +22,17 @@ def route(h, router_kernel, router_bias, top_k: int, scaling: float):
     """``h`` ``[T, D]`` -> (expert ids ``[T, k]`` int32, gates ``[T, k]``
     float32).  Float32 throughout, the matrix product at full precision:
     the top-k is a discrete choice and should not hang on bf16 rounding
-    of the scores."""
+    of the scores.  ``router_bias`` None: selection by the scores."""
     with jax.named_scope("moe_route"):
         logits = jnp.dot(h.astype(jnp.float32),
                          router_kernel.astype(jnp.float32),
                          precision=jax.lax.Precision.HIGHEST)
         scores = jax.nn.sigmoid(logits)
-        bias = jax.lax.stop_gradient(router_bias.astype(jnp.float32))
-        _, ids = jax.lax.top_k(scores + bias, top_k)
+        chosen_by = scores
+        if router_bias is not None:
+            chosen_by = scores + jax.lax.stop_gradient(
+                router_bias.astype(jnp.float32))
+        _, ids = jax.lax.top_k(chosen_by, top_k)
         picked = jnp.take_along_axis(scores, ids, axis=-1)
         gates = scaling * picked / jnp.sum(picked, axis=-1, keepdims=True)
         return ids.astype(jnp.int32), gates

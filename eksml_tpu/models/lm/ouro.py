@@ -59,19 +59,33 @@ def counter_spans(passes: int) -> dict:
         + [f"loop_ce_pass{t}" for t in each])}
 
 
+def rotate_half(x, inv_freq, scale=None):
+    """Rotary embedding by the frequencies ``inv_freq`` ``[r / 2]`` in
+    the half-split pairing (x[j], x[j + r/2]) of the first r dimensions
+    of the last axis, the rest unrotated; ``x`` ``[B, S, H, D]``,
+    position = index along S; cos and sin times ``scale`` (YaRN's
+    attention factor) where one is given."""
+    r = 2 * inv_freq.shape[0]
+    ang = (jnp.arange(x.shape[1], dtype=jnp.float32)[:, None]
+           * inv_freq[None, :])
+    cos, sin = jnp.cos(ang)[None, :, None, :], jnp.sin(ang)[None, :, None, :]
+    if scale is not None:
+        cos, sin = cos * scale, sin * scale
+    xf = x.astype(jnp.float32)
+    a, b = xf[..., :r // 2], xf[..., r // 2:r]
+    parts = [a * cos - b * sin, b * cos + a * sin]
+    if r < x.shape[-1]:
+        parts.append(xf[..., r:])
+    return jnp.concatenate(parts, axis=-1).astype(x.dtype)
+
+
 def rope_half(x, theta: float):
-    """Rotary embedding in the half-split pairing (x[j], x[j + d/2]) of
-    the last axis (the Llama convention, which Ouro's modelling file
-    follows; ``model.rope`` pairs neighbours, which is JoyAI's); ``x``
-    ``[B, S, H, D]``, position = index along S."""
+    """``rotate_half`` over the whole last axis at the plain table
+    theta^(-2j/d) (the Llama convention, which Ouro's modelling file
+    follows; ``model.rope`` pairs neighbours, which is JoyAI's)."""
     d = x.shape[-1]
     inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
-    ang = jnp.arange(x.shape[1], dtype=jnp.float32)[:, None] * inv[None, :]
-    cos, sin = jnp.cos(ang)[None, :, None, :], jnp.sin(ang)[None, :, None, :]
-    xf = x.astype(jnp.float32)
-    a, b = xf[..., :d // 2], xf[..., d // 2:]
-    out = jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1)
-    return out.astype(x.dtype)
+    return rotate_half(x, inv)
 
 
 class Attention(nn.Module):

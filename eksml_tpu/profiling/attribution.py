@@ -199,6 +199,11 @@ SCOPE_RULES: Tuple[Tuple[str, str, bool], ...] = (
     ("loop-mlp", r"(^|[/(])loop_mlp($|[/)])", True),
     ("loop-head", r"(^|[/(])loop_head($|[/)])", True),
     ("loop-exit", r"(^|[/(])loop_exit($|[/)])", False),
+    # Laguna's attention scopes (models/lm/laguna.py; its expert layer
+    # and loss carry JoyAI's)
+    ("gqa-proj", r"(^|[/(])gqa($|[/)])", True),
+    ("gqa-core-full", r"(^|[/(])gqa_core_full($|[/)])", True),
+    ("gqa-core-window", r"(^|[/(])gqa_core_window($|[/)])", True),
 )
 _SCOPE_RULES_C = tuple((comp, re.compile(pat), bwd)
                        for comp, pat, bwd in SCOPE_RULES)

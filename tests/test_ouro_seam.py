@@ -34,8 +34,8 @@ def test_the_looped_model_is_chosen_by_configuration(fresh_config):
         "loop_ce_pass3")}
 
 
-def test_the_seam_knows_three_names_and_says_so(fresh_config):
-    assert models.MODEL_NAMES == ("maskrcnn", "joyai_llm_flash", "ouro")
+def test_the_seam_knows_its_names_and_says_so(fresh_config):
+    assert models.MODEL_NAMES[:3] == ("maskrcnn", "joyai_llm_flash", "ouro")
     fresh_config.MODEL.NAME = "looplm"
     with pytest.raises(ValueError) as e:
         models.build_model(fresh_config)

@@ -270,6 +270,39 @@ def test_looped_attention_core_compiles_for_v5e(one_chip, monkeypatch):
         "splash_mha_fwd_residuals", "splash_mha_dkv_no_residuals"}
 
 
+@pytest.mark.parametrize("heads, window", [(48, None), (64, 512)])
+def test_grouped_window_attention_cores_compile_for_v5e(one_chip,
+                                                        monkeypatch, heads,
+                                                        window):
+    """Laguna's two cores at the cell's shapes: 2 rows x 8192 positions,
+    48 query heads full causal and 64 under a 512 window, over 8
+    key-value heads of 128, bf16: the grouped (``mqa``) kernels, forward
+    and the fused backward, one key-value head a group of query heads.
+    The kernels keep the names the ``swa_`` roofline readers look for."""
+    from eksml_tpu.models.lm import attention
+
+    monkeypatch.setattr(attention.jax, "default_backend", lambda: "tpu")
+    attention._splash_kernel.cache_clear()
+
+    def s(h):
+        return jax.ShapeDtypeStruct((2, 8192, h, 128), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    def loss(q, k, v):
+        with jax.named_scope("gqa_core_window" if window
+                             else "gqa_core_full"):
+            o = attention.causal_attention(q, k, v, 512, window=window)
+        return o.astype(jnp.float32).sum()
+
+    try:
+        compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            s(heads), s(8), s(8)).compile()
+    finally:
+        attention._splash_kernel.cache_clear()
+    assert set(_kernel_names(compiled)) == {
+        "splash_mqa_fwd_residuals", "splash_mqa_dkv_no_residuals"}
+
+
 def test_lm_grouped_product_compiles_for_v5e(one_chip):
     """The expert layer's routed part at the cell's size: 8192 tokens x
     8 pairs through 16 held experts of 2048 x 768.  XLA lowers
