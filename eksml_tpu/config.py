@@ -290,15 +290,22 @@ SHARDING_DEFAULTS = dict(
 #   <logdir>/trace-host<i>.json).  A live span is also a
 #   jax.profiler.TraceAnnotation, so inside a profiler session
 #   (/debugz/profile, --profile) the .xplane.pb carries the spans on
-#   the profiler's clock: data_wait (step, seq), globalize_batch,
+#   the profiler's clock: the fit's edges fit_setup (telemetry_start,
+#   prefetcher_start) and fit_teardown (goodput_final,
+#   prefetcher_close, exporter_stop, device_drain, ring_flush,
+#   ckpt_wait, writer_flush), data_wait (step, seq), globalize_batch,
 #   train_step (a StepTraceAnnotation), loss_sync (the log step's wait
-#   for the device), host_metrics, host_aggregate,
+#   for the device), host_metrics, log_row (steps and the collector's
+#   gc_ms, gc_count, gc_max_ms; goodput_publish, write_scalars,
+#   hbm_gauges, host_aggregate and the model's counter spans inside),
 #   checkpoint_save/restore, eval, the producer threads' batch_build
 #   (seq, rows) and h2d_prefetch (seq), and device_step (step): one
 #   per step, ended by a stamper thread that blocks on the step's
 #   loss, so each step has a completion time with no sync in the
-#   loop.  Off = the span API is a true no-op (shared null context
-#   manager, no allocation, no annotation, no stamper thread).
+#   loop.  A traced fit also hooks gc.callbacks: collections of 1 ms
+#   or more are ring-only gc spans.  Off = the span API is a true
+#   no-op (shared null context manager, no allocation, no annotation,
+#   no stamper thread, no gc hook).
 # - RING_EVENTS: span ring capacity (memory bound; oldest spans drop).
 # - PROFILE_STEPS: steps per on-demand/anomaly capture when the
 #   /debugz/profile request doesn't name its own count.  Every capture

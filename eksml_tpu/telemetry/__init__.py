@@ -54,7 +54,8 @@ from eksml_tpu.telemetry.recorder import (FlightRecorder,  # noqa: F401
                                           install, remove_event_sink)
 from eksml_tpu.telemetry.registry import (MetricRegistry,  # noqa: F401
                                           default_registry)
-from eksml_tpu.telemetry.tracing import (AnomalyDetector,  # noqa: F401
+from eksml_tpu.telemetry.tracing import (NULL_SPAN,  # noqa: F401
+                                         AnomalyDetector, GcMeter,
                                          ProfileTrigger, StepStamper,
                                          Tracer, complete_span,
                                          get_tracer, install_span_sink,

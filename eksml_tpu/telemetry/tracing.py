@@ -25,6 +25,10 @@ workflow of the TPU/XProf profiler:
   that blocks on each step's loss in turn inside a ``device_step``
   span, so every step has a completion time without a sync in the
   step loop.  Exists only while a traced ``fit`` runs.
+- **Garbage collection** (:class:`GcMeter`): one ``gc.callbacks`` hook
+  while a traced ``fit`` runs; it sums every collection's time and
+  count for the next log row's ``log_row`` span and keeps the
+  collections of 1 ms or more as ring-only ``gc`` spans.
 - **Trace files**: :meth:`Tracer.flush` writes the ring as
   Chrome-trace-event/Perfetto-compatible JSON to
   ``<logdir>/trace-host<i>.json`` (``pid`` = host, ``tid`` = thread),
@@ -42,6 +46,23 @@ workflow of the TPU/XProf profiler:
   persistent straggler survives K consecutive log intervals — the
   trace of a production incident exists *before* anyone is paged.
 
+The spans ``Trainer.fit`` records, by thread.  Step loop: the fit's
+edges ``fit_setup`` (children ``telemetry_start``,
+``prefetcher_start``) and ``fit_teardown`` (``goodput_final``,
+``prefetcher_close``, ``exporter_stop``, ``device_drain``,
+``ring_flush``, ``ckpt_wait``, ``writer_flush``); each step's
+``data_wait`` (``step``, ``seq``), ``globalize_batch`` and
+``train_step``; at log steps ``loss_sync``, ``host_metrics`` and
+``log_row`` (``steps``, ``gc_ms``, ``gc_count``, ``gc_max_ms``;
+children the model's zero-length counter spans, ``host_aggregate``,
+``goodput_publish``, ``write_scalars``, ``hbm_gauges``); ``eval``,
+``checkpoint_save`` / ``checkpoint_restore``.  The loader's producer:
+``batch_build`` (``seq``, ``rows``); the prefetcher's thread:
+``h2d_prefetch`` (``seq``); the stamper's: ``device_step``
+(``step``); any thread: ``gc`` (``generation``, ``collected``).
+Instants: ``clock`` (the ring's clock pair, once a fit),
+``profile_capture_start`` / ``profile_capture_done``.
+
 Everything fails soft: tracing must never take down training.  ``jax``
 is imported lazily (first live span); where it cannot be imported, or
 an annotation cannot be entered, spans stay ring-only.
@@ -50,6 +71,7 @@ an annotation cannot be entered, spans stay ring-only.
 from __future__ import annotations
 
 import collections
+import gc
 import json
 import logging
 import os
@@ -177,7 +199,6 @@ class Tracer:
             maxlen=self.capacity)
         self._epoch_wall_us = time.time() * 1e6
         self._epoch_perf = time.perf_counter()
-        self.spans_recorded = 0
 
     # -- recording -----------------------------------------------------
 
@@ -191,7 +212,8 @@ class Tracer:
         return self._epoch_wall_us + (perf_t - self._epoch_perf) * 1e6
 
     def _complete(self, name: str, t0: float, t1: float,
-                  step: Optional[int], attrs: Optional[Dict]) -> None:
+                  step: Optional[int], attrs: Optional[Dict],
+                  tid: Optional[int] = None) -> None:
         args: Dict = {"host": self.host_id}
         if step is not None:
             args["step"] = int(step)
@@ -201,11 +223,11 @@ class Tracer:
               "ts": round(self._ts_us(t0), 3),
               "dur": round((t1 - t0) * 1e6, 3),
               "pid": self.host_id,
-              "tid": threading.get_ident() % 2 ** 31,
+              "tid": (threading.get_ident() if tid is None else tid)
+              % 2 ** 31,
               "args": args}
         with self._lock:
             self._ring.append(ev)
-            self.spans_recorded += 1
         # span sink (goodput ledger): notified OUTSIDE the ring lock —
         # a sink must never extend this hot-path critical section, and
         # it must never take down the traced code
@@ -232,6 +254,14 @@ class Tracer:
               "args": args}
         with self._lock:
             self._ring.append(ev)
+
+    def mark_clock(self) -> None:
+        """A ``clock`` instant carrying the ring's one epoch pair
+        (``wall_us`` = ``time.time()`` in µs taken with ``perf_s`` =
+        ``perf_counter()``), so that a reader joining the ring to another
+        clock (the profiler's) can convert."""
+        self.instant("clock", wall_us=round(self._epoch_wall_us, 3),
+                     perf_s=self._epoch_perf)
 
     # -- output --------------------------------------------------------
 
@@ -380,6 +410,76 @@ class StepStamper:
             log.warning("step stamper still waiting on the device "
                         "after %.0fs — left behind",
                         self.ERROR_EXIT_TIMEOUT_SEC)
+
+
+class GcMeter:
+    """Python's garbage collector, seen from a traced ``fit``.
+
+    :meth:`install` adds one ``gc.callbacks`` hook; :meth:`uninstall`
+    takes it out again.  The hook times every collection on whatever
+    thread runs it and sums time, count and the longest; :meth:`drain`
+    hands the sums since the last drain to the next log row (``gc_ms``,
+    ``gc_count``, ``gc_max_ms``) and records each collection of
+    ``SPAN_MIN_S`` or more as a ``gc`` span (``generation``,
+    ``collected``) in the ring, on the thread that collected.
+
+    Short collections stay sums: there can be thousands of gen-0 ones
+    in a window, and the ring holds a few thousand events.  The hook
+    records nothing itself: a collection can start on a thread that
+    holds the tracer's lock (any allocation may trigger one), so the
+    long ones wait in a list and :meth:`drain` moves them into the
+    ring.  The hook and the drain share a reentrant lock: a collection
+    that the drain's own allocation triggers runs the hook on the
+    drain's thread, inside it."""
+
+    SPAN_MIN_S = 1e-3
+
+    def __init__(self, tracer: Tracer):
+        self._tracer = tracer
+        self._lock = threading.RLock()
+        self._t0 = 0.0
+        self._sums = [0.0, 0, 0.0]        # seconds, count, longest
+        self._long: List[Tuple] = []
+
+    def install(self) -> "GcMeter":
+        gc.callbacks.append(self._on_gc)
+        return self
+
+    def uninstall(self) -> None:
+        """Take the hook out and record the long collections still
+        waiting (idempotent)."""
+        if self._on_gc in gc.callbacks:
+            gc.callbacks.remove(self._on_gc)
+        self.drain()
+
+    def _on_gc(self, phase: str, info: Dict) -> None:
+        if phase == "start":           # collections never overlap
+            self._t0 = time.perf_counter()
+            return
+        t1 = time.perf_counter()
+        dt = t1 - self._t0
+        with self._lock:
+            self._sums[0] += dt
+            self._sums[1] += 1
+            self._sums[2] = max(self._sums[2], dt)
+            if dt >= self.SPAN_MIN_S:
+                self._long.append((self._t0, t1, threading.get_ident(),
+                                   info.get("generation"),
+                                   info.get("collected")))
+
+    def drain(self) -> Dict[str, float]:
+        """The sums since the last drain, as the log row's args; the
+        long collections since then go into the ring."""
+        with self._lock:
+            sums, self._sums = self._sums, [0.0, 0, 0.0]
+            long, self._long = self._long, []
+        for t0, t1, tid, generation, collected in long:
+            self._tracer._complete(
+                "gc", t0, t1, None,
+                {"generation": generation, "collected": collected},
+                tid=tid)
+        return {"gc_ms": round(sums[0] * 1e3, 3), "gc_count": sums[1],
+                "gc_max_ms": round(sums[2] * 1e3, 3)}
 
 
 # -- on-demand profile capture ----------------------------------------

@@ -604,6 +604,11 @@ class Trainer:
         dump — so input starvation (TPU idle, queue empty past the
         deadline) reads as a stalled-phase diagnosis, not a generic
         hang."""
+        # fit_setup: from here to the loop's first data_wait, on this
+        # Trainer's own tracer (installed for this fit further down);
+        # ended before the loop, or in the finally if setup raises
+        setup_span = self._span("fit_setup")
+        setup_span.__enter__()
         cfg = self.cfg
         res = cfg.RESILIENCE
         step_fn = None
@@ -714,12 +719,6 @@ class Trainer:
         # neither the exporter NOR the aggregation collective runs
         aggregate_hosts = bool(self._telemetry["ENABLED"]
                                and self._telemetry["AGGREGATE_HOSTS"])
-        # distinct family name from the eksml_train_step_time_ms GAUGE
-        # the MetricWriter mirror creates for the step_time_ms scalar —
-        # one name must mean one type (registry enforces it)
-        step_time_hist = registry.histogram(
-            "eksml_train_step_duration_ms",
-            "wall time per training step (log-interval mean)")
         sentinel = DivergenceSentinel(patience=res.NAN_PATIENCE,
                                       max_rollbacks=res.MAX_ROLLBACKS)
         nan_injected = False
@@ -731,6 +730,7 @@ class Trainer:
             # Before the prefetcher: its thread's first h2d_prefetch
             # span (seq 0) starts with the thread
             telemetry.install_tracer(self.tracer)
+            self.tracer.mark_clock()
         # per-step completion stamps (device_step spans): a thread of
         # its own, only while a traced fit runs
         stamper = None
@@ -750,74 +750,80 @@ class Trainer:
         if getattr(cfg.TRAIN, "PREFETCH_TO_DEVICE", False):
             from eksml_tpu.data.loader import DevicePrefetcher
 
-            prefetcher = DevicePrefetcher(batches,
-                                          self._globalize_batch,
-                                          health=data_health)
+            with self._span("prefetcher_start"):
+                prefetcher = DevicePrefetcher(batches,
+                                              self._globalize_batch,
+                                              health=data_health)
             source = prefetcher
 
+        gc_meter = None
         step = start_step
         try:
-            # goodput ledger (telemetry/goodput.py): classify this
-            # fit's wall-clock from the EXISTING span/event exhaust.
-            # Downtime since the previous segment is recovered NOW
-            # from the shared event file + checkpoint timestamps, so
-            # the live eksml_goodput_ratio already reflects the
-            # restart gap the relaunch is paying for.  self._goodput
-            # is assigned BEFORE the sinks install, so the finally's
-            # cleanup runs even for a partial setup.
-            if (self._telemetry["ENABLED"]
-                    and self._goodput_cfg["ENABLED"]):
-                from eksml_tpu.telemetry import goodput as goodput_mod
+            with self._span("telemetry_start"):
+                # goodput ledger (telemetry/goodput.py): classify this
+                # fit's wall-clock from the EXISTING span/event exhaust.
+                # Downtime since the previous segment is recovered NOW
+                # from the shared event file + checkpoint timestamps, so
+                # the live eksml_goodput_ratio already reflects the
+                # restart gap the relaunch is paying for.  self._goodput
+                # is assigned BEFORE the sinks install, so the finally's
+                # cleanup runs even for a partial setup.
+                if (self._telemetry["ENABLED"]
+                        and self._goodput_cfg["ENABLED"]):
+                    from eksml_tpu.telemetry import goodput as goodput_mod
 
-                down_s, seg_start = goodput_mod.recover_downtime(
-                    self.logdir, jax.process_index())
-                meter = telemetry.GoodputMeter(
-                    fine=self.tracer is not None,
-                    segment_start_wall=seg_start)
-                if down_s > 0:
-                    meter.credit("downtime", down_s)
-                    log.info("goodput: recovered %.1fs downtime since "
-                             "the previous segment", down_s)
-                self._goodput = meter
-                prev_span_sink = telemetry.install_span_sink(
-                    meter.on_span)
-                telemetry.add_event_sink(meter.on_event)
-                if self._goodput_cfg["BANK"]:
-                    goodput_bank_path = telemetry.goodput_path_for(
+                    down_s, seg_start = goodput_mod.recover_downtime(
                         self.logdir, jax.process_index())
-            # exporter starts INSIDE the try so any setup failure
-            # below still reaches the finally that stops it — a leaked
-            # server would squat the fixed port and keep serving stale
-            # health state to probes
-            if self._telemetry["ENABLED"]:
-                exporter = telemetry.TelemetryExporter(
-                    port=int(self._telemetry["PORT"]),
-                    health_fn=_health,
-                    port_file=os.path.join(
-                        self.logdir,
-                        f"telemetry-host{jax.process_index()}.port"),
-                    profile_trigger=profile_trigger,
-                    stale_after_sec=float(
-                        self._telemetry["HEALTHZ_STALE_SEC"]),
-                ).start()
-            elif float(self._telemetry["HEALTHZ_STALE_SEC"]) > 0:
-                # the charts render a livenessProbe whenever
-                # healthz_stale_seconds > 0 — with telemetry disabled
-                # nothing serves /healthz, every probe gets connection
-                # refused, and kubelet restarts a HEALTHY pod forever.
-                # The combination is an operator error; say so loudly.
-                log.warning(
-                    "TELEMETRY.HEALTHZ_STALE_SEC=%s is set but "
-                    "TELEMETRY.ENABLED=False: /healthz will NOT be "
-                    "served — if the chart rendered a livenessProbe "
-                    "(healthz_stale_seconds > 0) kubelet will restart "
-                    "this pod in a loop. Set healthz_stale_seconds=0 "
-                    "when disabling telemetry.",
-                    self._telemetry["HEALTHZ_STALE_SEC"])
+                    meter = telemetry.GoodputMeter(
+                        fine=self.tracer is not None,
+                        segment_start_wall=seg_start)
+                    if down_s > 0:
+                        meter.credit("downtime", down_s)
+                        log.info("goodput: recovered %.1fs downtime since "
+                                 "the previous segment", down_s)
+                    self._goodput = meter
+                    prev_span_sink = telemetry.install_span_sink(
+                        meter.on_span)
+                    telemetry.add_event_sink(meter.on_event)
+                    if self._goodput_cfg["BANK"]:
+                        goodput_bank_path = telemetry.goodput_path_for(
+                            self.logdir, jax.process_index())
+                # exporter starts INSIDE the try so any setup failure
+                # below still reaches the finally that stops it — a leaked
+                # server would squat the fixed port and keep serving stale
+                # health state to probes
+                if self._telemetry["ENABLED"]:
+                    exporter = telemetry.TelemetryExporter(
+                        port=int(self._telemetry["PORT"]),
+                        health_fn=_health,
+                        port_file=os.path.join(
+                            self.logdir,
+                            f"telemetry-host{jax.process_index()}.port"),
+                        profile_trigger=profile_trigger,
+                        stale_after_sec=float(
+                            self._telemetry["HEALTHZ_STALE_SEC"]),
+                    ).start()
+                elif float(self._telemetry["HEALTHZ_STALE_SEC"]) > 0:
+                    # the charts render a livenessProbe whenever
+                    # healthz_stale_seconds > 0 — with telemetry disabled
+                    # nothing serves /healthz, every probe gets connection
+                    # refused, and kubelet restarts a HEALTHY pod forever.
+                    # The combination is an operator error; say so loudly.
+                    log.warning(
+                        "TELEMETRY.HEALTHZ_STALE_SEC=%s is set but "
+                        "TELEMETRY.ENABLED=False: /healthz will NOT be "
+                        "served — if the chart rendered a livenessProbe "
+                        "(healthz_stale_seconds > 0) kubelet will restart "
+                        "this pod in a loop. Set healthz_stale_seconds=0 "
+                        "when disabling telemetry.",
+                        self._telemetry["HEALTHZ_STALE_SEC"])
             if self.tracer is not None:
                 stamper = telemetry.StepStamper(jax.block_until_ready)
+                gc_meter = telemetry.GcMeter(self.tracer).install()
             source_iter = iter(source)
             _end = object()
+            setup_span.__exit__(None, None, None)
+            setup_span = None
             while True:
                 # data_wait: how long the step loop blocked on input —
                 # the span that names a starving TPU in the timeline.
@@ -1004,111 +1010,121 @@ class Trainer:
                         # the sync the log row needs anyway
                         metrics = jax.tree.map(
                             lambda x: float(np.asarray(x)), metrics)  # eksml-lint: disable=host-sync
-                    for name, keys in self._counter_spans.items():
-                        # the model's counters of THIS step, where a
-                        # reader of the span ring can see them
-                        with telemetry.span(name, step=step, attrs={
-                                k: metrics[k] for k in keys
-                                if k in metrics}):
-                            pass
-                    if data_health is not None:
-                        metrics.update(
-                            {f"data/{k}": float(v) for k, v
-                             in data_health.scalars().items()
-                             if isinstance(v, (int, float))})
-                    elif prefetcher is not None:
-                        # no LoaderHealth surface (direct fit callers):
-                        # still emit the prefetch wait
-                        metrics["data/prefetch_wait_ms"] = round(
-                            prefetcher.wait_ms_ewma or 0.0, 2)
-                    dt = time.time() - t_last
-                    t_last = time.time()
-                    # normalize by the steps actually covered since the
-                    # last log — the final step lands off the
-                    # LOG_PERIOD boundary, where assuming a full period
-                    # overstated throughput
-                    metrics["images_per_sec"] = (
-                        imgs_per_step * steps_since_log / max(dt, 1e-9))
-                    step_time_ms = (dt * 1000.0
-                                    / max(1, steps_since_log))
-                    metrics["step_time_ms"] = round(step_time_ms, 2)
-                    step_time_hist.observe(step_time_ms)
-                    steps_since_log = 0
-                    agg = None
-                    if aggregate_hosts:
-                        # cross-host min/max/mean + straggler index:
-                        # host-side allgather OUTSIDE jit, zero RNG —
-                        # a collective, so it runs on EVERY host at
-                        # this (host-identical) log step, not just
-                        # where the writer lives
-                        hv = {k: metrics.get(f"data/{k}", 0.0)
-                              for k in telemetry.HOST_AGG_KEYS}
-                        hv["step_time_ms"] = step_time_ms
-                        with telemetry.span("host_aggregate",
-                                            step=step):
-                            agg = telemetry.aggregate_host_scalars(hv)
-                        telemetry.publish_aggregates(agg, registry)
-                        metrics.update(agg)
-                    if detector is not None:
-                        # anomaly trigger: a persistent step-time p95
-                        # regression or straggler fires the SAME
-                        # guarded capture /debugz/profile uses, so the
-                        # incident's trace exists before anyone is
-                        # paged.  agg values are host-identical (they
-                        # came off a collective), so all hosts request
-                        # together and each captures its own trace.
-                        lag = spread = None
-                        if agg is not None:
-                            mean = agg.get("hosts/step_time_ms_mean",
-                                           0.0)
-                            if mean > 0:
-                                lag = agg.get("hosts/lagging")
-                                spread = (agg.get(
-                                    "hosts/step_time_ms_max", 0.0)
-                                    / mean)
-                        reason = detector.observe(
-                            step_time_ms, lagging_host=lag,
-                            spread_ratio=spread)
-                        if (reason is not None
-                                and profile_trigger is not None):
-                            ok, detail = profile_trigger.request(
-                                steps=int(
-                                    self._tracing["PROFILE_STEPS"]),
-                                reason=f"anomaly: {reason}")
-                            log.warning(
-                                "telemetry anomaly at step %d: %s — "
-                                "profile capture %s (%s)", step,
-                                reason,
-                                "accepted" if ok else "rejected",
-                                detail)
-                            telemetry.event(
-                                "anomaly_detected", step=step,
-                                reason=reason,
-                                capture=("accepted" if ok
-                                         else detail))
-                    if self._goodput is not None:
-                        # rolling run-level SLI: the ratio gauge +
-                        # monotonic per-bucket badput counters land on
-                        # /metrics (the elastic controller's inputs),
-                        # the banked snapshot line is what makes the
-                        # ledger survive this process
-                        snap = self._goodput.publish(registry,
-                                                     steps=step)
-                        metrics["goodput/ratio"] = \
-                            snap["goodput_ratio"]
-                        if goodput_bank_path:
-                            self._goodput.bank(goodput_bank_path,
-                                               steps=step)
-                    if self.writer:
-                        self.writer.write_scalars(step, metrics)
-                    # live HBM gauges + the one-time predicted-vs-
-                    # measured peak line (best-effort: CPU backends
-                    # report no memory_stats and this is a silent
-                    # no-op — test-pinned)
-                    self._publish_hbm()
-                    log.info("step %d/%d loss=%.4f (%.1f img/s)", step,
-                             total_steps, metrics["total_loss"],
-                             metrics["images_per_sec"])
+                    # log_row: the rest of the log step's host work, while
+                    # the device, drained by the wait above, idles until
+                    # the next train_step.  Its args: the steps since the
+                    # previous row and the collector's time over them
+                    row = None
+                    if gc_meter is not None:
+                        row = dict(gc_meter.drain(), steps=steps_since_log)
+                    with telemetry.span("log_row", step=step, attrs=row):
+                        for name, keys in self._counter_spans.items():
+                            # the model's counters of THIS step, where a
+                            # reader of the span ring can see them
+                            with telemetry.span(name, step=step, attrs={
+                                    k: metrics[k] for k in keys
+                                    if k in metrics}):
+                                pass
+                        if data_health is not None:
+                            metrics.update(
+                                {f"data/{k}": float(v) for k, v
+                                 in data_health.scalars().items()
+                                 if isinstance(v, (int, float))})
+                        elif prefetcher is not None:
+                            # no LoaderHealth surface (direct fit callers):
+                            # still emit the prefetch wait
+                            metrics["data/prefetch_wait_ms"] = round(
+                                prefetcher.wait_ms_ewma or 0.0, 2)
+                        dt = time.time() - t_last
+                        t_last = time.time()
+                        # normalize by the steps actually covered since the
+                        # last log — the final step lands off the
+                        # LOG_PERIOD boundary, where assuming a full period
+                        # overstated throughput
+                        metrics["images_per_sec"] = (
+                            imgs_per_step * steps_since_log / max(dt, 1e-9))
+                        step_time_ms = (dt * 1000.0
+                                        / max(1, steps_since_log))
+                        metrics["step_time_ms"] = round(step_time_ms, 2)
+                        steps_since_log = 0
+                        agg = None
+                        if aggregate_hosts:
+                            # cross-host min/max/mean + straggler index:
+                            # host-side allgather OUTSIDE jit, zero RNG —
+                            # a collective, so it runs on EVERY host at
+                            # this (host-identical) log step, not just
+                            # where the writer lives
+                            hv = {k: metrics.get(f"data/{k}", 0.0)
+                                  for k in telemetry.HOST_AGG_KEYS}
+                            hv["step_time_ms"] = step_time_ms
+                            with telemetry.span("host_aggregate",
+                                                step=step):
+                                agg = telemetry.aggregate_host_scalars(hv)
+                            telemetry.publish_aggregates(agg, registry)
+                            metrics.update(agg)
+                        if detector is not None:
+                            # anomaly trigger: a persistent step-time p95
+                            # regression or straggler fires the SAME
+                            # guarded capture /debugz/profile uses, so the
+                            # incident's trace exists before anyone is
+                            # paged.  agg values are host-identical (they
+                            # came off a collective), so all hosts request
+                            # together and each captures its own trace.
+                            lag = spread = None
+                            if agg is not None:
+                                mean = agg.get("hosts/step_time_ms_mean",
+                                               0.0)
+                                if mean > 0:
+                                    lag = agg.get("hosts/lagging")
+                                    spread = (agg.get(
+                                        "hosts/step_time_ms_max", 0.0)
+                                        / mean)
+                            reason = detector.observe(
+                                step_time_ms, lagging_host=lag,
+                                spread_ratio=spread)
+                            if (reason is not None
+                                    and profile_trigger is not None):
+                                ok, detail = profile_trigger.request(
+                                    steps=int(
+                                        self._tracing["PROFILE_STEPS"]),
+                                    reason=f"anomaly: {reason}")
+                                log.warning(
+                                    "telemetry anomaly at step %d: %s — "
+                                    "profile capture %s (%s)", step,
+                                    reason,
+                                    "accepted" if ok else "rejected",
+                                    detail)
+                                telemetry.event(
+                                    "anomaly_detected", step=step,
+                                    reason=reason,
+                                    capture=("accepted" if ok
+                                             else detail))
+                        if self._goodput is not None:
+                            # rolling run-level SLI: the ratio gauge +
+                            # monotonic per-bucket badput counters land on
+                            # /metrics (the elastic controller's inputs),
+                            # the banked snapshot line is what makes the
+                            # ledger survive this process
+                            with telemetry.span("goodput_publish", step=step):
+                                snap = self._goodput.publish(registry,
+                                                             steps=step)
+                                metrics["goodput/ratio"] = \
+                                    snap["goodput_ratio"]
+                                if goodput_bank_path:
+                                    self._goodput.bank(goodput_bank_path,
+                                                       steps=step)
+                        if self.writer:
+                            with telemetry.span("write_scalars", step=step):
+                                self.writer.write_scalars(step, metrics)
+                        # live HBM gauges + the one-time predicted-vs-
+                        # measured peak line (best-effort: CPU backends
+                        # report no memory_stats and this is a silent
+                        # no-op — test-pinned)
+                        with telemetry.span("hbm_gauges", step=step):
+                            self._publish_hbm()
+                        log.info("step %d/%d loss=%.4f (%.1f img/s)", step,
+                                 total_steps, metrics["total_loss"],
+                                 metrics["images_per_sec"])
 
                 if sync_every and step % sync_every == 0:
                     from eksml_tpu.parallel.collectives import \
@@ -1139,11 +1155,6 @@ class Trainer:
                         t_save = time.time()
                         self.ckpt.save(step, state)
                         save_ms = (time.time() - t_save) * 1000
-                        registry.histogram(
-                            "eksml_checkpoint_save_ms",
-                            "step-loop blocking time of a checkpoint "
-                            "save (async snapshot + dispatch)"
-                        ).observe(save_ms)
                         if self.writer:
                             self.writer.write_scalars(step, {
                                 "checkpoint_save_ms": save_ms})
@@ -1176,79 +1187,105 @@ class Trainer:
                 if watchdog:
                     watchdog.beat("next_batch", step)
         finally:
-            if self._goodput is not None:
-                # final snapshot: the exporter may already be gone but
-                # the banked line is the segment's authoritative
-                # ledger row for the cross-restart merge — land it on
-                # EVERY exit path (preemption included)
+            if setup_span is not None:
+                setup_span.__exit__(*sys.exc_info())
+            # fit_teardown and its children are recorded on this
+            # Trainer's tracer directly: the ones after the ring's flush
+            # and uninstall land in the ring, not in the flushed file
+            with self._span("fit_teardown", step=step):
+                if self._goodput is not None:
+                    # final snapshot: the exporter may already be gone
+                    # but the banked line is the segment's authoritative
+                    # ledger row for the cross-restart merge — land it
+                    # on EVERY exit path (preemption included)
+                    with self._span("goodput_final"):
+                        try:
+                            self._goodput.publish(registry, steps=step)
+                            if goodput_bank_path:
+                                self._goodput.bank(goodput_bank_path,
+                                                   steps=step, final=True)
+                        except Exception:  # noqa: BLE001 — observability
+                            log.exception("final goodput snapshot failed")
+                        telemetry.remove_event_sink(self._goodput.on_event)
+                        telemetry.install_span_sink(prev_span_sink)
+                        self._goodput = None
+                if watchdog:
+                    watchdog.stop()
+                if preempt is not None:
+                    preempt.uninstall()
+                if prefetcher is not None:
+                    # stop the transfer thread and drop its queued device
+                    # batches — an exception mid-loop must not leak the
+                    # thread or pin prefetched HBM
+                    with self._span("prefetcher_close"):
+                        prefetcher.close()
+                if exporter is not None:
+                    # the scrape endpoint dies with the loop it
+                    # describes; a relaunch (or a later fit) re-binds
+                    # cleanly
+                    with self._span("exporter_stop"):
+                        exporter.stop()
+                if stamper is not None:
+                    # stamp every dispatched step (the device drains)
+                    # while the tracer, and a capture in flight, still
+                    # take the spans; on the way out of an error a wedged
+                    # device must not hang the exit (the thread is a
+                    # daemon).  After the teardown above, not before it:
+                    # the loop runs ahead of the device, and an untraced
+                    # fit stops its exporter (up to 0.5 s of server poll)
+                    # while the device drains — so must a traced one (on
+                    # a TPU v5e, 0.43 s a fit otherwise)
+                    with self._span("device_drain"):
+                        stamper.close(failed=sys.exc_info()[0] is not None)
+                if capture is not None:
+                    # run ended before the capture's steps elapsed —
+                    # close the trace so it still lands (and a later
+                    # start_trace won't raise)
+                    self._finish_capture(capture, profile_trigger, step,
+                                         truncated=True)
+                if gc_meter is not None:
+                    gc_meter.uninstall()
+                if self.tracer is not None:
+                    # steady-state spans land even without a capture:
+                    # the cross-host merge works from whatever the ring
+                    # holds
+                    with self._span("ring_flush"):
+                        self.tracer.flush()
+                    # uninstall so later spans in this process (another
+                    # Trainer, eval tooling) can't record into THIS run's
+                    # ring and be flushed into its trace file
+                    if telemetry.get_tracer() is self.tracer:
+                        telemetry.install_tracer(None)
+                # always drain the async checkpoint thread and buffered
+                # metrics — an exception mid-loop must not abandon an
+                # in-flight save or lose the last metric rows.  A drain
+                # failure is swallowed ONLY while another exception is
+                # already propagating (it must not mask where training
+                # actually died); on the clean path it raises, so a
+                # failed final commit cannot masquerade as a successful
+                # run.
+                propagating = sys.exc_info()[0] is not None
                 try:
-                    self._goodput.publish(registry, steps=step)
-                    if goodput_bank_path:
-                        self._goodput.bank(goodput_bank_path,
-                                           steps=step, final=True)
-                except Exception:  # noqa: BLE001 — observability only
-                    log.exception("final goodput snapshot failed")
-                telemetry.remove_event_sink(self._goodput.on_event)
-                telemetry.install_span_sink(prev_span_sink)
-                self._goodput = None
-            if watchdog:
-                watchdog.stop()
-            if preempt is not None:
-                preempt.uninstall()
-            if prefetcher is not None:
-                # stop the transfer thread and drop its queued device
-                # batches — an exception mid-loop must not leak the
-                # thread or pin prefetched HBM
-                prefetcher.close()
-            if exporter is not None:
-                # the scrape endpoint dies with the loop it describes;
-                # a relaunch (or a later fit) re-binds cleanly
-                exporter.stop()
-            if stamper is not None:
-                # stamp every dispatched step (the device drains) while
-                # the tracer, and a capture in flight, still take the
-                # spans; on the way out of an error a wedged device
-                # must not hang the exit (the thread is a daemon).
-                # After the teardown above, not before it: the loop
-                # runs ahead of the device, and an untraced fit stops
-                # its exporter (up to 0.5 s of server poll) while the
-                # device drains — so must a traced one (my chip runs,
-                # PR 25: 0.43 s a fit otherwise)
-                stamper.close(failed=sys.exc_info()[0] is not None)
-            if capture is not None:
-                # run ended before the capture's steps elapsed — close
-                # the trace so it still lands (and a later start_trace
-                # won't raise)
-                self._finish_capture(capture, profile_trigger, step,
-                                     truncated=True)
-            if self.tracer is not None:
-                # steady-state spans land even without a capture: the
-                # cross-host merge works from whatever the ring holds
-                self.tracer.flush()
-                # uninstall so later spans in this process (another
-                # Trainer, eval tooling) can't record into THIS run's
-                # ring and be flushed into its trace file
-                if telemetry.get_tracer() is self.tracer:
-                    telemetry.install_tracer(None)
-            # always drain the async checkpoint thread and buffered
-            # metrics — an exception mid-loop must not abandon an
-            # in-flight save or lose the last metric rows.  A drain
-            # failure is swallowed ONLY while another exception is
-            # already propagating (it must not mask where training
-            # actually died); on the clean path it raises, so a failed
-            # final commit cannot masquerade as a successful run.
-            propagating = sys.exc_info()[0] is not None
-            try:
-                self.ckpt.wait()
-                if self.writer:
-                    self.writer.flush()
-            except Exception:
-                if not propagating:
-                    raise
-                log.exception("draining checkpoint/metrics state "
-                              "during shutdown failed (keeping the "
-                              "original exception)")
+                    with self._span("ckpt_wait"):
+                        self.ckpt.wait()
+                    if self.writer:
+                        with self._span("writer_flush"):
+                            self.writer.flush()
+                except Exception:
+                    if not propagating:
+                        raise
+                    log.exception("draining checkpoint/metrics state "
+                                  "during shutdown failed (keeping the "
+                                  "original exception)")
         return state
+
+    def _span(self, name: str, step: Optional[int] = None):
+        """A span on this Trainer's own tracer, installed or not: the
+        fit's edges run before the install and after the uninstall.
+        The shared null span where tracing is off."""
+        if self.tracer is None:
+            return telemetry.NULL_SPAN
+        return self.tracer.span(name, step=step)
 
     @staticmethod
     def _batch_shape_key(batch) -> Tuple:

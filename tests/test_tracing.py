@@ -8,6 +8,7 @@ tools/trace_summary.py.  The subprocess half (mid-run
 tests/test_fault_tolerance.py.
 """
 
+import gc
 import json
 import os
 import threading
@@ -39,9 +40,7 @@ def test_ring_is_bounded():
             pass
     events = tr.snapshot()
     assert len(events) == 32  # ring bounded, oldest dropped
-    assert tr.spans_recorded == 100
-    assert events[-1]["args"]["step"] == 99
-    assert events[0]["args"]["step"] == 68
+    assert [e["args"]["step"] for e in events] == list(range(68, 100))
 
 
 def test_span_step_host_attribution_and_chrome_fields():
@@ -315,27 +314,47 @@ def _fit(logdir, tracing_on, steps=4):
                                  with_masks=True, gt_mask_size=28,
                                  seed=0)
         trainer = Trainer(cfg, logdir)
-        out = {"built": built}
+        out = {"built": built, "gc_hooks_during": []}
+
+        def gc_meters():
+            return [cb for cb in gc.callbacks if isinstance(
+                getattr(cb, "__self__", None), tracing.GcMeter)]
+
+        def batches_with_a_full_collection():
+            for i, batch in enumerate(loader.batches(steps)):
+                if i == 2:
+                    out["gc_hooks_during"].append(gc_meters())
+                    gc.collect()
+                yield batch
+
+        def two_then_boom():
+            gen = loader.batches(2)
+            yield from gen
+            out["gc_hooks_during"].append(gc_meters())
+            raise _Boom("the input broke")
+
+        hooks = list(gc.callbacks)
         try:
-            state = trainer.fit(loader.batches(steps), total_steps=100)
+            state = trainer.fit(batches_with_a_full_collection(),
+                                total_steps=100)
             jax.block_until_ready(state)
+            out["gc_hooks_kept"] = gc.callbacks == hooks
             out["threads_after_fit"] = [
                 t.name for t in threading.enumerate()]
             out["installed_after_fit"] = telemetry.get_tracer()
+            before = 0
             if trainer.tracer is not None:
                 out["spans"] = trainer.tracer.snapshot()
                 before = len(out["spans"])
-
-                def two_then_boom():
-                    gen = loader.batches(2)
-                    yield from gen
-                    raise _Boom("the input broke")
-
-                with pytest.raises(_Boom):
-                    trainer.fit(two_then_boom(), total_steps=100,
-                                start_step=steps, state=state)
-                out["threads_after_raise"] = [
-                    t.name for t in threading.enumerate()]
+                with open(trainer.tracer.path) as f:
+                    out["flushed"] = json.load(f)["traceEvents"]
+            with pytest.raises(_Boom):
+                trainer.fit(two_then_boom(), total_steps=100,
+                            start_step=steps, state=state)
+            out["gc_hooks_kept_on_raise"] = gc.callbacks == hooks
+            out["threads_after_raise"] = [
+                t.name for t in threading.enumerate()]
+            if trainer.tracer is not None:
                 out["spans_of_raising_fit"] = \
                     trainer.tracer.snapshot()[before:]
         finally:
@@ -440,10 +459,101 @@ def test_tracing_leaves_the_losses_alone(fits):
     assert sorted(fits["on"]["losses"]) == [2, 4]
 
 
+def _inside(child, parent):
+    """``child`` lies within ``parent`` on the same thread (µs, with
+    the ring's rounding)."""
+    return (child["tid"] == parent["tid"]
+            and child["ts"] >= parent["ts"] - 0.01
+            and child["ts"] + child["dur"]
+            <= parent["ts"] + parent["dur"] + 0.01)
+
+
+FIT_EDGES = {"fit_setup": ("telemetry_start", "prefetcher_start"),
+             "fit_teardown": ("goodput_final", "prefetcher_close",
+                              "exporter_stop", "device_drain",
+                              "ring_flush", "ckpt_wait", "writer_flush")}
+
+
+@pytest.mark.parametrize("edge", sorted(FIT_EDGES))
+def test_a_fits_edges_are_spans_with_their_children_inside(fits, edge):
+    """One ``fit_setup`` from fit's entry to the first ``data_wait``,
+    one ``fit_teardown`` over the ``finally``; each child lies inside
+    its parent on the step loop's thread.  ``ckpt_wait`` and
+    ``writer_flush`` follow the ring's flush and the uninstall: they
+    are in the ring, not in the flushed file."""
+    spans = fits["on"]["spans"]
+    (parent,) = [e for e in spans if e["name"] == edge]
+    loop = {e["tid"] for e in spans if e["name"] == "train_step"}
+    assert {parent["tid"]} == loop
+    for name in FIT_EDGES[edge]:
+        (child,) = [e for e in spans if e["name"] == name]
+        assert _inside(child, parent), name
+    waits = sorted(e["ts"] for e in spans if e["name"] == "data_wait")
+    if edge == "fit_setup":
+        assert parent["ts"] + parent["dur"] <= waits[0]
+        (clock,) = [e for e in spans if e["name"] == "clock"]
+        assert clock["ph"] == "i" and parent["ts"] <= clock["ts"]
+        assert set(clock["args"]) >= {"wall_us", "perf_s"}
+    else:
+        assert parent["ts"] >= waits[-1]
+        flushed = {e.get("name") for e in fits["on"]["flushed"]}
+        assert "ring_flush" not in flushed
+        assert not flushed & {"ckpt_wait", "writer_flush", edge}
+        assert "device_drain" in flushed
+
+
+def test_a_log_row_is_a_span_after_host_metrics(fits):
+    """At each log step ``log_row`` follows ``host_metrics`` and holds
+    the counter and aggregate spans and its three children; its args
+    are the steps since the last row and the collector's sums."""
+    spans = fits["on"]["spans"]
+    rows = {e["args"]["step"]: e for e in spans if e["name"] == "log_row"}
+    assert sorted(rows) == [2, 4]                       # LOG_PERIOD=2
+    metrics = {e["args"]["step"]: e for e in spans
+               if e["name"] == "host_metrics"}
+    for step, row in rows.items():
+        assert metrics[step]["ts"] + metrics[step]["dur"] <= row["ts"]
+        assert row["args"]["steps"] == 2
+        assert set(row["args"]) >= {"gc_ms", "gc_count", "gc_max_ms"}
+        for name in ("goodput_publish", "write_scalars", "hbm_gauges",
+                     "host_aggregate"):
+            (child,) = [e for e in spans if e["name"] == name
+                        and e["args"].get("step") == step]
+            assert _inside(child, row), name
+    assert not {"log_row", "fit_setup", "fit_teardown", "gc"} \
+        & set(telemetry.goodput.SPAN_BUCKETS)
+
+
+def test_a_long_collection_is_a_gc_span_and_every_one_is_counted(fits):
+    """The full collection forced inside the traced fit, in the
+    input's generator, is a ``gc`` span of generation 2; the rows count
+    it with the short ones."""
+    on = fits["on"]
+    full = [e for e in on["spans"] if e["name"] == "gc"
+            and e["args"]["generation"] == 2]
+    assert full and all(e["dur"] >= 1000.0 for e in full)
+    rows = [e["args"] for e in on["spans"] if e["name"] == "log_row"]
+    assert sum(r["gc_count"] for r in rows) >= 1
+    assert max(r["gc_max_ms"] for r in rows) >= max(
+        e["dur"] for e in full) / 1e3 - 0.01
+
+
+@pytest.mark.parametrize("mode", ["off", "on"])
+def test_the_gc_hook_lives_only_inside_a_traced_fit(fits, mode):
+    """Traced, one hook while the fit runs; untraced, none.  Either
+    way ``gc.callbacks`` is as it was after the fit, and after a fit
+    that raises."""
+    fit = fits[mode]
+    during = fit["gc_hooks_during"]
+    assert len(during) == 2                     # both fits looked
+    assert all(len(hooks) == (mode == "on") for hooks in during)
+    assert fit["gc_hooks_kept"] and fit["gc_hooks_kept_on_raise"]
+
+
 def test_thread_safety_and_flush_is_valid_chrome_trace(tmp_path):
     path = telemetry.trace_path_for(str(tmp_path), 2)
     assert path.endswith("trace-host2.json")
-    tr = Tracer(capacity=512, path=path, host_id=2)
+    tr = Tracer(capacity=2048, path=path, host_id=2)
 
     def worker(n):
         for i in range(200):
@@ -456,15 +566,15 @@ def test_thread_safety_and_flush_is_valid_chrome_trace(tmp_path):
         t.start()
     for t in threads:
         t.join()
-    assert tr.spans_recorded == 1600
+    assert len(tr.snapshot()) == 1600      # no append lost
     out = tr.flush()
     assert out == path
     doc = json.load(open(path))
     events = doc["traceEvents"]
-    # process metadata + a full ring, every event host-stamped
+    # process metadata + the ring, every event host-stamped
     assert events[0]["ph"] == "M"
     spans = [e for e in events if e["ph"] == "X"]
-    assert len(spans) == 512
+    assert len(spans) == 1600
     assert all(e["pid"] == 2 and e["args"]["host"] == 2
                for e in spans)
 
