@@ -43,7 +43,7 @@ from eksml_tpu.ops.roi_align import dispatch_roi_align, resample_masks
 # carries it at log steps (train.Trainer.fit)
 COUNTER_SPANS = {"roi_bwd_strips": ("roi_bwd_tile_share",
                                     "roi_fwd_tile_share"),
-                 "rpn_targets": ("rpn_fg_rows",)}
+                 "rpn_targets": ("rpn_fg_rows", "match_gt_rows")}
 
 
 class MaskRCNN(nn.Module):
@@ -234,9 +234,9 @@ class MaskRCNN(nn.Module):
                 labels, r, self.rpn_batch_per_im, self.rpn_fg_ratio)
             return (*rpn_losses(logits, deltas, anchors_cat, labels, matched,
                                 gt_boxes, fg, bg, fg_idx, fg_take),
-                    fg_take.sum())
+                    fg_take.sum(), jnp.sum(gt_valid > 0))
 
-        rpn_cls, rpn_box, rpn_fg_rows = jax.vmap(rpn_one)(
+        rpn_cls, rpn_box, rpn_fg_rows, match_gt_rows = jax.vmap(rpn_one)(
             logits_cat, deltas_cat, batch["gt_boxes"], batch["gt_valid"],
             gt_crowd, rngs[:, 0])
 
@@ -332,6 +332,9 @@ class MaskRCNN(nn.Module):
         # how full the box term's int(batch_per_im * fg_ratio) slots an
         # image are (its time does not depend on it)
         losses["rpn_fg_rows"] = rpn_fg_rows.astype(jnp.float32).mean()
+        # ground-truth rows the anchor matching visited an image: the
+        # valid ones (rpn.match_reductions)
+        losses["match_gt_rows"] = match_gt_rows.astype(jnp.float32).mean()
         return losses
 
     def _cascade_train(self, feats, rois, roi_labels, matched_gt, fg_mask,

@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 import optax
 
-from eksml_tpu.ops.boxes import clip_boxes, decode_boxes, pairwise_iou
+from eksml_tpu.ops.boxes import EPS, area, clip_boxes, decode_boxes
 from eksml_tpu.ops.nms import nms_mask
 
 
@@ -53,6 +53,64 @@ class RPNHead(nn.Module):
         return logits, deltas
 
 
+def match_reductions(anchors: jnp.ndarray, gt_boxes: jnp.ndarray,
+                     target_ok: jnp.ndarray, crowd_ok: jnp.ndarray):
+    """The reductions ``match_anchors`` labels from, one ground-truth
+    row at a time: ``(best_iou [A], matched_gt [A], crowd_iou [A],
+    gt_best_iou [G], best_anchor [G])``, equal to the max / argmax over
+    the rows (per anchor) and over the anchors (per row) of the
+    ``[G, A]`` IoU matrix whose rows outside ``target_ok`` read 0, and
+    the per-anchor max over the ``crowd_ok`` rows.
+
+    A is ~450k at 1344 px and G (``DATA.MAX_GT_BOXES``) 100, of which
+    an image holds a handful: the dense matrix is 180 MB an image,
+    written once and read three times, padding rows and all (PERF.md
+    §6).  Here a loop visits the image's target rows in increasing row
+    order, then its crowd rows, wherever they sit among the G; each
+    computes its IoU against the anchors (``pairwise_iou``'s formula,
+    anchors along the lanes) and folds it into running maxima carried
+    through the loop.  An anchor's best row is replaced only where a
+    later row is strictly greater, so the lowest tied row keeps it and
+    an anchor no row overlaps keeps row 0 at IoU 0, as ``argmax`` does;
+    rows the loop skips keep 0 and anchor 0, as their zero rows of the
+    dense matrix give.  The work follows the image's rows: all G rows
+    valid cost about what the dense form does."""
+    ax1, ay1, ax2, ay2 = (anchors[:, k] for k in range(4))
+    anchor_area = area(anchors)
+    gt_area = area(gt_boxes)
+    # the rows to visit first, targets before crowds, each in row order
+    kind = jnp.where(target_ok, 0, jnp.where(crowd_ok, 1, 2))
+    order = jnp.argsort(kind, stable=True).astype(jnp.int32)
+    n_target = jnp.sum(target_ok, dtype=jnp.int32)
+    n_crowd = jnp.sum(crowd_ok, dtype=jnp.int32)
+
+    def iou(g):
+        x1, y1, x2, y2 = (gt_boxes[g, k] for k in range(4))
+        w = jnp.maximum(jnp.minimum(x2, ax2) - jnp.maximum(x1, ax1), 0.0)
+        h = jnp.maximum(jnp.minimum(y2, ay2) - jnp.maximum(y1, ay1), 0.0)
+        inter = w * h
+        return inter / jnp.maximum(gt_area[g] + anchor_area - inter, EPS)
+
+    def target(e, carry):
+        best, matched, row_best, row_anchor = carry
+        g = order[e]
+        v = iou(g)
+        better = v > best
+        return (jnp.where(better, v, best), jnp.where(better, g, matched),
+                row_best.at[g].set(v.max()),
+                row_anchor.at[g].set(jnp.argmax(v).astype(jnp.int32)))
+
+    a, rows = anchors.shape[0], gt_boxes.shape[0]
+    zeros = lambda n, dtype=jnp.float32: jnp.zeros(n, dtype)
+    best, matched, row_best, row_anchor = jax.lax.fori_loop(
+        0, n_target, target,
+        (zeros(a), zeros(a, jnp.int32), zeros(rows), zeros(rows, jnp.int32)))
+    crowd_iou = jax.lax.fori_loop(
+        n_target, n_target + n_crowd,
+        lambda e, c: jnp.maximum(c, iou(order[e])), zeros(a))
+    return best, matched, crowd_iou, row_best, row_anchor
+
+
 @jax.named_scope("matching")
 def match_anchors(anchors: jnp.ndarray, gt_boxes: jnp.ndarray,
                   gt_valid: jnp.ndarray, pos_thresh: float,
@@ -62,35 +120,24 @@ def match_anchors(anchors: jnp.ndarray, gt_boxes: jnp.ndarray,
     """Label anchors against padded GT.
 
     Returns ``labels`` [A] (1 fg, 0 bg, -1 ignore) and ``matched_gt``
-    [A] (index of best GT).  Padded GT rows (gt_valid=0) are masked out
-    of the IoU matrix, so static GT padding never creates positives.
-    Crowd GT rows (``gt_crowd=1``) never become positives, and anchors
-    overlapping a crowd region above ``neg_thresh`` are *ignored*
-    rather than trained as background.
+    [A] (index of best GT).  Padded GT rows (gt_valid=0) take no part
+    (``match_reductions`` visits the valid rows alone), so static GT
+    padding never creates positives.  Crowd GT rows (``gt_crowd=1``)
+    never become positives, and anchors overlapping a crowd region
+    above ``neg_thresh`` are *ignored* rather than trained as
+    background.
     """
     crowd = jnp.zeros_like(gt_valid) if gt_crowd is None else gt_crowd
     target_ok = (gt_valid > 0) & (crowd == 0)
-    # [G, A], NOT [A, G]: A is ~450k at 1344 px while G ≤ MAX_GT_BOXES
-    # (100) — the anchor axis must own the 128-wide lane dim.  The [A, G]
-    # orientation ran at ~6% lane utilization and 6.7 GB/s (profiled
-    # fusion.35, 10.8 ms/step at 1344/b4).  argmax tie-breaking (first
-    # max wins) is orientation-independent here: per-anchor reductions
-    # run over axis 0 and per-GT reductions over axis 1, both
-    # returning the lowest tied index exactly as before.
-    iou_all = pairwise_iou(gt_boxes, anchors)  # [G, A]
-    iou = iou_all * target_ok[:, None].astype(iou_all.dtype)
-    best_iou = iou.max(axis=0)
-    matched_gt = iou.argmax(axis=0)
+    crowd_ok = (gt_valid > 0) & (crowd > 0)
+    best_iou, matched_gt, crowd_iou, gt_best_iou, best_anchor_per_gt = (
+        match_reductions(anchors, gt_boxes, target_ok, crowd_ok))
     labels = jnp.full(anchors.shape[0], -1, jnp.int32)
     labels = jnp.where(best_iou < neg_thresh, 0, labels)
     labels = jnp.where(best_iou >= pos_thresh, 1, labels)
     # crowd overlap → ignore (only demotes background, never positives)
-    crowd_iou = (iou_all * ((gt_valid > 0) & (crowd > 0))[:, None]
-                 ).max(axis=0)
     labels = jnp.where((labels == 0) & (crowd_iou >= neg_thresh), -1, labels)
     # force-match: every valid non-crowd GT gets its best anchor positive
-    best_anchor_per_gt = iou.argmax(axis=1)  # [G]
-    gt_best_iou = iou.max(axis=1)
     force = target_ok & (gt_best_iou > 1e-3)
     labels = labels.at[best_anchor_per_gt].set(
         jnp.where(force, 1, labels[best_anchor_per_gt]))

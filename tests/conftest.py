@@ -39,6 +39,21 @@ def _seed():
     np.random.seed(0)
 
 
+@pytest.fixture(autouse=True, scope="module")
+def _config_put_back_after_module():
+    """Each test module leaves the one global config tree as it found
+    it.  A module that reaches ``harness.program_config`` writes a
+    cell's overrides into the tree (``MODE_MASK=False`` for the
+    Faster-RCNN cell), and modules that read the tree bare
+    (``tests/test_config.py``) may follow it on the same worker under
+    ``--dist loadfile``."""
+    saved, frozen = config_mod.config.to_dict(), config_mod.config._frozen
+    yield
+    config_mod.config.freeze(False)
+    config_mod.config.from_dict(saved)
+    config_mod.config.freeze(frozen)
+
+
 @pytest.fixture(scope="session")
 def tracked_files():
     """Repo-relative paths of the files git would commit (tracked, or

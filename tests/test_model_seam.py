@@ -29,7 +29,7 @@ def test_the_default_is_the_detector_built_as_before(fresh_config):
     assert models.build_model(cfg) == models.MaskRCNN.from_config(cfg)
     assert models.counter_spans(cfg) == {
         "roi_bwd_strips": ("roi_bwd_tile_share", "roi_fwd_tile_share"),
-        "rpn_targets": ("rpn_fg_rows",)}
+        "rpn_targets": ("rpn_fg_rows", "match_gt_rows")}
     assert models.pretrained_loader(cfg) is None
     fresh_config.freeze(False)
     fresh_config.BACKBONE.WEIGHTS = "/no/such/file.npz"
@@ -195,8 +195,10 @@ def test_the_detectors_step_carries_its_counter_to_a_span(tmp_path):
     64 x 64 tile, outside the summed loss), the log rows carry them,
     and at log steps they ride a zero-length ``roi_bwd_strips`` span,
     as ``moe_route`` does.  Likewise ``rpn_fg_rows`` (the filled slots
-    of the RPN box term's ``int(BATCH_PER_IM * FG_RATIO)`` an image) on
-    an ``rpn_targets`` span."""
+    of the RPN box term's ``int(BATCH_PER_IM * FG_RATIO)`` an image) and
+    ``match_gt_rows`` (the ground-truth rows the anchor matching
+    visited an image: its valid ones, of ``DATA.MAX_GT_BOXES``) on an
+    ``rpn_targets`` span."""
     logdir = str(tmp_path / "run")
     env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=ROOT)
     out = subprocess.run(
@@ -222,6 +224,8 @@ def test_the_detectors_step_carries_its_counter_to_a_span(tmp_path):
         # RPN.BATCH_PER_IM 256 x RPN.FG_RATIO 0.5 slots; the synthetic
         # records have boxes, so some are filled
         assert 0 < r["rpn_fg_rows"] <= 128
+        # the synthetic records have boxes, padded to 8 rows an image
+        assert 0 < r["match_gt_rows"] <= 8
         assert r["total_loss"] == pytest.approx(sum(
             v for k, v in r.items()
             if k.endswith("_loss") and k != "total_loss"), rel=1e-5)
@@ -233,6 +237,6 @@ def test_the_detectors_step_carries_its_counter_to_a_span(tmp_path):
         assert [e["args"][key] for e in strips] == [
             r[key] for r in logged]
     targets = [e for e in events if e["name"] == "rpn_targets"]
-    assert [(e["args"]["step"], e["args"]["rpn_fg_rows"])
-            for e in targets] == [(r["step"], r["rpn_fg_rows"])
-                                  for r in logged]
+    for key in ("rpn_fg_rows", "match_gt_rows"):
+        assert [(e["args"]["step"], e["args"][key]) for e in targets] == [
+            (r["step"], r[key]) for r in logged]

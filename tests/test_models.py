@@ -420,7 +420,8 @@ def test_no_per_anchor_row_pick_under_rpn_loss(kind):
         jax.value_and_grad(loss_fn, has_aux=True), return_shape=True)(params)
     (_, losses), _ = out
     assert losses["rpn_fg_rows"].shape == ()
-    assert COUNTER_SPANS["rpn_targets"] == ("rpn_fg_rows",)
+    assert losses["match_gt_rows"].shape == ()
+    assert COUNTER_SPANS["rpn_targets"] == ("rpn_fg_rows", "match_gt_rows")
     n_anchors = sum(3 * (128 // s) ** 2 for s in (4, 8, 16, 32, 64))
     k = int(model.rpn_batch_per_im * model.rpn_fg_ratio)
     scoped = [eqn for eqn in _eqns(closed.jaxpr)
