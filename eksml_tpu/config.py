@@ -475,9 +475,9 @@ def _define_defaults() -> None:
 
     # ---- model selection (eksml_tpu/models/__init__.py build_model) --
     # "maskrcnn" = the detector the MODE_*/BACKBONE/FPN/RPN/FRCNN/MRCNN
-    # blocks describe; "joyai_llm_flash", "ouro" and "laguna" = the
-    # three sequence models the LM block describes (models/lm/, imported
-    # only when selected)
+    # blocks describe; "joyai_llm_flash", "ouro", "laguna" and "xing4" =
+    # the four sequence models the LM block describes (models/lm/,
+    # imported only when selected)
     _C.MODEL.NAME = "maskrcnn"
 
     # ---- sequence models (models/lm/): JoyAI-LLM-Flash's config.json
@@ -546,6 +546,27 @@ def _define_defaults() -> None:
     _C.LM.ROPE_WINDOW.TYPE = "default"
     _C.LM.ROPE_WINDOW.THETA = 10000
     _C.LM.ROPE_WINDOW.PARTIAL_ROTARY_FACTOR = 1.0
+    # MODEL.NAME=xing4 (XingChen-AGI/Xing4.0-29B-A4B's config.json): JoyAI's
+    # block (MLA, the expert layer, the keys above) on HC_MULT residual
+    # streams, mixed around every sublayer by the manifold-constrained
+    # hyper-connection (mHC, arXiv:2512.24880: models/lm/mhc.py) whose
+    # stream-to-stream map is projected by HC_SINKHORN_ITERS row and
+    # column normalisations (each denominator + HC_EPS) of exp(logits
+    # clamped to HC_RES_CLAMP); MLA's rotary is DeepSeek-V3's YaRN over
+    # the QK_ROPE_HEAD_DIM rotary width, its softmax scale times
+    # (0.1 MSCALE_ALL_DIM ln FACTOR + 1)^2; MSCALE is held equal to
+    # MSCALE_ALL_DIM, as published, so cos and sin stay unscaled
+    _C.LM.HC_MULT = 4
+    _C.LM.HC_SINKHORN_ITERS = 20
+    _C.LM.HC_EPS = 1e-6
+    _C.LM.HC_RES_CLAMP = (-30.0, 30.0)
+    _C.LM.ROPE_SCALING.TYPE = "yarn"
+    _C.LM.ROPE_SCALING.FACTOR = 64
+    _C.LM.ROPE_SCALING.ORIGINAL_MAX_POSITION = 4096
+    _C.LM.ROPE_SCALING.BETA_FAST = 32
+    _C.LM.ROPE_SCALING.BETA_SLOW = 1
+    _C.LM.ROPE_SCALING.MSCALE = 1.0
+    _C.LM.ROPE_SCALING.MSCALE_ALL_DIM = 1.0
     # (first, count): the contiguous routed experts THIS chip holds; the
     # router still scores all N_ROUTED_EXPERTS and picks 8 a token
     _C.LM.EXPERTS_HELD = (0, 16)
@@ -833,6 +854,13 @@ LM_KEYS_OF = {
     "laguna": ("HEAD_DIM", "NUM_KV_HEADS", "HEADS_PER_LAYER",
                "LAYER_TYPES", "SLIDING_WINDOW", "ROPE_FULL",
                "ROPE_WINDOW") + _EXPERT_LAYER_KEYS,
+    # NUM_MTP only to hold it at 0: the MTP module is the last
+    # pipeline stage's (models/lm/xing.py)
+    "xing4": (
+        "Q_LORA_RANK", "KV_LORA_RANK", "QK_NOPE_HEAD_DIM",
+        "QK_ROPE_HEAD_DIM", "V_HEAD_DIM", "NUM_MTP", "NUM_HEADS",
+        "ROPE_THETA", "ROPE_SCALING", "HC_MULT", "HC_SINKHORN_ITERS",
+        "HC_EPS", "HC_RES_CLAMP") + _EXPERT_LAYER_KEYS,
 }
 _LM_DEFAULTS = _C.LM.to_dict()
 
@@ -883,6 +911,20 @@ def finalize_configs(is_training: bool) -> AttrDict:
         assert lm.SLIDING_WINDOW >= 1, lm.SLIDING_WINDOW
         for rope in (lm.ROPE_FULL, lm.ROPE_WINDOW):
             assert rope.TYPE in ("default", "yarn"), rope.TYPE
+    if _C.MODEL.NAME == "xing4":
+        lm = _C.LM
+        assert lm.NUM_MTP == 0, (
+            f"LM.NUM_MTP={lm.NUM_MTP}: xing4 builds no MTP module (it is "
+            "the last pipeline stage's)")
+        assert lm.HC_MULT >= 1 and lm.HC_SINKHORN_ITERS >= 1, (
+            lm.HC_MULT, lm.HC_SINKHORN_ITERS)
+        lo, hi = lm.HC_RES_CLAMP
+        assert lo < hi, f"LM.HC_RES_CLAMP={lm.HC_RES_CLAMP}"
+        rope = lm.ROPE_SCALING
+        assert rope.TYPE == "yarn", rope.TYPE
+        assert rope.MSCALE == rope.MSCALE_ALL_DIM, (
+            f"LM.ROPE_SCALING.MSCALE={rope.MSCALE} != MSCALE_ALL_DIM="
+            f"{rope.MSCALE_ALL_DIM}: xing4 leaves cos and sin unscaled")
     assert _C.TRAIN.PARAM_DTYPE in ("float32", "bfloat16"), (
         _C.TRAIN.PARAM_DTYPE)
     assert _C.RESILIENCE.DATA.VALIDATE in ("off", "warn", "strict"), (
@@ -1055,6 +1097,26 @@ LAGUNA_TINY_OVERRIDES = (
     "LM.VOCAB_ROWS=96", "LM.SEQ_LEN=64", "LM.ATTENTION_BLOCK=16",
     "LM.LOSS_CHUNK=32", "LM.DATA.DOC_LEN_MEDIAN=24.0",
     "LM.DATA.DOC_LEN_CLIP=(4,256)",
+)
+
+
+# Xing's hyper-connected stack at the same tiny size (a dense layer and
+# two expert layers, 4 heads of MLA over latent widths 48 / 32, YaRN over
+# an original context of 16, 4 streams of 64), for the CPU tests alone.
+XING_TINY_OVERRIDES = (
+    "MODEL.NAME=xing4", "TRAIN.OPTIMIZER=adamw",
+    "TRAIN.PRECISION=float32", "TRAIN.REMAT=True",
+    "LM.HIDDEN_SIZE=64", "LM.NUM_HEADS=4", "LM.Q_LORA_RANK=48",
+    "LM.KV_LORA_RANK=32", "LM.QK_NOPE_HEAD_DIM=16",
+    "LM.QK_ROPE_HEAD_DIM=8", "LM.V_HEAD_DIM=16", "LM.ROPE_THETA=10000",
+    "LM.ROPE_SCALING.FACTOR=4", "LM.ROPE_SCALING.ORIGINAL_MAX_POSITION=16",
+    "LM.ROPE_SCALING.BETA_FAST=4",
+    "LM.INTERMEDIATE_SIZE=160", "LM.MOE_INTERMEDIATE_SIZE=32",
+    "LM.N_ROUTED_EXPERTS=8", "LM.NUM_EXPERTS_PER_TOK=2",
+    "LM.ROUTED_SCALING_FACTOR=2.0", "LM.NUM_MTP=0",
+    "LM.NUM_LAYERS=3", "LM.EXPERTS_HELD=(0,4)", "LM.VOCAB_ROWS=96",
+    "LM.SEQ_LEN=64", "LM.ATTENTION_BLOCK=16", "LM.LOSS_CHUNK=32",
+    "LM.DATA.DOC_LEN_MEDIAN=24.0", "LM.DATA.DOC_LEN_CLIP=(4,256)",
 )
 
 

@@ -51,8 +51,14 @@ def _laguna(cfg):
     return laguna.Laguna, laguna.decay_mask, laguna.COUNTER_SPANS
 
 
+def _xing4(cfg):
+    from eksml_tpu.models.lm import xing
+
+    return xing.Xing, xing.decay_mask, xing.COUNTER_SPANS
+
+
 _SEAM = {"maskrcnn": _maskrcnn, "joyai_llm_flash": _joyai_llm_flash,
-         "ouro": _ouro, "laguna": _laguna}
+         "ouro": _ouro, "laguna": _laguna, "xing4": _xing4}
 MODEL_NAMES = tuple(_SEAM)
 
 

@@ -28,7 +28,6 @@ they visit).  Every parameter float32, compute in ``dtype``.
 
 from __future__ import annotations
 
-import math
 from typing import Any
 
 import flax.linen as nn
@@ -39,40 +38,14 @@ import numpy as np
 from eksml_tpu.models.lm import attention
 from eksml_tpu.models.lm.model import (  # noqa: F401  (decay_mask: the seam's)
     COUNTER_SPANS as MOE_COUNTER_SPANS, EMBED_INIT_STD, Matrix, MoE, RMSNorm,
-    SwiGLU, chunked_cross_entropy, decay_mask, linear, routing_counters)
+    SwiGLU, chunked_cross_entropy, decay_mask, linear, plain_inv_freq,
+    routing_counters, yarn_inv_freq)
 from eksml_tpu.models.lm.ouro import rotate_half
 
 # JoyAI's routing counters on their span, and the window's on its own
 COUNTER_SPANS = dict(MOE_COUNTER_SPANS, attn_window=("window_tile_share",))
 
 FULL, SLIDING = "full_attention", "sliding_attention"
-
-
-def plain_inv_freq(dim: int, theta: float):
-    """theta^(-2j/dim) for the dim / 2 pairs of a rotary width, float64."""
-    return theta ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
-
-
-def yarn_inv_freq(dim: int, theta: float, factor: float, original_max: int,
-                  beta_fast: float, beta_slow: float):
-    """YaRN's frequencies for a rotary width ``dim`` (arXiv:2309.00071,
-    as ``transformers.modeling_rope_utils._compute_yarn_parameters``
-    computes them): dimension j turns ``original_max x theta^(-2j/dim) /
-    2 pi`` times over the original context; those that turn more than
-    ``beta_fast`` times keep theta^(-2j/dim), those under ``beta_slow``
-    take it divided by ``factor``, a linear ramp over j blends between
-    the two bounds (rounded outward)."""
-    def dimension_of(turns):
-        return (dim * math.log(original_max / (turns * 2 * math.pi))
-                / (2 * math.log(theta)))
-
-    low = max(math.floor(dimension_of(beta_fast)), 0)
-    high = min(math.ceil(dimension_of(beta_slow)), dim - 1)
-    if low == high:
-        high += 0.001
-    plain = plain_inv_freq(dim, theta)
-    ramp = np.clip((np.arange(dim // 2) - low) / (high - low), 0.0, 1.0)
-    return (plain / factor * ramp + plain * (1.0 - ramp)).astype(np.float32)
 
 
 def rotary_table(rope, head_dim: int):

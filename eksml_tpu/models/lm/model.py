@@ -1,8 +1,9 @@
 """JoyAI-LLM-Flash (DeepSeek-V3 family, arXiv:2412.19437), one of the
-three sequence models of ``models/lm`` (the others: ``ouro.py`` and
-``laguna.py``, which share this file's ``Matrix``, ``linear``,
-``RMSNorm``, ``SwiGLU`` and chunked cross-entropy; Laguna its expert
-layer too), as a flax module with the training losses:
+four sequence models of ``models/lm`` (the others: ``ouro.py``,
+``laguna.py`` and ``xing.py``, which share this file's ``Matrix``,
+``linear``, ``RMSNorm``, ``SwiGLU`` and chunked cross-entropy; Laguna
+its expert layer too, Xing its ``MLA`` and expert layer), as a flax
+module with the training losses:
 multi-head latent attention in every block, a dense SwiGLU layer first,
 then expert layers (256-way sigmoid routing, 8 a token, one shared
 expert, the chip's held experts), one multi-token-prediction module of
@@ -19,11 +20,13 @@ Every parameter is the one parameter of its module (``kernel``,
 
 from __future__ import annotations
 
-from typing import Any, Tuple
+import math
+from typing import Any, Optional, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from eksml_tpu.models.lm import attention, moe
 
@@ -85,11 +88,43 @@ class RMSNorm(nn.Module):
         return (y * scale).astype(self.dtype)
 
 
-def rope(x, theta: float):
+def plain_inv_freq(dim: int, theta: float):
+    """theta^(-2j/dim) for the dim / 2 pairs of a rotary width, float64."""
+    return theta ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+
+
+def yarn_inv_freq(dim: int, theta: float, factor: float, original_max: int,
+                  beta_fast: float, beta_slow: float):
+    """YaRN's frequencies for a rotary width ``dim`` (arXiv:2309.00071,
+    as ``transformers.modeling_rope_utils._compute_yarn_parameters``
+    computes them): dimension j turns ``original_max x theta^(-2j/dim) /
+    2 pi`` times over the original context; those that turn more than
+    ``beta_fast`` times keep theta^(-2j/dim), those under ``beta_slow``
+    take it divided by ``factor``, a linear ramp over j blends between
+    the two bounds (rounded outward)."""
+    def dimension_of(turns):
+        return (dim * math.log(original_max / (turns * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(dimension_of(beta_fast)), 0)
+    high = min(math.ceil(dimension_of(beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    plain = plain_inv_freq(dim, theta)
+    ramp = np.clip((np.arange(dim // 2) - low) / (high - low), 0.0, 1.0)
+    return (plain / factor * ramp + plain * (1.0 - ramp)).astype(np.float32)
+
+
+def rope(x, theta: float, inv_freq=None):
     """Rotary embedding on interleaved pairs (x[2j], x[2j+1]) of the
-    last axis; ``x`` ``[B, S, H, D]``, position = index along S."""
+    last axis; ``x`` ``[B, S, H, D]``, position = index along S.  The
+    frequencies are theta^(-2j/D), or ``inv_freq`` (``[D / 2]``: a
+    YaRN table)."""
     d = x.shape[-1]
-    inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    if inv_freq is None:
+        inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    else:
+        inv = jnp.asarray(inv_freq, jnp.float32)
     ang = jnp.arange(x.shape[1], dtype=jnp.float32)[:, None] * inv[None, :]
     cos, sin = jnp.cos(ang)[None, :, None, :], jnp.sin(ang)[None, :, None, :]
     xf = x.astype(jnp.float32).reshape(x.shape[:-1] + (d // 2, 2))
@@ -113,9 +148,14 @@ class SwiGLU(nn.Module):
 
 class MLA(nn.Module):
     """Multi-head latent attention: low-rank q and shared low-rank kv,
-    one rotary key shared by all heads."""
+    one rotary key shared by all heads.  ``rope_inv_freq`` (a tuple of
+    ``QK_ROPE_HEAD_DIM / 2`` frequencies, e.g. YaRN's) replaces the plain
+    table, ``softmax_factor`` multiplies the softmax scale ``(qk
+    width)^-1/2``; the defaults are JoyAI's."""
     cfg: Any
     dtype: Any
+    rope_inv_freq: Optional[Tuple[float, ...]] = None
+    softmax_factor: float = 1.0
 
     @nn.compact
     def __call__(self, h):
@@ -125,20 +165,21 @@ class MLA(nn.Module):
                           c.QK_ROPE_HEAD_DIM, c.V_HEAD_DIM)
         lin = lambda x, n, name: linear(x, n, c.INIT_STD, self.dtype, name)
         norm = lambda name: RMSNorm(c.RMS_NORM_EPS, self.dtype, name=name)
+        rot = lambda x: rope(x, c.ROPE_THETA, self.rope_inv_freq)
         with jax.named_scope("mla"):
             cq = norm("q_a_norm")(lin(h, c.Q_LORA_RANK, "q_a"))
             q = lin(cq, nh * (dn + dr), "q_b").reshape(b, s, nh, dn + dr)
             kva = lin(h, c.KV_LORA_RANK + dr, "kv_a")
             ckv = norm("kv_a_norm")(kva[..., :c.KV_LORA_RANK])
-            k_rope = rope(kva[..., None, c.KV_LORA_RANK:], c.ROPE_THETA)
+            k_rope = rot(kva[..., None, c.KV_LORA_RANK:])
             kv = lin(ckv, nh * (dn + dv), "kv_b").reshape(b, s, nh, dn + dv)
-            q = jnp.concatenate(
-                [q[..., :dn], rope(q[..., dn:], c.ROPE_THETA)], axis=-1)
+            q = jnp.concatenate([q[..., :dn], rot(q[..., dn:])], axis=-1)
             k = jnp.concatenate(
                 [kv[..., :dn], jnp.broadcast_to(k_rope, (b, s, nh, dr))],
                 axis=-1)
             v = kv[..., dn:]
-            q = q * jnp.asarray((dn + dr) ** -0.5, q.dtype)
+            q = q * jnp.asarray((dn + dr) ** -0.5 * self.softmax_factor,
+                                q.dtype)
         with jax.named_scope("mla_core"):
             o = attention.causal_attention(q, k, v, c.ATTENTION_BLOCK)
         with jax.named_scope("mla"):

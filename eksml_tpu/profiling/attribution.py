@@ -204,6 +204,10 @@ SCOPE_RULES: Tuple[Tuple[str, str, bool], ...] = (
     ("gqa-proj", r"(^|[/(])gqa($|[/)])", True),
     ("gqa-core-full", r"(^|[/(])gqa_core_full($|[/)])", True),
     ("gqa-core-window", r"(^|[/(])gqa_core_window($|[/)])", True),
+    # the hyper-connections' two halves (models/lm/mhc.py; Xing's
+    # attention, expert layer and loss carry JoyAI's scopes)
+    ("mhc-pre", r"(^|[/(])mhc_pre($|[/)])", True),
+    ("mhc-post", r"(^|[/(])mhc_post($|[/)])", True),
 )
 _SCOPE_RULES_C = tuple((comp, re.compile(pat), bwd)
                        for comp, pat, bwd in SCOPE_RULES)

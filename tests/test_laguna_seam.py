@@ -38,7 +38,7 @@ def test_laguna_is_chosen_by_configuration(fresh_config):
                       "moe_pairs_dropped", "moe_rows_moved_share"),
         "attn_window": ("window_tile_share",)}
     assert models.MODEL_NAMES == ("maskrcnn", "joyai_llm_flash", "ouro",
-                                  "laguna")
+                                  "laguna", "xing4")
 
 
 @pytest.mark.parametrize("name, stray", [
